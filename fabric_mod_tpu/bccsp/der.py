@@ -3,8 +3,8 @@
 The verify front-end used to decode every signature with a per-item
 python DER parse (`decode_dss_signature`) and marshal digests/keys
 one `np.frombuffer` at a time — at 2048 items per bucket that python
-loop serialized the host against the device (BENCH_r05: the device sat
-idle while the front-end marshalled).  This module replaces the loop
+loop serialized the host against the device (the device sat idle
+while the front-end marshalled).  This module replaces the loop
 with whole-batch numpy:
 
 * `pack_fixed`  — one `b"".join` + one `np.frombuffer` for all the

@@ -496,8 +496,7 @@ class TpuVerifier:
         # FMT_TRACE_JAX_PROFILE=<dir>): dispatch AND resolve run
         # inside the capture so the profile contains real device
         # execution — this batch forfeits its overlap, once, on
-        # purpose (the tpu_watcher matrix trades one batch's latency
-        # for the first on-hardware device profile)
+        # purpose (one batch's latency for a device profile)
         capture = tracing.device_profile_capture()
         if msg is not None:
             # fused hash->verify: raw-message lanes hash on device in

@@ -605,9 +605,8 @@ def compile_count() -> int:
 
 def jax_profile_dir() -> Optional[str]:
     """FMT_TRACE_JAX_PROFILE=<dir>: arm a ONE-SHOT jax.profiler
-    capture window around a device batch dispatch (the tpu_watcher
-    matrix sets it so the first hardware run leaves a real device
-    profile behind)."""
+    capture window around a device batch dispatch, so a hardware run
+    can leave a real device profile behind."""
     got = knobs.get_str("FMT_TRACE_JAX_PROFILE")
     return got or None
 

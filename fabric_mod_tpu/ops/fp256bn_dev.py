@@ -474,10 +474,23 @@ def _use_split_finalexp() -> bool:
     FABRIC_MOD_TPU_SPLIT_FINALEXP=0/1 overrides either way for A/B."""
     from fabric_mod_tpu.utils import knobs
     env = knobs.get_str("FABRIC_MOD_TPU_SPLIT_FINALEXP")
-    if env in ("0", "1"):
-        return env == "1"
     import jax
-    return jax.default_backend() == "cpu"
+    backend = jax.default_backend()
+    split = env == "1" if env in ("0", "1") else backend == "cpu"
+    _say_finalexp_once(split, backend, env)
+    return split
+
+
+@functools.lru_cache(maxsize=None)
+def _say_finalexp_once(split: bool, backend: str, env) -> None:
+    """Which final-exponentiation program serves, and who chose it —
+    once per distinct answer (the choice is made on every batch)."""
+    from fabric_mod_tpu.observability.logging import get_logger
+    get_logger("ops.fp256bn_dev").info(
+        "idemix final exponentiation: %s program on backend %r (%s)",
+        "split/eager" if split else "fused jitted", backend,
+        f"FABRIC_MOD_TPU_SPLIT_FINALEXP={env}" if env in ("0", "1")
+        else "chosen by backend")
 
 
 def pairing_check_batch(a_points, q1: "host.G2",

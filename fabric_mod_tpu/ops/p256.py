@@ -656,6 +656,26 @@ def marshal_inputs(digests: np.ndarray, r_bytes: np.ndarray,
     return core_args, range_ok
 
 
+def _put(x: np.ndarray, sharding):
+    """Host array -> device.  With a sharding, each device receives
+    its slice straight from the host (no stop on device 0 first)."""
+    if sharding is None:
+        return jnp.asarray(x)
+    return jax.device_put(x, sharding)
+
+
+def place_core_args(core_args, mesh=None):
+    """`marshal_inputs`' core_args as device arrays, placed where the
+    verify program wants them: default device without a mesh; with
+    one, the batch axis split over `dp` (parallel.verify_shardings)."""
+    shardings = (None,) * 6
+    if mesh is not None:
+        from fabric_mod_tpu.parallel import verify_shardings
+        limb_s, flag_s = verify_shardings(mesh)
+        shardings = (limb_s,) * 5 + (flag_s,)
+    return tuple(_put(a, s) for a, s in zip(core_args, shardings))
+
+
 def batch_verify(digests: np.ndarray, r_bytes: np.ndarray,
                  s_bytes: np.ndarray, qx_bytes: np.ndarray,
                  qy_bytes: np.ndarray, mesh=None, lazy: bool = False):
@@ -676,21 +696,8 @@ def batch_verify(digests: np.ndarray, r_bytes: np.ndarray,
     """
     core_args, range_ok = marshal_inputs(
         digests, r_bytes, s_bytes, qx_bytes, qy_bytes)
-
-    shardings = (None,) * 6
-    if mesh is not None:
-        from fabric_mod_tpu.parallel import verify_shardings
-        limb_s, flag_s = verify_shardings(mesh)
-        shardings = (limb_s,) * 5 + (flag_s,)
-
-    def _dev(x, s):
-        arr = jnp.asarray(x)
-        if s is not None:
-            arr = jax.device_put(arr, s)
-        return arr
-
     core = _select_core(digests.shape[0], mesh)
-    ok = core(*(_dev(a, s) for a, s in zip(core_args, shardings)))
+    ok = core(*place_core_args(core_args, mesh))
     if lazy:
         return lambda: np.asarray(ok) & range_ok
     return np.asarray(ok) & range_ok
@@ -717,25 +724,18 @@ def batch_verify_raw(words: np.ndarray, nblocks: np.ndarray,
     core_args, range_ok = marshal_inputs(
         digests, r_bytes, s_bytes, qx_bytes, qy_bytes)
 
-    limb_s = flag_s = words_s = None
+    flag_s = words_s = None
     if mesh is not None:
         from fabric_mod_tpu.parallel import (fused_verify_shardings,
                                              verify_shardings)
-        limb_s, flag_s = verify_shardings(mesh)
+        _, flag_s = verify_shardings(mesh)
         words_s, _ = fused_verify_shardings(mesh)
 
-    def _dev(x, s):
-        arr = jnp.asarray(x)
-        if s is not None:
-            arr = jax.device_put(arr, s)
-        return arr
-
     core = _select_core(digests.shape[0], mesh, fused=True)
-    ok = core(_dev(np.asarray(words, np.uint32), words_s),
-              _dev(np.asarray(nblocks, np.int32), flag_s),
-              _dev(np.asarray(has_msg, bool), flag_s),
-              *(_dev(a, s) for a, s in zip(
-                  core_args, (limb_s,) * 5 + (flag_s,))))
+    ok = core(_put(np.asarray(words, np.uint32), words_s),
+              _put(np.asarray(nblocks, np.int32), flag_s),
+              _put(np.asarray(has_msg, bool), flag_s),
+              *place_core_args(core_args, mesh))
     if lazy:
         return lambda: np.asarray(ok) & range_ok
     return np.asarray(ok) & range_ok
@@ -749,12 +749,18 @@ def _select_core(batch: int, mesh, fused: bool = False):
     kernel now IMPLEMENTS the mixed schedule rather than being routed
     around it (the PR-1 follow-up ROADMAP.md named)."""
     mixed = _use_mixed()
-    if _use_pallas() and mesh is None and batch % 8 == 0:
-        # odd direct-caller batches (not divisible by 8 — bccsp
-        # buckets always are) stay on the XLA core above: a lane
-        # width under 8 would make the grid pathological
-        tile = next(t for t in (128, 64, 32, 16, 8) if batch % t == 0)
-        return _pallas_core(tile, mixed, fused)
+    if _use_pallas():
+        if mesh is None and batch % 8 == 0:
+            # odd direct-caller batches (not divisible by 8 — bccsp
+            # buckets always are) stay on the XLA core below: a lane
+            # width under 8 would make the grid pathological
+            tile = next(t for t in (128, 64, 32, 16, 8)
+                        if batch % t == 0)
+            return _pallas_core(tile, mixed, fused)
+        _say_once("FABRIC_MOD_TPU_PALLAS is set, but "
+                  + ("a mesh-sharded batch" if mesh is not None
+                     else "a batch that is not a multiple of 8")
+                  + " runs the XLA ladder, not the Pallas kernel")
     if fused:
         return verify_core_fused_mixed if mixed else verify_core_fused
     return verify_core_mixed if mixed else verify_core
@@ -781,7 +787,20 @@ def _use_pallas() -> bool:
     from fabric_mod_tpu.utils import knobs
     if not knobs.get_bool("FABRIC_MOD_TPU_PALLAS"):
         return False
-    return jax.default_backend() != "cpu"
+    if jax.default_backend() == "cpu":
+        _say_once("FABRIC_MOD_TPU_PALLAS is set, but the CPU backend "
+                  "cannot run a compiled pallas_call: the XLA ladder "
+                  "runs instead")
+        return False
+    return True
+
+
+@functools.lru_cache(maxsize=None)
+def _say_once(msg: str) -> None:
+    """A caller asked for one kernel and gets another: say so, once
+    per distinct reason (the selection runs on every dispatch)."""
+    from fabric_mod_tpu.observability.logging import get_logger
+    get_logger("ops.p256").warning(msg)
 
 
 @functools.lru_cache(maxsize=None)

@@ -2,11 +2,16 @@
 
 The XLA version of the ladder (ops/p256.shamir_ladder) materializes
 every intermediate limb array to HBM between fusions — measured to be
-the throughput ceiling at the XLA level (ROUND3_NOTES "kernel perf
-findings": long element-wise chains run at ~0.07 Tops/s because they
-are HBM-materialization-bound).  This kernel keeps the accumulator,
-the per-lane Q window table, and every Montgomery intermediate in
-VMEM for the full 64-window ladder.
+the throughput ceiling at the XLA level (PERF.md "Older notes": on the
+retired int32 kernel long element-wise chains ran at ~0.07 Tops/s
+because they were HBM-materialization-bound).  This kernel keeps the
+accumulator, the per-lane Q window table, and every Montgomery
+intermediate in VMEM for the full 64-window ladder.
+
+STATUS (tests/test_chip_compile.py): the TPU compiler does not accept
+this kernel yet — after the window-selection BlockSpec repair the
+lowering stops at `scatter` (limbs9.carried's `.at[-1].set`).  It is
+off the default path and has never executed on a chip.
 
 Structure (designed around the Mosaic failure modes catalogued in
 round 3 — no giant concats, no scratch-slice accumulation, no
@@ -262,7 +267,11 @@ def _ladder_call(u1_w, u2_w, qx_m, qy_m, tile: int = 128,
         # uninitialized output rows for them
         raise ValueError(f"batch {batch} not divisible by tile {tile}")
     grid = (batch // tile, N_WINDOWS)
-    sel_spec = pl.BlockSpec((1, tile), lambda i, nw: (nw, i))
+    # selections ride as (N_WINDOWS, 1, batch) with the window axis
+    # squeezed: the TPU lowering wants a block's last two dims
+    # divisible by (8, 128) or equal to the array's, and (1, tile) over
+    # (N_WINDOWS, batch) is neither.  The kernels still see (1, tile).
+    sel_spec = pl.BlockSpec((None, 1, tile), lambda i, nw: (nw, 0, i))
     limb_spec = pl.BlockSpec((K, tile), lambda i, nw: (0, i))
 
     def full(shape):
@@ -313,8 +322,9 @@ def _ladder_call(u1_w, u2_w, qx_m, qy_m, tile: int = 128,
                 pltpu.VMEM((K, tile), _F),           # acc z
             ],
             interpret=interpret,
-        )(u1_w.astype(jnp.int32), u2_w.astype(jnp.int32), qx_m, qy_m,
-          *(jnp.asarray(c) for c in consts))
+        )(u1_w.astype(jnp.int32).reshape(N_WINDOWS, 1, batch),
+          u2_w.astype(jnp.int32).reshape(N_WINDOWS, 1, batch),
+          qx_m, qy_m, *(jnp.asarray(c) for c in consts))
     finally:
         limbs.set_unroll_low_carry(old)
     return x, y, z

@@ -206,8 +206,6 @@ declare("FABRIC_MOD_TPU_UNROLL_LOW_CARRY", "bool", None,
 declare("FABRIC_MOD_TPU_SPLIT_FINALEXP", "str", None,
         "0/1 forces the split/fused idemix final-exponentiation "
         "program; unset = split on the CPU backend, fused on TPU")
-declare("FABRIC_MOD_TPU_JIT_CACHE", "str", "~/.cache/fabric_mod_tpu/jit",
-        "persistent XLA compile-cache directory")
 
 # -- verify front-end -------------------------------------------------------
 declare("FABRIC_MOD_TPU_VERDICT_CACHE", "int", 8192,
@@ -322,4 +320,5 @@ declare("FABRIC_MOD_TPU_GOSSIP_SEND_RETRIES", "int", 2,
 
 # -- bench ------------------------------------------------------------------
 declare("FABRIC_MOD_TPU_BENCH_TIMEOUT", "float", 1200.0,
-        "bench worker wall-clock budget (s) per metric")
+        "bench.py --metric gossip: bound (s) on one device-verify "
+        "wait of the storm (covers a cold compile)")

@@ -188,7 +188,7 @@ export FABRIC_MOD_TPU_BENCH_TIMEOUT="${FABRIC_MOD_TPU_BENCH_TIMEOUT:-2400}"
 # typed) — host-only, small N, bounded wall time; --staged-batch adds
 # the unthrottled staged-vs-unstaged pair on the sw verifier (the
 # correctness/consistency gate of the staged engine at smoke scale —
-# the batch-ECONOMICS curve is the watcher's device-verifier job)
+# the batch-ECONOMICS curve needs the device verifier, on the chip)
 # commitpipe runs TENSOR-ARMED (--tensor-policy 1): its gates then
 # include the tensor-vs-closure txflags + state-fingerprint identity
 # on top of the pipelined/sync/traced differentials; policyeval is
@@ -200,11 +200,11 @@ export FABRIC_MOD_TPU_BENCH_TIMEOUT="${FABRIC_MOD_TPU_BENCH_TIMEOUT:-2400}"
 # deliverfanout: the shared fan-out A/B at smoke scale (sweep up to
 # 400 subscribers, host-only) — the byte-identity gate + the
 # once-per-(block, form) and once-per-(group, key) assertions run on
-# every change; the 10k-subscriber point is the watcher's job
+# every change; the 10k-subscriber point is not a smoke-scale run
 # statescale: the vectorized-MVCC state-scale differential at smoke
 # sizes (top point 100k keys, host-only) — flags/fingerprint identity,
 # the zero-fallback gate, and the stage+mvcc bucket reduction at the
-# 100k point run on every change; the 1M point is the watcher's job
+# 100k point run on every change; the 1M point is not smoke-scale
 exec python bench.py --cpu --batch "${SMOKE_BATCH:-64}" --reps 1 \
     --metric diffverify --metric hashverify \
     --metric commitpipe --commitpipe-verifier sw --tensor-policy 1 \
