@@ -1,0 +1,158 @@
+"""`correct` has to come out false when the timed path is broken.
+
+These tests skip the harness's look for a chip and drive the rest of a
+run (`cellrun.run_cell`) at a size a test can hold: a test's cell of
+8-tx blocks (`testdata/`), the software verifier standing in for the
+device one, with the fault planted underneath.  A sound run comes out
+correct; each fault the cell can have comes out not correct, by the
+number named.  (One chip, so no exchange between chips to leave out.)
+
+The precision control cannot be shown on the CPU, where
+`Precision.HIGH` and `HIGHEST` are the same arithmetic: it is run on
+the chip by `seeds.py --control` (readings in PERF.md);
+`test_control_on_the_chip` repeats it where a TPU is attached, and
+`test_control_stand_in` plants on the CPU what the control did on the
+chip.
+
+    JAX_PLATFORMS=cpu python -m pytest benchmarks/ -q -p no:cacheprovider
+"""
+import os
+import time
+
+import numpy as np
+import pytest
+
+from benchmarks.manifest import Cell, HERE, benchmark_json
+
+SECONDS = 1.5
+
+
+def the_cell() -> Cell:
+    bench = benchmark_json()
+    bench["workloads"] = [{
+        "name": "rehearsal8.backlog", "config": "rehearsal-8",
+        "traffic": "backlog", "chips": 1, "why": "a test's cell"}]
+    return Cell("rehearsal8.backlog", bench,
+                root=os.path.join(HERE, "testdata"))
+
+
+class Standin:
+    """The software verifier behind the device verifier's seam, with a
+    hook on the verdicts of each batch."""
+
+    def __init__(self, alter=None):
+        from fabric_mod_tpu.bccsp.sw import SwCSP
+        from fabric_mod_tpu.bccsp.tpu import FakeBatchVerifier
+        self._inner = FakeBatchVerifier(SwCSP())
+        self._alter = alter or (lambda items, mask: mask)
+
+    def verify_many(self, items):
+        mask = np.asarray(self._inner.verify_many(items), bool)
+        return self._alter(items, mask.copy())
+
+    def verify_many_async(self, items):
+        out = self.verify_many(items)
+        return lambda: out
+
+    def verify_many_fused_async(self, items):
+        return self.verify_many_async(items)
+
+
+def drive(seed, alter=None, wrap_channel=None, traced=False):
+    from benchmarks.cellrun import run_cell
+    return run_cell(
+        the_cell(), seed, SECONDS, traced,
+        {"platform": "cpu", "kind": "cpu", "count": 1},
+        lambda msg: None, time.perf_counter(),
+        make_verifier=lambda: Standin(alter), wrap_channel=wrap_channel,
+        # set-up's own check of the warm-up verdicts would stop a broken
+        # verifier before the window; the fault is for `correct` to find
+        strict_warm=alter is None)
+
+
+def over_limit(result):
+    return {k for k, v in result["compared"].items()
+            if v["value"] > v["limit"]}
+
+
+def test_sound_run_is_correct():
+    result = drive(2 ** 31 + 12345)
+    assert result["correct"], over_limit(result)
+    assert result["attempted"] > 0 and result["failed"] == 0
+    assert set(result["metrics"]) == {"committed_tx_s", "setup_s"}
+    assert list(result)[-1] == "compared"
+
+
+def test_traced_run_reports_per_layer_metrics_and_no_trace_off_chip():
+    result = drive(77, traced=True)
+    # spans are read; the CPU has no device plane, so the trace's
+    # metrics are left out and the run says the trace is missing
+    assert {"unpack_ms_per_tx", "recv_ms_per_block",
+            "commit_ms_per_block"} <= set(result["metrics"])
+    assert "verify_roofline_pct" not in result["metrics"]
+    assert over_limit(result) == {"trace_missing"}
+
+
+def half_left_out(items, mask):
+    """Half of the batch is never verified: taken as valid."""
+    mask[len(mask) // 2:] = True
+    return mask
+
+
+def answer_altered(items, mask):
+    """One verdict of each block-sized batch altered where produced."""
+    if len(mask) > 1:
+        mask[0] = not mask[0]
+    return mask
+
+
+@pytest.mark.parametrize("alter,number", [
+    (half_left_out, "flag_diff"),
+    (answer_altered, "flag_diff"),
+])
+def test_broken_verdicts_are_not_correct(alter, number):
+    result = drive(5, alter=alter)
+    assert not result["correct"]
+    assert number in over_limit(result)
+
+
+def test_step_that_leaves_the_ledger_unchanged_is_not_correct():
+    def commit_nothing(channel):
+        def commit_staged(staged):
+            return list(staged.validator.finish(staged))
+        channel.commit_staged = commit_staged
+    result = drive(6, wrap_channel=commit_nothing)
+    assert not result["correct"]
+    assert "blocks_unread" in over_limit(result)
+
+
+def all_lanes_false(items, mask):
+    mask[:] = False
+    return mask
+
+
+def test_control_stand_in():
+    """What the precision control did on the chip (PERF.md): every
+    lane came back False, the orderer's block signature with them, so
+    the peer rejected the first block and no window opened."""
+    result = drive(7, alter=all_lanes_false)
+    assert not result["correct"]
+    assert over_limit(result) & {"window_not_closed", "rejected_blocks",
+                                 "flag_diff"}
+
+
+def test_control_on_the_chip():
+    import jax
+    if jax.devices()[0].platform != "tpu":
+        pytest.skip("the precision control differs from the program "
+                    "only on a TPU")
+    import json
+    import subprocess
+    import sys
+    out = subprocess.run(
+        [sys.executable, os.path.join(HERE, "seeds.py"), "--workload",
+         benchmark_json()["workloads"][0]["name"], "--seeds", "1,2,3",
+         "--seconds", "6", "--control"],
+        stdout=subprocess.PIPE, text=True).stdout
+    last = json.loads(out.strip().splitlines()[-1])
+    assert last["correct"] == 0 and last["not_correct"] == 3
