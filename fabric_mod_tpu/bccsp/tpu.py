@@ -492,12 +492,6 @@ class TpuVerifier:
             d, r, s, qx, qy, pre_ok, msg = marshal_items(items, size)
         faults.point("bccsp.device.dispatch")
         from fabric_mod_tpu.ops import p256
-        # opt-in one-shot jax.profiler window (FMT_TRACE armed +
-        # FMT_TRACE_JAX_PROFILE=<dir>): dispatch AND resolve run
-        # inside the capture so the profile contains real device
-        # execution — this batch forfeits its overlap, once, on
-        # purpose (one batch's latency for a device profile)
-        capture = tracing.device_profile_capture()
         if msg is not None:
             # fused hash->verify: raw-message lanes hash on device in
             # the SAME program as the ladder — one dispatch, no host
@@ -510,11 +504,8 @@ class TpuVerifier:
             dispatch = lambda: p256.batch_verify(d, r, s, qx, qy,
                                                  mesh=self._mesh,
                                                  lazy=True)
-        if capture is not None:
-            with capture:
-                mask = dispatch()()
-            resolve = lambda: mask
-        else:
+        # the transfer and the enqueue; the program runs after it
+        with tracing.span("device_enqueue", bucket=size):
             resolve = dispatch()
 
         def done() -> np.ndarray:

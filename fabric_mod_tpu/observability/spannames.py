@@ -21,12 +21,15 @@ DECLARED_SPANS: Set[str] = {
     "broadcast.handle",
     "broadcast.stage",
     "broadcast.submit",
+    "commit_wait_staged",
     "der_marshal",
     "device_dispatch",
+    "device_enqueue",
     "fanout.materialize",
     "fingerprint",
     "gossip.drain",
     "ledger_write",
+    "mcs_verify",
     "mvcc",
     "mvcc_vector",
     "policy_device",
@@ -37,12 +40,28 @@ DECLARED_SPANS: Set[str] = {
     "relay.push",
     "relay.repair",
     "shard.dispatch",
+    "stage_wait_block",
+    "stage_wait_slot",
+    "submit_wait",
     "unpack",
     "verdict_await",
     "verify.flush",
     "verify.resolve",
     "wal.sync",
 }
+
+# The spans that are WAITING, not work: a thread parked on a queue, a
+# condition or the device's verdict.  Whatever reads the spans to say
+# what the host was doing (the benchmark's naming of the device's idle
+# time) ranks these below every working span.
+WAIT_SPANS: Set[str] = {
+    "commit_wait_staged",
+    "stage_wait_block",
+    "stage_wait_slot",
+    "submit_wait",
+    "verdict_await",
+}
+assert WAIT_SPANS <= DECLARED_SPANS
 
 
 def is_declared(name: str) -> bool:

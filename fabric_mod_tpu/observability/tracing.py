@@ -22,7 +22,16 @@ and NO span objects are allocated):
   broadcast client/server carrier).  Finished spans land in a bounded
   ring served at ``/trace`` and feed per-name cumulative totals (the
   bench's stage-attribution source) plus the
-  ``fabric_trace_substage_seconds`` histogram.
+  ``fabric_trace_substage_seconds`` histogram.  Beside its wall
+  duration a span keeps its **self time** (the duration less that of
+  the spans nested in it on the same thread, so a sum over names
+  counts no second twice) and its **thread CPU time**
+  (``time.thread_time()`` at both ends: wall less CPU is the time the
+  thread was off the CPU — blocked, or waiting for the interpreter
+  lock).  The spans in ``spannames.WAIT_SPANS`` are waits, not work.
+  A span's ``ts`` is ``time.time()``: whoever holds a profiler trace
+  of the same stretch puts the spans beside the device's programs by
+  the session's own start on that clock (the benchmark does).
 
 * **Block timelines** — the commit path opens one
   ``start_timeline(consumer, block_num)`` per block; every span that
@@ -43,10 +52,10 @@ and NO span objects are allocated):
 
 Plus the device lens: ``export_chrome_trace()`` writes the span ring
 as Chrome trace-event JSON (Perfetto-loadable; device dispatches as
-async slices), ``install_compile_counter()`` counts XLA
-compiles/retraces into ``fabric_tpu_compiles_total``, and
-``FMT_TRACE_JAX_PROFILE=<dir>`` arms a one-shot ``jax.profiler``
-capture window around a device batch dispatch.
+async slices) and ``install_compile_counter()`` counts XLA
+compiles/retraces into ``fabric_tpu_compiles_total``.  The module
+opens no profiler session of its own: whoever wants the device's
+side (the benchmark does) opens one around the work.
 """
 from __future__ import annotations
 
@@ -212,7 +221,8 @@ class Span:
     timeline's sub-stage entries."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "ts",
-                 "dur", "attrs", "thread")
+                 "dur", "self_dur", "cpu", "attrs", "thread",
+                 "_child", "_cpu0")
 
     def __init__(self, name: str, trace_id: str, span_id: str,
                  parent_id: Optional[str], attrs: Dict):
@@ -224,6 +234,10 @@ class Span:
         self.thread = threading.current_thread().name
         self.ts = 0.0
         self.dur = 0.0
+        self.self_dur = 0.0     # dur less the nested spans' dur
+        self.cpu = 0.0          # this thread's CPU seconds inside
+        self._child = 0.0
+        self._cpu0 = 0.0
 
     @property
     def ctx(self) -> TraceContext:
@@ -234,17 +248,22 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
-        self.ts = _clock()
         _stack().append(self)
+        self._cpu0 = time.thread_time()
+        self.ts = _clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.dur = max(0.0, _clock() - self.ts)
+        self.cpu = max(0.0, time.thread_time() - self._cpu0)
+        self.self_dur = max(0.0, self.dur - self._child)
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         st = getattr(_tls, "stack", None)
         if st and st[-1] is self:
             st.pop()
+            if st:
+                st[-1]._child += self.dur
         tl = getattr(_tls, "timeline", None)
         if tl is not None:
             tl.add(self.name, self.ts, self.dur)
@@ -255,6 +274,8 @@ class Span:
         return {"trace_id": self.trace_id, "span_id": self.span_id,
                 "parent_id": self.parent_id, "name": self.name,
                 "ts": self.ts, "dur": round(self.dur, 6),
+                "self": round(self.self_dur, 6),
+                "cpu": round(self.cpu, 6),
                 "thread": self.thread, "attrs": self.attrs}
 
 
@@ -380,7 +401,8 @@ class Recorder:
             maxlen=FLIGHT_RING)
         self._events: collections.deque = collections.deque(maxlen=256)
         self._dumps: collections.deque = collections.deque(maxlen=8)
-        self._totals: Dict[str, List[float]] = {}   # name -> [secs, n]
+        # name -> [secs, n, self secs, cpu secs]
+        self._totals: Dict[str, List[float]] = {}
         self._last_dump = 0.0
 
     def add_span(self, sp: Span) -> None:
@@ -388,9 +410,11 @@ class Recorder:
             self._spans.append(sp.to_dict())
             tot = self._totals.get(sp.name)
             if tot is None:
-                tot = self._totals[sp.name] = [0.0, 0]
+                tot = self._totals[sp.name] = [0.0, 0, 0.0, 0.0]
             tot[0] += sp.dur
             tot[1] += 1
+            tot[2] += sp.self_dur
+            tot[3] += sp.cpu
         _substage_hist().with_labels(sp.name).observe(sp.dur)
 
     def add_timeline(self, tl: BlockTimeline) -> None:
@@ -421,7 +445,9 @@ class Recorder:
 
     def totals(self) -> Dict[str, Dict[str, float]]:
         with self._lock:
-            return {name: {"secs": round(t[0], 6), "count": int(t[1])}
+            return {name: {"secs": round(t[0], 6), "count": int(t[1]),
+                           "self_secs": round(t[2], 6),
+                           "cpu_secs": round(t[3], 6)}
                     for name, t in self._totals.items()}
 
     def dumps(self) -> List[Dict]:
@@ -561,7 +587,7 @@ def export_chrome_trace(path: str) -> int:
     return len(events)
 
 
-# -- device lens: compile counter + one-shot jax.profiler window ------------
+# -- device lens: the compile counter ---------------------------------------
 
 _compile_lock = RegisteredLock("observability.tracing._compile_lock")
 _compile_installed = False
@@ -601,40 +627,3 @@ def install_compile_counter() -> bool:
 
 def compile_count() -> int:
     return _compile_count
-
-
-def jax_profile_dir() -> Optional[str]:
-    """FMT_TRACE_JAX_PROFILE=<dir>: arm a ONE-SHOT jax.profiler
-    capture window around a device batch dispatch, so a hardware run
-    can leave a real device profile behind."""
-    got = knobs.get_str("FMT_TRACE_JAX_PROFILE")
-    return got or None
-
-
-_profile_lock = RegisteredLock("observability.tracing._profile_lock")
-_profile_taken = False
-
-
-def device_profile_capture():
-    """The one-shot capture window: a jax.profiler.trace context
-    manager on the FIRST call after arming (FMT_TRACE set + the
-    profile dir knob), else None.  Callers resolve the dispatch
-    INSIDE the window so the profile actually contains device
-    execution, not just the host-side enqueue."""
-    global _profile_taken
-    if not _enabled:
-        return None
-    out_dir = jax_profile_dir()
-    if out_dir is None:
-        return None
-    with _profile_lock:
-        if _profile_taken:
-            return None
-        _profile_taken = True
-    try:
-        import jax
-        os.makedirs(out_dir, exist_ok=True)
-        note_event("jax_profile", out_dir)
-        return jax.profiler.trace(out_dir)
-    except Exception:
-        return None

@@ -299,7 +299,9 @@ class PipelinedCommitter:
                         f"expects {expected}")
                 self._last_submitted = num
             self._ensure_started()
-            self._in_q.put(block)
+            # the deliver thread held back by a full pipeline
+            with tracing.span("submit_wait", block=num):
+                self._in_q.put(block)
 
     def store_block(self, block) -> List[int]:
         """Synchronous facade: submit + wait for THIS block's commit;
@@ -390,10 +392,12 @@ class PipelinedCommitter:
     def _stage_loop(self) -> None:
         try:
             while True:
-                block = self._in_q.get()
+                with tracing.span("stage_wait_block"):     # starved
+                    block = self._in_q.get()
                 if block is None:
                     return
-                with self._cv:
+                with tracing.span("stage_wait_slot",       # blocked
+                                  block=block.header.number), self._cv:
                     # depth bound + barrier drain share the wait: stage
                     # only when a slot is free AND no barrier block is
                     # still committing
@@ -442,7 +446,8 @@ class PipelinedCommitter:
     # -- commit loop: await verdicts, resolve, MVCC + commit -------------
     def _commit_loop(self) -> None:
         while True:
-            staged = self._staged_q.get()
+            with tracing.span("commit_wait_staged"):       # starved
+                staged = self._staged_q.get()
             if staged is None:
                 return
             tl = getattr(staged, "trace_timeline", None)

@@ -132,9 +132,6 @@ declare("FMT_TRACE_RING", "int", 256,
         "flight-recorder ring: block timelines retained")
 declare("FMT_TRACE_SPANS", "int", 2048,
         "span ring: finished spans retained for /trace + export")
-declare("FMT_TRACE_JAX_PROFILE", "str", None,
-        "directory for the one-shot jax.profiler capture around a "
-        "device batch dispatch (needs FMT_TRACE=1)")
 declare("FMT_SLOW_TESTS", "bool", None,
         "1 enables the multi-minute eager-pairing differentials in "
         "the test suite (excluded from tier-1)")
