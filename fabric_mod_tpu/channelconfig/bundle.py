@@ -29,6 +29,7 @@ except ImportError:
     # bccsp/_x509fallback.py; bccsp/sw.py logged the downgrade).
     from fabric_mod_tpu.bccsp import _x509fallback as x509
 
+from fabric_mod_tpu.msp.cache import CachedMsp
 from fabric_mod_tpu.msp.mspimpl import Msp, MspManager, NodeOUs
 from fabric_mod_tpu.policy.cauthdsl import PolicyError
 from fabric_mod_tpu.policy.manager import PolicyManager
@@ -184,7 +185,11 @@ class Bundle:
         root = config.channel_group
         top = groups_of(root)
 
-        # MSPs first (policies compile against them)
+        # MSPs first (policies compile against them), behind the one
+        # identity cache of this config (reference: the channel config
+        # wraps every MSP in msp/cache when the bundle is built):
+        # every policy compiled below and every consumer of
+        # `msp_manager` shares it, and it dies with the bundle
         msps: List[Msp] = []
         for section in (APPLICATION, ORDERER):
             sec = top.get(section)
@@ -195,7 +200,7 @@ class Bundle:
                 if mv is None:
                     raise ConfigError(f"org {org_name} has no MSP value")
                 msps.append(msp_from_config(m.MSPConfig.decode(mv.value), csp))
-        self.msp_manager = MspManager(msps)
+        self.msp_manager = CachedMsp(MspManager(msps))
 
         # Policy tree mirrors the group tree (reference: the policy
         # manager is constructed per config in policies.NewManagerImpl)

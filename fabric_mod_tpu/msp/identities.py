@@ -37,10 +37,17 @@ def fused_hash_enabled() -> bool:
 
 
 class Identity:
+    """Immutable once built: `serialize()` and the public point are
+    encoded once per object, so the identity that the MSP cache hands
+    back for repeated creator/endorser bytes costs a lookup nothing
+    but an attribute read."""
+
     def __init__(self, mspid: str, cert: x509.Certificate, csp: BCCSP):
         self.mspid = mspid
         self.cert = cert
         self._csp = csp
+        self._serialized: Optional[bytes] = None
+        self._public_xy: Optional[bytes] = None
         self._key = csp.key_import(
             cert.public_key().public_bytes(
                 serialization.Encoding.PEM,
@@ -52,8 +59,11 @@ class Identity:
         return self.cert.public_bytes(serialization.Encoding.PEM)
 
     def serialize(self) -> bytes:
-        return m.SerializedIdentity(mspid=self.mspid,
-                                    id_bytes=self.cert_pem()).encode()
+        out = self._serialized
+        if out is None:
+            out = self._serialized = m.SerializedIdentity(
+                mspid=self.mspid, id_bytes=self.cert_pem()).encode()
+        return out
 
     def ski(self) -> bytes:
         return self._key.ski()
@@ -90,10 +100,12 @@ class Identity:
         hash-then-verify shape implies (msp/identities.go:169)."""
         if self._key.curve != "P256":
             return None
+        xy = self._public_xy
+        if xy is None:
+            xy = self._public_xy = self._key.public_xy()
         if fused_hash_enabled():
-            return VerifyItem(b"", sig, self._key.public_xy(),
-                              message=msg)
-        return VerifyItem(self.digest_for(msg), sig, self._key.public_xy())
+            return VerifyItem(b"", sig, xy, message=msg)
+        return VerifyItem(self.digest_for(msg), sig, xy)
 
 
 class SigningIdentity(Identity):

@@ -13,7 +13,7 @@ configs, with an explicit admin-cert list as fallback.
 from __future__ import annotations
 
 import datetime
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 try:
     from cryptography import x509
@@ -37,6 +37,21 @@ from fabric_mod_tpu.protos import messages as m
 
 class MSPValidationError(Exception):
     pass
+
+
+class MSPValidityWindowError(MSPValidationError):
+    """A certificate of the chain is not yet, or no longer, valid: the
+    one refusal that the clock alone can undo (msp/cache.py never
+    keeps it)."""
+
+
+def now_utc() -> datetime.datetime:
+    """The clock every validity-window check reads, here and in
+    msp/cache.py."""
+    return datetime.datetime.now(datetime.timezone.utc)
+
+
+ValidityWindow = Tuple[datetime.datetime, datetime.datetime]
 
 
 def _check_link(child: x509.Certificate, issuer: x509.Certificate) -> bool:
@@ -129,8 +144,14 @@ class Msp:
         return Identity(self.mspid, cert, self._csp)
 
     def validate(self, ident: Identity) -> None:
+        self.validated_window(ident)
+
+    def validated_window(self, ident: Identity) -> ValidityWindow:
         """Raise MSPValidationError unless the identity chains to our
-        roots and is unexpired/unrevoked.
+        roots and is unexpired/unrevoked; return the interval in which
+        that verdict holds under this MSP's fixed roots and revocation
+        lists: the chain's latest not_valid_before and earliest
+        not_valid_after.
 
         CA certificates are not identities (reference:
         msp/mspimpl.go:713-716 'A CA certificate cannot be used
@@ -145,10 +166,10 @@ class Msp:
         if len(chain) < 2:
             raise MSPValidationError(
                 "identity chain must include at least one CA above the leaf")
-        now = datetime.datetime.now(datetime.timezone.utc)
+        now = now_utc()
         for cert in chain:
             if now < cert.not_valid_before_utc or now > cert.not_valid_after_utc:
-                raise MSPValidationError(
+                raise MSPValidityWindowError(
                     f"certificate {cert.subject.rfc4514_string()!r} outside"
                     " validity window")
             # Revocation applies to the whole chain: a revoked
@@ -158,6 +179,8 @@ class Msp:
                     in self._crl_revoked):
                 raise MSPValidationError("certificate revoked")
         self._check_key_usage(ident.cert)
+        return (max(c.not_valid_before_utc for c in chain),
+                min(c.not_valid_after_utc for c in chain))
 
     @staticmethod
     def _check_key_usage(cert: x509.Certificate) -> None:
@@ -276,11 +299,17 @@ class MspManager:
             raise MSPValidationError(f"unknown MSP {sid.mspid!r}")
         return msp.deserialize_identity(serialized)
 
-    def validate(self, ident: Identity) -> None:
+    def _msp_of(self, ident: Identity) -> Msp:
         msp = self._msps.get(ident.mspid)
         if msp is None:
             raise MSPValidationError(f"unknown MSP {ident.mspid!r}")
-        msp.validate(ident)
+        return msp
+
+    def validate(self, ident: Identity) -> None:
+        self._msp_of(ident).validate(ident)
+
+    def validated_window(self, ident: Identity) -> ValidityWindow:
+        return self._msp_of(ident).validated_window(ident)
 
     def satisfies_principal(self, ident: Identity,
                             principal: m.MSPPrincipal) -> bool:

@@ -128,18 +128,13 @@ class Channel:
 
     # -- bundle lifecycle -------------------------------------------------
     def _install_bundle(self, bundle: Bundle) -> None:
-        # second-chance caches around the bundle's MSP manager
-        # (reference: msp/cache/cache.go): the validator's pass-1
-        # staging deserializes + chain-validates the SAME handful of
-        # creator/endorser identities for every tx of every block —
-        # cache them per bundle.  A config update swaps the bundle,
-        # builds a fresh manager, and therefore starts cold: revoked
-        # or re-rooted identities can never be served from a previous
-        # epoch's cache.
-        from fabric_mod_tpu.msp.cache import CachedMsp
-        cached_mgr = CachedMsp(bundle.msp_manager)
+        # `bundle.msp_manager` is the bundle's own identity cache
+        # (msp/cache.py), shared with every policy compiled from it.
+        # A config update swaps the bundle, builds a fresh manager,
+        # and therefore starts cold: revoked or re-rooted identities
+        # can never be served from a previous epoch's cache.
         policy_eval = ApplicationPolicyEvaluator(
-            cached_mgr, bundle.policy_manager,
+            bundle.msp_manager, bundle.policy_manager,
             sequence=bundle.sequence)
         def state_vp(ns: str, key: str):
             meta = self.ledger.state.get_metadata(ns, key)
@@ -150,7 +145,7 @@ class Channel:
             return None
 
         validator = TxValidator(
-            self.channel_id, cached_mgr, policy_eval,
+            self.channel_id, bundle.msp_manager, policy_eval,
             self._verifier, self._vinfo,
             tx_id_exists=self.ledger.tx_id_exists,
             config_apply=self._validate_and_apply_config,

@@ -2,6 +2,8 @@
 the reference's msp/testdata scenario matrix (expired, wrong CA,
 revoked, NodeOUs) but with fixtures generated on the fly."""
 import datetime
+import sys
+import threading
 
 import pytest
 
@@ -9,6 +11,7 @@ from fabric_mod_tpu.bccsp.sw import SwCSP
 from fabric_mod_tpu.msp import ca as calib
 from fabric_mod_tpu.msp.cache import CachedMsp
 from fabric_mod_tpu.msp.identities import SigningIdentity, deserialize_cert
+from fabric_mod_tpu.msp import mspimpl
 from fabric_mod_tpu.msp.mspimpl import Msp, MspManager, MSPValidationError
 from fabric_mod_tpu.protos import messages as m
 
@@ -231,6 +234,206 @@ def test_cached_msp_agrees(org):
     for _ in range(2):
         with pytest.raises(MSPValidationError):
             cached.validate(bad)
+
+
+def _lookups():
+    """fabric_msp_cache_lookups_total as {(cache, result): value}."""
+    from fabric_mod_tpu.msp.cache import _LOOKUPS_OPTS
+    from fabric_mod_tpu.observability.metrics import default_provider
+    counter = default_provider().counter(_LOOKUPS_OPTS)
+    return {labels: child.value for labels, child in counter._samples()}
+
+
+def _delta(before):
+    return {k: v - before.get(k, 0) for k, v in _lookups().items()}
+
+
+def _set_clock(monkeypatch, at):
+    monkeypatch.setattr(mspimpl, "now_utc", lambda: at)
+
+
+def test_cache_counter_labels_and_hits(org):
+    """Every lookup is counted once under (cache, result); a repeat of
+    the same identity adds hits only, and no key is encoded again."""
+    from fabric_mod_tpu.observability.metrics import default_provider
+    cached = CachedMsp(MspManager([org["msp"]]))
+    raw = _ident(org, "peer").serialize()
+    peer = _role_principal(m.MSPRoleType.PEER)
+    before = _lookups()
+    for _ in range(2):
+        ident = cached.deserialize_identity(raw)
+        cached.validate(ident)
+        assert cached.satisfies_principal(ident, peer)
+    d = _delta(before)
+    assert set(d) == {(c, r) for c in ("deserialize", "validate", "principal")
+                      for r in ("hit", "miss")}
+    assert {k: v for k, v in d.items() if k[1] == "miss"} == {
+        ("deserialize", "miss"): 1, ("validate", "miss"): 1,
+        ("principal", "miss"): 1}
+    # the principal's miss asks the validate cache for the window: a hit
+    assert d[("deserialize", "hit")] == 1 and d[("principal", "hit")] == 1
+    assert d[("validate", "hit")] == 2
+    assert cached.deserialize_identity(raw) is ident
+    assert ident.serialize() is ident.serialize()
+    text = default_provider().render_prometheus()
+    assert ('fabric_msp_cache_lookups_total{cache="validate",result="hit"}'
+            in text)
+
+
+@pytest.mark.parametrize("first_to_expire", ["leaf", "intermediate"])
+def test_cached_valid_is_refused_after_the_chain_expires(
+        org, monkeypatch, first_to_expire):
+    """A cached `valid` is served only up to the chain's EARLIEST
+    not_valid_after, whichever certificate has it."""
+    now = datetime.datetime.now(datetime.timezone.utc)
+    soon = now + datetime.timedelta(hours=1)
+    ica = calib.CA.__new__(calib.CA)
+    ica.cert, ica.key = org["root"].issue(
+        "ica2.org1", "Org1", is_ca=True,
+        not_after=soon if first_to_expire == "intermediate" else None)
+    cert, key = ica.issue(
+        "short@org1", "Org1", ous=["peer"],
+        not_after=soon if first_to_expire == "leaf" else None)
+    cached = CachedMsp(Msp("Org1MSP", org["csp"], [org["root"].cert],
+                           [ica.cert]))
+    ident = SigningIdentity("Org1MSP", cert, calib.key_pem(key), org["csp"])
+    peer = _role_principal(m.MSPRoleType.PEER)
+    for _ in range(2):
+        cached.validate(ident)
+        assert cached.satisfies_principal(ident, peer)
+    _set_clock(monkeypatch, soon - datetime.timedelta(seconds=1))
+    before = _lookups()
+    cached.validate(ident)
+    assert cached.satisfies_principal(ident, peer)
+    assert all(v == 0 for k, v in _delta(before).items() if k[1] == "miss")
+    _set_clock(monkeypatch, soon + datetime.timedelta(seconds=1))
+    for _ in range(2):
+        with pytest.raises(MSPValidationError, match="validity"):
+            cached.validate(ident)
+        assert not cached.satisfies_principal(ident, peer)
+
+
+def test_not_yet_valid_passes_once_valid(org, monkeypatch):
+    """A refusal whose only cause is the validity window is never
+    kept: the same certificate passes once the clock reaches it."""
+    now = datetime.datetime.now(datetime.timezone.utc)
+    later = now + datetime.timedelta(hours=1)
+    cert, key = org["root"].issue("early@org1", "Org1", ous=["peer"],
+                                  not_before=later)
+    cached = CachedMsp(MspManager([org["msp"]]))
+    ident = SigningIdentity("Org1MSP", cert, calib.key_pem(key), org["csp"])
+    peer = _role_principal(m.MSPRoleType.PEER)
+    for _ in range(2):
+        with pytest.raises(MSPValidationError, match="validity"):
+            cached.validate(ident)
+        assert not cached.satisfies_principal(ident, peer)
+    _set_clock(monkeypatch, later + datetime.timedelta(seconds=1))
+    cached.validate(ident)
+    assert cached.satisfies_principal(ident, peer)
+
+
+def test_cached_refusals_keep_their_outcomes(org):
+    """Untrusted, unknown-MSP and malformed identities: the same error
+    on every call, a fresh exception each time (two threads may raise
+    it at once)."""
+    cached = CachedMsp(MspManager([org["msp"]]))
+    evil = calib.CA("ca.evil", "Evil")
+    cert, key = evil.issue("x", "Evil")
+    bad = SigningIdentity("Org1MSP", cert, calib.key_pem(key), org["csp"])
+    stranger = SigningIdentity("NopeMSP", cert, calib.key_pem(key),
+                               org["csp"])
+    for ident, what in ((bad, "no trusted issuer"), (stranger, "unknown MSP")):
+        raised = []
+        for _ in range(2):
+            with pytest.raises(MSPValidationError, match=what) as ei:
+                cached.validate(ident)
+            raised.append(ei.value)
+        assert raised[0] is not raised[1]
+        assert not cached.satisfies_principal(
+            ident, _role_principal(m.MSPRoleType.MEMBER))
+    for _ in range(2):
+        with pytest.raises(MSPValidationError, match="unknown MSP"):
+            cached.deserialize_identity(stranger.serialize())
+        with pytest.raises(Exception):
+            cached.deserialize_identity(m.SerializedIdentity(
+                mspid="Org1MSP", id_bytes=b"not a certificate").encode())
+
+
+def test_identities_without_a_certificate_pass_through_uncached():
+    """The idemix kind: pseudonymous per signature, nothing to key by."""
+    class Pseudonym:
+        mspid = "IdemixOrg"
+
+    class Inner:
+        calls = 0
+
+        def deserialize_identity(self, raw):
+            Inner.calls += 1
+            return Pseudonym()
+
+        def validate(self, ident):
+            Inner.calls += 1
+
+        def satisfies_principal(self, ident, principal):
+            Inner.calls += 1
+            return True
+
+    cached = CachedMsp(Inner())
+    for n in (1, 2):
+        ident = cached.deserialize_identity(b"presentation")
+        cached.validate(ident)
+        assert cached.satisfies_principal(
+            ident, _role_principal(m.MSPRoleType.MEMBER, "IdemixOrg"))
+        assert Inner.calls == 3 * n
+
+
+def test_threads_resolving_one_identity_agree(org):
+    """More threads than cores, a short switch interval: every thread
+    gets the same verdicts for a valid and for an untrusted identity,
+    and the cache ends with one object per identity."""
+    cached = CachedMsp(MspManager([org["msp"]]))
+    good = _ident(org, "peer").serialize()
+    evil = calib.CA("ca.evil", "Evil")
+    cert, key = evil.issue("peer0.org1", "Org1", ous=["peer"])
+    bad = SigningIdentity("Org1MSP", cert, calib.key_pem(key),
+                          org["csp"]).serialize()
+    peer = _role_principal(m.MSPRoleType.PEER)
+    start = threading.Barrier(16)
+    seen, errors = [], []
+
+    def resolve():
+        try:
+            start.wait(timeout=30)
+            for _ in range(50):
+                out = []
+                for raw in (good, bad):
+                    ident = cached.deserialize_identity(raw)
+                    try:
+                        cached.validate(ident)
+                        out.append("valid")
+                    except MSPValidationError as e:
+                        out.append(str(e))
+                    out.append(cached.satisfies_principal(ident, peer))
+                seen.append(tuple(out))
+        except BaseException as e:          # reported below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=resolve) for _ in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(t.is_alive() for t in threads)
+    assert len(seen) == 16 * 50 and len(set(seen)) == 1
+    assert seen[0][0] == "valid" and seen[0][1] is True
+    assert "no trusted issuer" in seen[0][2] and seen[0][3] is False
+    assert cached.deserialize_identity(good) is \
+        cached.deserialize_identity(good)
 
 
 def test_verify_item_fused_hash_emits_raw_message(org, monkeypatch):

@@ -16,6 +16,7 @@ from fabric_mod_tpu.policy import (
 from fabric_mod_tpu.policy.manager import ImplicitMetaPolicyObj
 from fabric_mod_tpu.protos import messages as m
 from fabric_mod_tpu.protos.protoutil import SignedData
+from tests.test_msp import _lookups
 
 
 @pytest.fixture(scope="module")
@@ -296,3 +297,262 @@ def test_application_policy_evaluator(world):
     ev2 = ApplicationPolicyEvaluator(world["mgr"], root)
     assert ev2.evaluate(ref.encode(), [_sd(o["Org3"]["peer"], b"z")])
     assert not ev2.evaluate(ref.encode(), [_sd(o["Org1"]["peer"], b"z")])
+
+
+# --- one identity cache per channel config (msp/cache.py in the bundle) -----
+
+ENDORSEMENT = "/Channel/Application/Endorsement"
+V = m.TxValidationCode
+
+
+def _misses_since(before):
+    return {k[0]: v - before.get(k, 0) for k, v in _lookups().items()
+            if k[1] == "miss"}
+
+
+def _genesis_config(world, crls=None, roots=None):
+    """The standard three-org channel config over the world's CAs;
+    `crls` {org: [CRL DER]} and `roots` {org: CA} replace an org's."""
+    from fabric_mod_tpu.channelconfig import genesis
+    from fabric_mod_tpu.channelconfig.configtx import config_from_block
+    orgs = []
+    for name in sorted(world["orgs"]):
+        ca = (roots or {}).get(name, world["orgs"][name]["ca"])
+        orgs.append(genesis.org_group(
+            name, [calib.cert_pem(ca.cert)],
+            crls_der=(crls or {}).get(name, ())))
+    oca = world["orgs"]["Org1"]["ca"]
+    root = genesis.channel_group(
+        genesis.application_group(orgs, sorted(world["orgs"])),
+        genesis.orderer_group(
+            [genesis.org_group("OrdererOrg", [calib.cert_pem(oca.cert)])],
+            ["OrdererOrg"]))
+    block = genesis.config_block("cachech", genesis.genesis_config(root))
+    return config_from_block(block)[1]
+
+
+def _bundle(world, uncached=False, **kw):
+    """A Bundle of the world's config; `uncached` compiles the same
+    policy tree against the bare MspManager, as before the cache moved
+    into the bundle."""
+    from fabric_mod_tpu.channelconfig import Bundle
+    from fabric_mod_tpu.channelconfig import bundle as bundle_mod
+    config = _genesis_config(world, **kw)
+    if not uncached:
+        return Bundle("cachech", config, world["csp"])
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(bundle_mod, "CachedMsp", lambda mgr: mgr)
+        return Bundle("cachech", config, world["csp"])
+
+
+def _validator_of(bundle, world):
+    from fabric_mod_tpu.bccsp.tpu import FakeBatchVerifier
+    from fabric_mod_tpu.peer import TxValidator, ValidationInfoProvider
+    ref = m.ApplicationPolicy(channel_config_policy_reference=ENDORSEMENT)
+    return TxValidator(
+        "cachech", bundle.msp_manager,
+        ApplicationPolicyEvaluator(bundle.msp_manager, bundle.policy_manager,
+                                   sequence=bundle.sequence),
+        FakeBatchVerifier(world["csp"]),
+        ValidationInfoProvider(ref.encode()))
+
+
+def _signed_tx(world, endorsers, creator=None, key="k"):
+    from fabric_mod_tpu.ledger.rwsetutil import RWSetBuilder
+    from fabric_mod_tpu.protos import protoutil
+    b = RWSetBuilder()
+    b.add_write("mycc", key, b"v")
+    return protoutil.create_signed_tx(
+        "cachech", "mycc", b.build().encode(),
+        creator or world["orgs"]["Org1"]["admin"], endorsers)
+
+
+def _flags(validator, envs):
+    from fabric_mod_tpu.protos import protoutil
+    block = protoutil.new_block(1, b"", envs)
+    flags = validator.validate(block)
+    assert bytes(protoutil.block_txflags(block)) == bytes(flags)
+    return bytes(flags)
+
+
+def test_bundle_policies_share_one_identity_cache(world, monkeypatch):
+    """Every policy of the bundle is compiled against the bundle's
+    cached manager, so N evaluations of the MAJORITY Endorsement policy
+    over the same two endorsers walk each chain a fixed number of
+    times, not 3 x N; the creator path hits the same cache."""
+    from fabric_mod_tpu.msp import mspimpl
+    from fabric_mod_tpu.msp.cache import CachedMsp
+    bundle = _bundle(world)
+    mgr = bundle.msp_manager
+    assert isinstance(mgr, CachedMsp)
+    assert {msp.mspid for msp in mgr.msps()} == {
+        "Org1", "Org2", "Org3", "OrdererOrg"}
+    assert mgr.get("Org2") is not None
+    pol = bundle.policy(ENDORSEMENT)
+    assert isinstance(pol, ImplicitMetaPolicyObj) and len(pol._subs) == 3
+    assert all(sub._msp_mgr is mgr for sub in pol._subs)
+    assert bundle.policy(
+        "/Channel/Orderer/OrdererOrg/Writers")._msp_mgr is mgr
+
+    links = []
+    check_link = mspimpl._check_link
+    monkeypatch.setattr(
+        mspimpl, "_check_link",
+        lambda child, issuer: links.append(1) or check_link(child, issuer))
+    o = world["orgs"]
+    endorsers = [o["Org1"]["peer"], o["Org2"]["peer"]]
+    before = _lookups()
+    col = BatchCollector()
+    pendings = [pol.prepare([_sd(e, b"tx%d" % i) for e in endorsers], col)
+                for i in range(40)]
+    mask = world["csp"].verify_batch(col.items)
+    assert all(p.finish(mask) for p in pendings)
+    # per endorser: one walk to validate, one inside the principal
+    # match of its own org's sub-policy; 40 x 3 x 2 before the cache
+    assert len(links) == 4
+    # principal: Org1's leaf stops at the first endorser, the others
+    # try both
+    assert _misses_since(before) == {
+        "deserialize": 2, "validate": 2, "principal": 5}
+
+    # the creator path (validator, endorser, gossip) is the same object
+    ident = mgr.deserialize_identity(o["Org1"]["peer"].serialize())
+    mgr.validate(ident)
+    assert len(links) == 4
+    assert _misses_since(before)["validate"] == 2
+
+
+def test_second_block_of_the_same_identities_adds_hits_only(world):
+    validator = _validator_of(_bundle(world), world)
+    o = world["orgs"]
+    endorsers = [o["Org1"]["peer"], o["Org2"]["peer"]]
+    envs = [_signed_tx(world, endorsers, key=f"a{i}") for i in range(6)]
+    assert _flags(validator, envs) == bytes([V.VALID] * 6)
+    before = _lookups()
+    envs = [_signed_tx(world, endorsers, key=f"b{i}") for i in range(6)]
+    assert _flags(validator, envs) == bytes([V.VALID] * 6)
+    after = _lookups()
+    assert _misses_since(before) == {
+        "deserialize": 0, "validate": 0, "principal": 0}
+    # 6 creators + 6 x 2 endorsers x 3 sub-policies
+    assert after[("validate", "hit")] - before[("validate", "hit")] == 42
+
+
+def _crl_revoking(ca, cert):
+    import datetime
+    from cryptography import x509
+    from cryptography.hazmat.primitives import hashes, serialization
+    now = datetime.datetime.now(datetime.timezone.utc)
+    crl = (x509.CertificateRevocationListBuilder()
+           .issuer_name(ca.cert.subject)
+           .last_update(now).next_update(now + datetime.timedelta(days=7))
+           .add_revoked_certificate(
+               x509.RevokedCertificateBuilder()
+               .serial_number(cert.serial_number)
+               .revocation_date(now).build())
+           .sign(ca.key, hashes.SHA256()))
+    return crl.public_bytes(serialization.Encoding.DER)
+
+
+@pytest.mark.parametrize("change", ["revoked", "re-rooted"])
+def test_config_update_starts_cold_and_refuses(world, tmp_path, change):
+    """A config update is a new Bundle, hence a new, cold cache: the
+    identity the old config's cache holds as valid is refused under a
+    config that revoked it or replaced its org's root."""
+    pytest.importorskip("cryptography.x509")
+    from fabric_mod_tpu import e2e
+    from fabric_mod_tpu.msp.mspimpl import MSPValidationError
+    o = world["orgs"]
+    peer2 = o["Org2"]["peer"]
+    old = _bundle(world)
+    endorsers = [o["Org1"]["peer"], peer2]
+    envs = [_signed_tx(world, endorsers, key=f"k{i}") for i in range(3)]
+    assert _flags(_validator_of(old, world), envs) == bytes([V.VALID] * 3)
+    old.msp_manager.validate(
+        old.msp_manager.deserialize_identity(peer2.serialize()))
+
+    if change == "revoked":
+        new = _bundle(world, crls={"Org2": [_crl_revoking(
+            o["Org2"]["ca"], peer2.cert)]})
+    else:
+        new = _bundle(world, roots={"Org2": calib.CA("ca.org2.new", "Org2")})
+    assert new.msp_manager is not old.msp_manager
+    before = _lookups()
+    ident = new.msp_manager.deserialize_identity(peer2.serialize())
+    with pytest.raises(MSPValidationError):
+        new.msp_manager.validate(ident)
+    assert _misses_since(before) == {
+        "deserialize": 1, "validate": 1, "principal": 0}
+    assert _flags(_validator_of(new, world), envs) == bytes(
+        [V.ENDORSEMENT_POLICY_FAILURE] * 3)
+    # the old bundle's cache is untouched by the new one
+    old.msp_manager.validate(
+        old.msp_manager.deserialize_identity(peer2.serialize()))
+
+    # the channel installs the bundle's own manager: no second wrapper
+    net = e2e.Network(str(tmp_path))
+    try:
+        chan = net.channel
+        assert chan.validator()._msp_mgr is chan.bundle().msp_manager
+        chan._install_bundle(new)
+        assert chan.validator()._msp_mgr is new.msp_manager
+    finally:
+        net.close()
+
+
+def test_cached_and_uncached_bundles_give_identical_txflags(world):
+    """One block of valid, single-endorsed, corrupted-signature,
+    unknown-MSP, wrong-chain and expired endorsers (and creators): the
+    bundle with the cache and the same policy tree compiled against the
+    bare manager write the same txflags, twice over (cold, then warm)."""
+    import dataclasses
+    import datetime
+    from fabric_mod_tpu.protos import protoutil
+    csp, o = world["csp"], world["orgs"]
+    p1, p2, p3 = (o[n]["peer"] for n in ("Org1", "Org2", "Org3"))
+    past = (datetime.datetime.now(datetime.timezone.utc)
+            - datetime.timedelta(days=1))
+
+    def signer(mspid, ca, cn, **kw):
+        cert, key = ca.issue(cn, mspid, ous=["peer"], **kw)
+        return SigningIdentity(mspid, cert, calib.key_pem(key), csp)
+
+    evil = calib.CA("ca.evil", "Evil")
+    stranger = signer("NopeMSP", evil, "peer0.nope")
+    wrong_chain = signer("Org2", evil, "peer0.org2")
+    expired = signer("Org2", o["Org2"]["ca"], "old.org2", not_after=past)
+
+    def corrupted(env):
+        payload = protoutil.unmarshal_envelope_payload(env)
+        tx = protoutil.extract_endorser_tx(payload)
+        cap = m.ChaincodeActionPayload.decode(tx.actions[0].payload)
+        e1 = cap.action.endorsements[1]
+        cap.action.endorsements[1] = dataclasses.replace(
+            e1, signature=e1.signature[:-1]
+            + bytes([e1.signature[-1] ^ 1]))
+        tx.actions[0] = m.TransactionAction(payload=cap.encode())
+        return protoutil.sign_envelope(
+            m.Payload(header=payload.header, data=tx.encode()),
+            o["Org1"]["admin"])
+
+    envs = [
+        _signed_tx(world, [p1, p2], key="valid"),
+        _signed_tx(world, [p1], key="single"),
+        corrupted(_signed_tx(world, [p1, p2], key="corrupt")),
+        _signed_tx(world, [p1, stranger], key="unknown-msp"),
+        _signed_tx(world, [p1, wrong_chain], key="wrong-chain"),
+        _signed_tx(world, [p1, expired], key="expired"),
+        _signed_tx(world, [p2, p3, expired, stranger], key="valid-3"),
+        _signed_tx(world, [p1, p2], creator=expired, key="c-expired"),
+        _signed_tx(world, [p1, p2], creator=stranger, key="c-unknown"),
+        _signed_tx(world, [p1, p2], creator=wrong_chain, key="c-chain"),
+    ]
+    cached = _validator_of(_bundle(world), world)
+    bare = _validator_of(_bundle(world, uncached=True), world)
+    assert isinstance(bare._msp_mgr, MspManager)
+    want = _flags(bare, envs)
+    assert want[0] == want[6] == V.VALID
+    assert set(want[1:6]) == {V.ENDORSEMENT_POLICY_FAILURE}
+    assert V.VALID not in want[7:]
+    for _ in range(2):
+        assert _flags(cached, envs) == want
