@@ -12,6 +12,7 @@ the comparison.
 """
 import dataclasses
 import gc
+import inspect
 import os
 import sys
 import tempfile
@@ -159,6 +160,36 @@ def real_items_of(tx_facts) -> int:
     return sum(1 + len(t.endorsements) for t in tx_facts)
 
 
+def buckets_reached(items: int, buckets) -> List[int]:
+    """The device programs one batch of `items` signatures runs, as
+    `bccsp/tpu.py` reckons: chunks of the widest bucket, each in the
+    least bucket that holds it."""
+    chunks = [min(buckets[-1], items - i)
+              for i in range(0, items, buckets[-1])]
+    return [min(b for b in buckets if b >= n) for n in chunks]
+
+
+def network_arguments(cell: Cell, network_class) -> dict:
+    """What the configuration's file hands to `e2e.Network`: the block
+    settings and, verbatim, its `network`.  A key the program's class
+    does not take stops the run here, before set-up: that setting
+    needs a change to the program first."""
+    settings = cell.config["settings"]
+    args = {"max_message_count": int(settings["max_message_count"]),
+            "batch_timeout": settings["batch_timeout"]}
+    taken = set(inspect.signature(network_class.__init__).parameters) \
+        - {"self", "root_dir"} - set(args)
+    for key, value in cell.config.get("network", {}).items():
+        if key not in taken:
+            raise RunFailure(
+                f"configuration {cell.entry['config']!r}: `network` has "
+                f"the key {key!r}, which the program's e2e.Network does "
+                f"not take (it takes {sorted(taken)}): a setting the "
+                f"program cannot pass needs a change to the program first")
+        args[key] = value
+    return args
+
+
 def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
              device: dict, say, t_start: float,
              make_verifier: Optional[Callable] = None,
@@ -181,18 +212,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     from fabric_mod_tpu.peer.deliverclient import DeliverClient
     from fabric_mod_tpu.protos import protoutil
 
-    settings = cell.config["settings"]
-    block_txs = int(settings["max_message_count"])
+    network_args = network_arguments(cell, e2e.Network)
+    block_txs = network_args["max_message_count"]
     warm_buckets = [int(b) for b in cell.file["warm_buckets"]]
     tracing.install_compile_counter()
     verifier = (make_verifier or (lambda: TpuVerifier(
         fallback=refuse_fallback)))()
 
     with tempfile.TemporaryDirectory(prefix="bench-") as root:
-        net = e2e.Network(
-            os.path.join(root, "net"),
-            max_message_count=block_txs,
-            batch_timeout=settings["batch_timeout"])
+        net = e2e.Network(os.path.join(root, "net"), **network_args)
         dev_mgr = LedgerManager(os.path.join(root, "dev-peer"))
         # the software network's own threads (its consenter loop) live
         # until `net.close()`; what the peer under test leaves is more
@@ -328,13 +356,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
             cutter_cfg = net.support.cutter.config
             acked = [e[0] for e in stamps.events]
             timer_closed = unwarmed = 0
+            warmed = set(warm_buckets)
             for num in acked:
                 blk = net.support.store.get_block_by_number(num)
                 if closing_rule(blk, cutter_cfg) != "count":
                     timer_closed += 1
                 items = real_items_of(
                     backlog.txs[(num - 1) * block_txs: num * block_txs])
-                if min(b for b in BUCKETS if b >= items) not in warm_buckets:
+                if not warmed.issuperset(buckets_reached(items, BUCKETS)):
                     unwarmed += 1
             compared["timer_closed_blocks"] = timer_closed
             compared["unwarmed_bucket_blocks"] = unwarmed
@@ -360,12 +389,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
                         previous_hash=blk.header.previous_hash,
                         header_hash=protoutil.block_header_hash(
                             blk.header))
-                qe = reopened.new_query_executor()
-                ns = cell.params.get("chaincode", "mycc")
-                n_keys = len(reopened.state.get_state_range(ns, "", ""))
+                held = {(ns, key): value
+                        for ns in sorted({t.ns for t in backlog.txs})
+                        for key, value, _version
+                        in reopened.state.get_state_range(ns, "", "")}
+                rule = cell.rule().Rule(
+                    cell.config["settings"], cell.params,
+                    reference.Signatures().counts)
                 compared.update(reference.compare(
-                    backlog.txs, block_txs, acked, read, qe.get_state,
-                    n_keys))
+                    rule, backlog.txs, block_txs, acked, read, held))
             finally:
                 reopened.close()
             gc.unfreeze()

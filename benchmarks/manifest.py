@@ -6,7 +6,12 @@ mix or one per-layer metric sits in a data file of its own:
     workloads/<cell>.json        the cell: config, traffic, chips, why,
                                  the cell's own traffic parameters and
                                  the device buckets it warms
-    configs/<config>.json        the deployment as it is run
+    configs/<config>.json        the deployment as it is run; `network`
+                                 holds what `e2e.Network` is given
+                                 beside the block settings, `reference`
+                                 names the module
+                                 `references/<reference>.py` that
+                                 `correct` is decided by
     traffic/<traffic>.json       the mix; `generator` names the module
                                  `traffic/<generator>.py` that reads it
     layer_metrics/<metric>.json  one per-layer metric; `reducer` names
@@ -21,6 +26,11 @@ import os
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CHECKOUT = os.path.dirname(HERE)
+
+
+# the rule of a configuration that names none: the one `reference.py`
+# held before a configuration could name its own
+DEFAULT_REFERENCE = "majority_own_key"
 
 
 class ManifestError(RuntimeError):
@@ -79,6 +89,12 @@ class Cell:
     def generator(self):
         return importlib.import_module(
             "benchmarks.traffic." + self.traffic["generator"])
+
+    def rule(self):
+        """The module of the deployment's rule (`reference.py`)."""
+        return importlib.import_module(
+            "benchmarks.references."
+            + self.config.get("reference", DEFAULT_REFERENCE))
 
 
 def reducer_for(metric_name: str):

@@ -2,30 +2,51 @@
 
 The reference is the deployment's semantics written down once more,
 with nothing of the program in it: it imports `cryptography` (OpenSSL)
-and `hashlib` only.  From what the traffic generator made
-(`TxFacts`) it decides, per transaction, the validation code a
-committing peer of this deployment must record, and the world state
-that must result:
+and the standard library only.  It has two parts.
+
+What holds for every deployment is here:
 
 * a signature counts if OpenSSL verifies it over the stated message
   with the stated certificate's key AND its `s` is in the lower half
   of the group order (Fabric accepts low-S signatures only);
-* the creator's signature over the envelope payload must count, else
-  BAD_CREATOR_SIGNATURE;
-* the chaincode's endorsement policy is the channel default, a
-  MAJORITY of the application orgs' peers: with `n_orgs` orgs a
-  transaction needs counting endorsements from more than half of them
-  (2 of 3), else ENDORSEMENT_POLICY_FAILURE;
-* every transaction writes one key nobody else touches and reads none,
-  so MVCC passes all: a valid transaction's value is in the state, an
-  invalid one's key is absent.
+* what the peer acknowledged is read back from its ledger after it
+  was closed and opened again from disk: every block, each one's
+  `previous_hash` the hash of the header before it, the transaction
+  bytes as the orderer cut them, a validation code for every
+  transaction;
+* the world state is exactly what the valid transactions' writes,
+  applied in chain order, leave: every value, no key missing, no key
+  that should not be there.
 
-`compare` holds what was read back from the peer's ledger (after it
-was closed and opened again from disk) against that.  Every number
-compared is exact: its limit is 0.
+What is the deployment's own is a rule, `references/<name>.py`, named
+by the configuration (`"reference"`; `manifest.Cell.rule`): which
+validation code a committing peer of this deployment must record for a
+transaction, and which writes it applies.  `compare` asks the rule once
+per transaction, in chain order, and holds what was read back against
+its answers.  Every number compared is exact: its limit is 0.
+
+The contract of a rule module:
+
+    class Rule:
+        def __init__(self, settings, params, counts): ...
+        def judge(self, tx, block, index) -> (code, writes)
+
+`settings` is the configuration's, `params` the cell's traffic
+parameters, `counts(part) -> bool` the test of a signature above.
+`judge` is called for every transaction of every acknowledged block,
+in chain order (`block` is the block's number, `index` the
+transaction's place in it), so a rule may keep state from one
+transaction to the next (the versions an MVCC check needs).  `tx` is
+what the traffic generator recorded (`TxFacts`, or the generator's own
+kind of fact with at least `env_bytes`, `ns`, `creator` and
+`endorsements`).  `code` is Fabric's TxValidationCode; `writes` maps
+(namespace, key) to the value the peer's state must hold after this
+transaction, or to None for a key it deletes: empty where the
+transaction is invalid.  A rule imports `cryptography`, `hashlib` and
+the standard library: nothing of the program.
 """
 import dataclasses
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from cryptography import x509
 from cryptography.exceptions import InvalidSignature
@@ -36,8 +57,6 @@ from cryptography.hazmat.primitives.asymmetric.utils import (
 
 # Fabric's TxValidationCode (fabric-protos peer/transaction.proto)
 VALID = 0
-BAD_CREATOR_SIGNATURE = 4
-ENDORSEMENT_POLICY_FAILURE = 10
 NOT_VALIDATED = 254
 
 # order of the P-256 group (FIPS 186-4, D.1.2.3)
@@ -54,6 +73,9 @@ class SignedPart:
 
 @dataclasses.dataclass
 class TxFacts:
+    """What `cellrun.py` and `compare` read of a fact: `env_bytes`,
+    `ns`, `creator`, `endorsements`.  `key` and `value` are between
+    the generator and the rule."""
     env_bytes: bytes
     ns: str
     key: str
@@ -62,9 +84,10 @@ class TxFacts:
     endorsements: List[SignedPart]
 
 
-class Reference:
-    def __init__(self, n_orgs: int = 3):
-        self.n_orgs = n_orgs
+class Signatures:
+    """Whether a signature counts, by OpenSSL and the low-S rule."""
+
+    def __init__(self):
         self._keys: Dict[bytes, ec.EllipticCurvePublicKey] = {}
 
     def _key(self, cert_pem: bytes):
@@ -88,14 +111,6 @@ class Reference:
             return False
         return True
 
-    def flag(self, tx: TxFacts) -> int:
-        if not self.counts(tx.creator):
-            return BAD_CREATOR_SIGNATURE
-        orgs = {e.org for e in tx.endorsements if self.counts(e)}
-        if 2 * len(orgs) <= self.n_orgs:
-            return ENDORSEMENT_POLICY_FAILURE
-        return VALID
-
 
 @dataclasses.dataclass
 class ReadBlock:
@@ -107,21 +122,32 @@ class ReadBlock:
     header_hash: bytes
 
 
-def compare(txs: List[TxFacts], block_txs: int, acked: List[int],
-            read: Dict[int, ReadBlock], state_get, n_state_keys: int,
-            n_orgs: int = 3) -> Dict[str, int]:
-    """`acked`: numbers of the blocks the peer acknowledged
-    (`on_commit`).  `read`: what its ledger holds after a reopen.
-    `state_get(ns, key) -> bytes | None`, `n_state_keys`: keys the
-    state holds in the traffic's namespace.  Returns the numbers
-    compared; each has the limit 0."""
-    ref = Reference(n_orgs)
+def compare(rule, txs: list, block_txs: int, acked: List[int],
+            read: Dict[int, ReadBlock],
+            held: Dict[Tuple[str, str], bytes]) -> Dict[str, int]:
+    """`rule`: the deployment's (`judge`, above).  `acked`: numbers of
+    the blocks the peer acknowledged (`on_commit`).  `read`: what its
+    ledger holds after a reopen.  `held`: the whole of the traffic's
+    namespaces as its state holds them, (namespace, key) -> value.
+    Returns the numbers compared; each has the limit 0."""
     out = {"blocks_unread": 0, "chain_breaks": 0, "tx_bytes_diff": 0,
            "flag_diff": 0, "flags_missing": 0, "state_diff": 0}
     n_invalid = 0
-    expected_keys = 0
+    expected: Dict[Tuple[str, str], bytes] = {}
     prev: Optional[ReadBlock] = None
     for num in sorted(acked):
+        mine = txs[(num - 1) * block_txs: num * block_txs]
+        # the rule sees every acknowledged transaction, read back or not
+        due = []
+        for j, tx in enumerate(mine):
+            code, writes = rule.judge(tx, num, j)
+            due.append(code)
+            n_invalid += code != VALID
+            for at, value in writes.items():
+                if value is None:
+                    expected.pop(at, None)
+                else:
+                    expected[at] = value
         blk = read.get(num)
         if blk is None:
             out["blocks_unread"] += 1
@@ -131,27 +157,21 @@ def compare(txs: List[TxFacts], block_txs: int, acked: List[int],
                 and blk.previous_hash != prev.header_hash:
             out["chain_breaks"] += 1
         prev = blk
-        mine = txs[(num - 1) * block_txs: num * block_txs]
         if len(blk.tx_bytes) != len(mine):
             out["tx_bytes_diff"] += abs(len(blk.tx_bytes) - len(mine))
         for j, tx in enumerate(mine):
             if j >= len(blk.tx_bytes) or blk.tx_bytes[j] != tx.env_bytes:
                 out["tx_bytes_diff"] += 1
                 continue
-            want = ref.flag(tx)
             got = blk.flags[j] if j < len(blk.flags) else NOT_VALIDATED
             if got == NOT_VALIDATED:
                 out["flags_missing"] += 1
-            elif got != want:
+            elif got != due[j]:
                 out["flag_diff"] += 1
-            n_invalid += want != VALID
-            expected_keys += want == VALID
-            held = state_get(tx.ns, tx.key)
-            if held != (tx.value if want == VALID else None):
-                out["state_diff"] += 1
-    # the state holds nothing but the valid transactions' keys
-    if not out["blocks_unread"] and n_state_keys != expected_keys:
-        out["state_diff"] += abs(n_state_keys - expected_keys)
+    # values that differ, keys that are missing, keys that should not
+    # be there
+    out["state_diff"] = sum(
+        expected.get(at) != held.get(at) for at in set(expected) | set(held))
     # a run in which no invalid transaction was due proves nothing
     # about False lanes
     out["no_invalid_tx_due"] = int(n_invalid == 0)

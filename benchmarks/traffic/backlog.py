@@ -22,6 +22,11 @@ the cell (`workloads/<cell>.json`, `params`):
 
     provision_tx_s   transactions provisioned per second of window
     warm_blocks      blocks the peer commits before the window opens
+    endorsements_per_tx
+                     how many orgs endorse a transaction, the first so
+                     many of the network's (default 2); a mix sets it to
+                     its policy's threshold, so that one bad signature
+                     decides a flag
     single_endorsed_per, corrupt_signature_per
                      one transaction in so many carries one
                      endorsement only / a corrupted second endorsement
@@ -107,7 +112,13 @@ def provision(net, params: dict, seed: int, seconds: float, say) -> Backlog:
     n_corrupt = max(1, n_txs // int(params["corrupt_signature_per"]))
     picked = rng.sample(range(n_txs), n_single + n_corrupt)
     single, corrupt = set(picked[:n_single]), set(picked[n_single:])
-    orgs = list(net.endorsers)[:2]
+    n_endorse = int(params.get("endorsements_per_tx", 2))
+    if not 2 <= n_endorse <= len(net.endorsers):
+        raise TrafficError(
+            f"endorsements_per_tx is {n_endorse}: the corrupted signature "
+            f"is the second endorsement's, and the network has "
+            f"{len(net.endorsers)} endorsing orgs")
+    orgs = list(net.endorsers)[:n_endorse]
     endorsers = trusting_endorsers(net)
 
     def pem(identity) -> bytes:
