@@ -414,6 +414,8 @@ class TpuVerifier:
             out = vals[lanes]
             return lambda: out
         resolve = self._dispatch([uniq_items[j] for j in miss_lanes])
+        # device calls this batch became, for the caller's span
+        chunks = getattr(resolve, "chunks", 1)
         miss_idx = np.asarray(miss_lanes)
 
         if keep_device and len(miss_lanes) == len(uniq_keys):
@@ -442,6 +444,7 @@ class TpuVerifier:
                 if cache is not None and raw is not None:
                     cache.put_many(uniq_keys, np.asarray(raw, bool))
             finish_fused.writeback = writeback
+            finish_fused.chunks = chunks
             return finish_fused
 
         def finish() -> np.ndarray:
@@ -450,6 +453,7 @@ class TpuVerifier:
                 cache.put_many([uniq_keys[j] for j in miss_lanes], mask)
             vals[miss_idx] = mask
             return vals[lanes]
+        finish.chunks = chunks
         return finish
 
     def _dispatch(self, items: Sequence[VerifyItem]):
@@ -463,7 +467,11 @@ class TpuVerifier:
             # chunk through the fixed buckets — never mint new shapes
             parts = [self._dispatch(items[i:i + BUCKETS[-1]])
                      for i in range(0, n, BUCKETS[-1])]
-            return lambda: np.concatenate([p() for p in parts])
+
+            def finish_parts() -> np.ndarray:
+                return np.concatenate([p() for p in parts])
+            finish_parts.chunks = len(parts)
+            return finish_parts
         breaker = self.breaker
         if not breaker.allow():
             self._m_fallback.add(1)

@@ -85,13 +85,24 @@ def _std_meta_policies(g: m.ConfigGroup) -> None:
 
 
 def application_group(orgs: Sequence[m.ConfigGroup],
-                      org_names: Sequence[str]) -> m.ConfigGroup:
+                      org_names: Sequence[str],
+                      endorsement_policy: Optional[str] = None
+                      ) -> m.ConfigGroup:
+    """`endorsement_policy`: the channel's default endorsement policy
+    (`/Channel/Application/Endorsement`, what a chaincode without a
+    definition of its own validates against) as a policydsl string,
+    stated as configtx.yaml states one (`Type: Signature`, `Rule:
+    "OutOf(9, 'Org1.peer', ...)"`); None is the implicit-meta
+    `MAJORITY Endorsement` over the orgs' own Endorsement policies.
+    LifecycleEndorsement is the implicit-meta majority either way."""
     g = m.ConfigGroup(mod_policy=ADMINS)
     for name, org in zip(org_names, orgs):
         set_group(g, name, org)
     _std_meta_policies(g)
     set_policy(g, ENDORSEMENT, _config_policy(
-        _meta_policy(m.ImplicitMetaRule.MAJORITY, ENDORSEMENT)))
+        _meta_policy(m.ImplicitMetaRule.MAJORITY, ENDORSEMENT)
+        if endorsement_policy is None
+        else _sig_policy(endorsement_policy)))
     set_policy(g, LIFECYCLE_ENDORSEMENT, _config_policy(
         _meta_policy(m.ImplicitMetaRule.MAJORITY, ENDORSEMENT)))
     return g
@@ -158,14 +169,18 @@ def config_block(channel_id: str, config: m.Config,
 
 
 def standard_network(channel_id: str, org_cas: dict,
-                     orderer_cas: dict, **orderer_kwargs) -> m.Block:
+                     orderer_cas: dict,
+                     endorsement_policy: Optional[str] = None,
+                     **orderer_kwargs) -> m.Block:
     """Convenience: {mspid: [root PEM]} maps for application and
-    orderer orgs -> genesis block (the e2e/test topology builder)."""
+    orderer orgs -> genesis block (the e2e/test topology builder).
+    `endorsement_policy` goes to `application_group`, the rest to
+    `orderer_group`."""
     app_orgs = [org_group(mspid, pems) for mspid, pems in
                 sorted(org_cas.items())]
     ord_orgs = [org_group(mspid, pems) for mspid, pems in
                 sorted(orderer_cas.items())]
     root = channel_group(
-        application_group(app_orgs, sorted(org_cas)),
+        application_group(app_orgs, sorted(org_cas), endorsement_policy),
         orderer_group(ord_orgs, sorted(orderer_cas), **orderer_kwargs))
     return config_block(channel_id, genesis_config(root))

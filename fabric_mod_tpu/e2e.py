@@ -33,14 +33,23 @@ from fabric_mod_tpu.concurrency.threads import RegisteredThread
 
 class Network:
     """One channel, N orgs, one solo orderer, one committing peer,
-    one endorser per org — all in-process."""
+    one endorser per org — all in-process.
+
+    `endorsement_policy` is the channel's default endorsement policy
+    as a policydsl string (`genesis.application_group`; None: the
+    implicit-meta MAJORITY of the orgs); `absolute_max_bytes` and
+    `preferred_max_bytes` are the orderer's BatchSize byte limits
+    beside `max_message_count`."""
 
     def __init__(self, root_dir: str, channel_id: str = "testchannel",
                  orgs: Sequence[str] = ("Org1", "Org2", "Org3"),
                  verifier=None, csp=None,
                  max_message_count: int = 500,
                  batch_timeout: str = "250ms",
-                 ingress_batching: bool = False):
+                 ingress_batching: bool = False,
+                 endorsement_policy: Optional[str] = None,
+                 absolute_max_bytes: int = 10 * 1024 * 1024,
+                 preferred_max_bytes: int = 2 * 1024 * 1024):
         self.channel_id = channel_id
         self.csp = csp or SwCSP()
         if verifier is None:
@@ -79,7 +88,10 @@ class Network:
             channel_id,
             {org: [calib.cert_pem(ca.cert)] for org, ca in self.cas.items()},
             {"OrdererOrg": [calib.cert_pem(self.orderer_ca.cert)]},
+            endorsement_policy=endorsement_policy,
             max_message_count=max_message_count,
+            absolute_max_bytes=absolute_max_bytes,
+            preferred_max_bytes=preferred_max_bytes,
             batch_timeout=batch_timeout)
 
         # ordering service; with ingress batching, concurrent

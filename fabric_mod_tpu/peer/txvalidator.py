@@ -58,6 +58,12 @@ _RAW_ITEMS_OPTS = MetricOpts(
     help="Staged items carrying raw messages instead of host digests "
          "(FABRIC_MOD_TPU_FUSED_HASH: e = H(m) computed on device in "
          "the same program as the verify).")
+_POLICY_EVALS_OPTS = MetricOpts(
+    "fabric", "policy", "signature_evals_total",
+    help="Endorsement-policy evaluations the validator finished "
+         "(chaincode-wide and key-level), by result; added once per "
+         "block.",
+    label_names=("result",))
 _BODY_FALLBACK_OPTS = MetricOpts(
     "fabric", "validator", "body_decode_fallbacks",
     help="Endorser-tx bodies the columnar batch decoder could not "
@@ -73,6 +79,13 @@ def _stage_metrics():
             prov.counter(_DEDUP_SAVED_OPTS),
             prov.counter(_RAW_ITEMS_OPTS),
             prov.counter(_BODY_FALLBACK_OPTS))
+
+
+@functools.lru_cache(maxsize=None)
+def _policy_eval_metrics():
+    evals = default_provider().counter(_POLICY_EVALS_OPTS)
+    return (evals.with_labels("satisfied"),
+            evals.with_labels("unsatisfied"))
 
 
 class ValidationInfoProvider:
@@ -618,7 +631,7 @@ class TxValidator:
                         if getattr(it, "message", None) is not None))
         with tracing.span("device_dispatch",
                           block=block.header.number,
-                          items=len(collector.items)):
+                          items=len(collector.items)) as dispatch_span:
             # with a tensor session, prefer the verifier's FUSED seam:
             # its resolver may hand back a device-resident mask the
             # policy program consumes without a host round trip
@@ -634,6 +647,10 @@ class TxValidator:
             else:
                 items = collector.items
                 mask_fn = lambda: self._verifier.verify_many(items)
+            # device calls the batch became, where the verifier says
+            chunks = getattr(mask_fn, "chunks", None)
+            if chunks is not None:
+                dispatch_span.set(chunks=chunks)
         return StagedBlock(block, self, works, mask_fn, session, rwsets)
 
     def finish(self, staged: "StagedBlock") -> List[int]:
@@ -656,9 +673,13 @@ class TxValidator:
         flags: List[int] = []
         seen_txids = set()
         applied_vp: Dict[tuple, int] = {}   # (ns, key) -> writer tx_idx
-        with tracing.span("policy_finish", block=block.header.number):
+        # evaluations satisfied and unsatisfied: tallied per block,
+        # never per (identity, principal) pair
+        tally = [0, 0]
+        with tracing.span("policy_finish",
+                          block=block.header.number) as finish_span:
             for idx, work in enumerate(works):
-                flag = self._finish_tx(work, mask, applied_vp)
+                flag = self._finish_tx(work, mask, applied_vp, tally)
                 if flag == V.VALID and work.txid:
                     if work.txid in seen_txids or \
                             self._tx_id_exists(work.txid):
@@ -670,6 +691,10 @@ class TxValidator:
                         applied_vp[(ns, key)] = idx
                 flags.append(flag)
             protoutil.set_block_txflags(block, bytes(flags))
+            finish_span.set(evals=tally[0] + tally[1])
+        satisfied, unsatisfied = _policy_eval_metrics()
+        satisfied.add(tally[0])
+        unsatisfied.add(tally[1])
         return flags
 
     def validate(self, block: m.Block) -> List[int]:
@@ -678,7 +703,13 @@ class TxValidator:
         the flags (reference: validator.go:182-267)."""
         return self.finish(self.stage(block))
 
-    def _finish_tx(self, work: _TxWork, mask, applied_vp) -> int:
+    @staticmethod
+    def _finish_eval(pending, mask, tally) -> bool:
+        ok = pending.finish(mask)
+        tally[0 if ok else 1] += 1
+        return ok
+
+    def _finish_tx(self, work: _TxWork, mask, applied_vp, tally) -> int:
         if work.flag != V.NOT_VALIDATED:
             return work.flag
         bidx, host_ok = work.creator_slot
@@ -711,9 +742,10 @@ class TxValidator:
                 if pending is None:
                     uncovered = True        # falls to the cc-wide policy
                     continue
-                if not pending.finish(mask):
+                if not self._finish_eval(pending, mask, tally):
                     return V.ENDORSEMENT_POLICY_FAILURE
-            if uncovered and not action.cc_pending.finish(mask):
+            if uncovered and not self._finish_eval(
+                    action.cc_pending, mask, tally):
                 return V.ENDORSEMENT_POLICY_FAILURE
         return V.VALID
 
