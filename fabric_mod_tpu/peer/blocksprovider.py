@@ -19,13 +19,17 @@ adds:
   that failed verification; the source re-fetches from `n` on a
   DIFFERENT orderer instead of the caller halting commit forever — the
   reference's "disconnect and try another orderer" stance
-  (blocksprovider.go:227 VerifyBlock error path).
+  (blocksprovider.go:227 VerifyBlock error path).  The report may come
+  some blocks late and from another thread (the deliver client's
+  signature verdict lands with the block's own verify batch): the
+  source rewinds at its next yield, or within a poll of a quiet
+  stream, and whatever it yielded past `n` is the caller's to discard.
 """
 from __future__ import annotations
 
 import threading
 import time
-from typing import Iterator, List, Optional, Sequence
+from typing import Callable, Iterator, List, Optional, Sequence
 
 from fabric_mod_tpu import faults
 from fabric_mod_tpu.comm.grpc_comm import GRPCClient
@@ -96,9 +100,16 @@ class FailoverDeliverSource:
         it from a different orderer (fail-closed per orderer, not
         forever)."""
         with self._lock:
-            self._resume = number
+            # the lowest pending wins: a later block of the same
+            # stream is re-fetched with it
+            if self._resume is None or number < self._resume:
+                self._resume = number
         log.warning("block %d failed verification; rotating orderer",
                     number)
+
+    def _rewind_pending(self) -> bool:
+        with self._lock:
+            return self._resume is not None
 
     def _rotate(self) -> None:
         with self._lock:
@@ -124,10 +135,13 @@ class FailoverDeliverSource:
 
         next_needed = start
         consecutive_failures = 0
+        with self._lock:
+            self._resume = None            # an earlier pull's report
         while not (stop_event is not None and stop_event.is_set()):
             if stop is not None and next_needed > stop:
                 return
             ep = self._endpoints[self._idx]
+            stream_start = next_needed
             made_progress = False
             try:
                 seek = make_seek_envelope(self._channel_id, next_needed,
@@ -137,7 +151,8 @@ class FailoverDeliverSource:
                     timeout=None)
                 try:
                     watchdog = _StreamWatchdog(stream, timeout_s,
-                                               stop_event)
+                                               stop_event,
+                                               self._rewind_pending)
                     for raw in watchdog.iterate():
                         # chaos seam: a mid-stream death of THIS
                         # endpoint (the except below rotates away)
@@ -155,19 +170,10 @@ class FailoverDeliverSource:
                                 next_needed)
                             break
                         yield blk
-                        # a yield only counts as PROGRESS if the
-                        # caller's verify stage did not immediately
-                        # reject it — otherwise N orderers all serving
-                        # an unverifiable block would rotate in a hot
-                        # loop with the backoff never engaging
-                        with self._lock:
-                            if self._resume is not None:
-                                next_needed = self._resume
-                                self._resume = None
-                                break      # rotate below
-                            next_needed = blk.header.number + 1
+                        if self._rewind_pending():
+                            break          # rewind + rotate below
+                        next_needed = blk.header.number + 1
                         made_progress = True
-                        consecutive_failures = 0
                         if stop_event is not None and stop_event.is_set():
                             return
                         if stop is not None and next_needed > stop:
@@ -188,8 +194,22 @@ class FailoverDeliverSource:
                 # rotate, not kill the peer's deliver thread
                 log.warning("deliver stream to %s raised: %r",
                             ep.address, e)
+            with self._lock:
+                resume, self._resume = self._resume, None
+            if resume is not None:
+                # the caller's verify stage rejected block `resume`,
+                # at once or (a deferred verdict) some yields later.
+                # A stream counts as PROGRESS only if it delivered a
+                # block below the one rejected, one the caller kept —
+                # otherwise N orderers all serving an unverifiable
+                # block would rotate in a hot loop with the backoff
+                # never engaging
+                next_needed = min(next_needed, resume)
+                made_progress = stream_start < resume
             self._rotate()
-            if not made_progress:
+            if made_progress:
+                consecutive_failures = 0
+            else:
                 consecutive_failures += 1
                 if consecutive_failures >= len(self._endpoints):
                     # full rotation without progress: back off on the
@@ -216,10 +236,13 @@ class _StreamWatchdog:
     _POLL_S = 0.5                         # stop_event responsiveness
 
     def __init__(self, stream, timeout_s: float,
-                 stop_event: Optional[threading.Event]):
+                 stop_event: Optional[threading.Event],
+                 rewind_pending: Callable[[], bool]):
         self._stream = stream
         self._timeout = timeout_s
         self._stop_event = stop_event
+        # a quiet stream is left when its consumer asked for a rewind
+        self._rewind_pending = rewind_pending
         self._abandoned = threading.Event()
 
     def abandon(self) -> None:
@@ -266,6 +289,9 @@ class _StreamWatchdog:
                 except _queue.Empty:
                     if (self._stop_event is not None
                             and self._stop_event.is_set()):
+                        self._stream.cancel()
+                        return
+                    if self._rewind_pending():
                         self._stream.cancel()
                         return
                     waited += self._POLL_S

@@ -21,7 +21,8 @@ from fabric_mod_tpu.channelconfig import (
 from fabric_mod_tpu.channelconfig.configtx import config_from_block
 from fabric_mod_tpu.observability.metrics import (MetricOpts,
                                                   default_provider)
-from fabric_mod_tpu.peer.mcs import MessageCryptoService
+from fabric_mod_tpu.peer.mcs import (MessageCryptoService,
+                                     block_validation_policy)
 from fabric_mod_tpu.peer.txvalidator import (
     Committer, TxValidator, ValidationInfoProvider)
 from fabric_mod_tpu.policy import ApplicationPolicyEvaluator
@@ -312,8 +313,21 @@ class Channel:
     # the verdicts and commits.  `staged.needs_barrier` tells the
     # pipeline when staging must NOT run ahead (config / vp-write /
     # lifecycle blocks).
-    def stage_block(self, block: m.Block):
-        return self.validator().stage(block)
+    def stage_block(self, block: m.Block, block_sigs=None):
+        """`block_sigs`: the block signatures' SignedData from
+        `mcs.check_block`, where the caller left the BlockValidation
+        policy to this block's own verify batch (the deliver client).
+        The policy is the one of the bundle in force NOW: the stage
+        loop waits out every earlier config block (`needs_barrier`),
+        so a block is never held against a policy an uncommitted
+        config block is about to replace."""
+        with self._lock:
+            bundle, validator = self._bundle, self._validator
+        if block_sigs is None:
+            return validator.stage(block)
+        return validator.stage(
+            block, (block_validation_policy(bundle, block.header.number),
+                    block_sigs))
 
     def commit_staged(self, staged) -> List[int]:
         # finish on the validator that staged: its pending evaluators

@@ -14,8 +14,18 @@ pipeline over an in-order block stream:
   caller       submit(block)   -> bounded in-queue (backpressure)
   stage loop   stage(N+1): host unpack + device dispatch, CONCURRENT
                with ...
-  commit loop  finish(N): await verdicts, resolve flags; then
+  commit loop  finish(N): await verdicts; FIRST the block signature's
+               (below); resolve flags; then
                kvledger.commit_block(N): MVCC + block store + state
+
+`submit(block, block_sigs)` hands the stage loop the block signatures'
+SignedData too (peer/mcs.check_block; the deliver client does): their
+items ride the block's own verify batch, and the commit loop's first
+act after the verdict await is their BlockValidation verdict, before a
+flag is written, a config applied or a byte committed.  Unsatisfied,
+`finish` raises BlockVerificationError and the sticky error below
+drains every later staged and queued block uncommitted.  A block
+submitted without them is one its caller verified beforehand.
 
 `depth` bounds how many blocks may be staged-but-uncommitted at once;
 depth=1 is bit-identical to the synchronous Committer (stage(N+1)
@@ -265,9 +275,12 @@ class PipelinedCommitter:
                 log.debug("on_error callback raised: %r", cb_err)
 
     # -- producer side ---------------------------------------------------
-    def submit(self, block) -> None:
-        """Enqueue one block for pipelined commit.  Blocks only on the
-        bounded in-queue (or a pending error).  Blocks MUST arrive in
+    def submit(self, block, block_sigs=None) -> None:
+        """Enqueue one block for pipelined commit; with `block_sigs`
+        (the block signatures' SignedData) its BlockValidation verdict
+        rides the block's batch and gates the commit (module
+        docstring).  Blocks only on the bounded in-queue (or a
+        pending error).  Blocks MUST arrive in
         block-number order; a misordered submit (stale redelivery, or
         a racing producer's block arriving early) is rejected HERE
         with the ledger's own error type, to the offending caller
@@ -301,7 +314,7 @@ class PipelinedCommitter:
             self._ensure_started()
             # the deliver thread held back by a full pipeline
             with tracing.span("submit_wait", block=num):
-                self._in_q.put(block)
+                self._in_q.put((block, block_sigs))
 
     def store_block(self, block) -> List[int]:
         """Synchronous facade: submit + wait for THIS block's commit;
@@ -393,9 +406,10 @@ class PipelinedCommitter:
         try:
             while True:
                 with tracing.span("stage_wait_block"):     # starved
-                    block = self._in_q.get()
-                if block is None:
+                    item = self._in_q.get()
+                if item is None:
                     return
+                block, block_sigs = item
                 with tracing.span("stage_wait_slot",       # blocked
                                   block=block.header.number), self._cv:
                     # depth bound + barrier drain share the wait: stage
@@ -424,7 +438,12 @@ class PipelinedCommitter:
                 tl = tracing.start_timeline(self._consumer,
                                             block.header.number)
                 with tracing.timeline_scope(tl):
-                    staged = self._channel.stage_block(block)
+                    # a target that never folds (ValidatorCommitTarget)
+                    # keeps its one-argument stage_block
+                    staged = (self._channel.stage_block(block)
+                              if block_sigs is None else
+                              self._channel.stage_block(block,
+                                                        block_sigs))
                 if tl is not None:
                     staged.trace_timeline = tl
                 dt = time.perf_counter() - t0

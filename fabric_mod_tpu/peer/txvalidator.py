@@ -36,6 +36,7 @@ import numpy as np
 from fabric_mod_tpu.observability import tracing
 from fabric_mod_tpu.observability.metrics import (MetricOpts,
                                                   default_provider)
+from fabric_mod_tpu.peer.mcs import signatures_unsatisfied
 from fabric_mod_tpu.policy import ApplicationPolicyEvaluator, BatchCollector
 from fabric_mod_tpu.policy import tensorpolicy
 from fabric_mod_tpu.protos import batchdecode
@@ -168,13 +169,19 @@ class StagedBlock:
     the block's tensor-policy session: resolve_mask hands it the
     verify mask BEFORE the host sync, so a device-resident mask flows
     straight into the jitted policy program (fused downstream of the
-    batch verify) while the host copy is still materializing."""
+    batch verify) while the host copy is still materializing.
+
+    `gate` (None unless the block was staged with its block
+    signatures, as the deliver client's blocks are) is the pending
+    BlockValidation evaluation of the orderer's signature set: its
+    items rode this block's own batch, and `finish` reads its verdict
+    before anything else."""
 
     __slots__ = ("block", "validator", "works", "mask_fn", "_mask",
-                 "trace_timeline", "session", "rwsets")
+                 "trace_timeline", "session", "rwsets", "gate")
 
     def __init__(self, block, validator, works, mask_fn, session=None,
-                 rwsets=None):
+                 rwsets=None, gate=None):
         self.block = block
         self.validator = validator
         self.works = works
@@ -186,6 +193,7 @@ class StagedBlock:
         # BlockRWSets | None) — commit_block's vectorized MVCC
         # consumes them so the block's tx bodies are decoded ONCE
         self.rwsets = rwsets
+        self.gate = gate
 
     def resolve_mask(self):
         """Await the device verdicts (idempotent).  The commit
@@ -539,13 +547,21 @@ class TxValidator:
         return key_evals
 
     # -- the three passes -------------------------------------------------
-    def stage(self, block: m.Block) -> "StagedBlock":
+    def stage(self, block: m.Block,
+              block_gate: Optional[tuple] = None) -> "StagedBlock":
         """Passes 1+2: host unpack/staging, then DISPATCH the device
         batch without awaiting it.  The returned StagedBlock carries
         the pending verdicts; `finish` resolves them.  Staging block
         N+1 while block N commits is the commit pipeline's double
         buffer — legal exactly when block N sets no state the staging
-        reads (see StagedBlock.needs_barrier)."""
+        reads (see StagedBlock.needs_barrier).
+
+        `block_gate` is (policy, signed_datas): the orderer's block
+        signatures (peer/mcs.check_block) and the BlockValidation
+        policy in force now.  Their items join this block's batch
+        AFTER the transactions' (a batch past the widest bucket chunks
+        as before; the extra lane lands in the last chunk), and
+        `finish` refuses the block unless the policy is satisfied."""
         works: List[_TxWork] = []
         collector = BatchCollector()
         session = None
@@ -612,6 +628,11 @@ class TxValidator:
                               instances=len(session),
                               fallbacks=session.fallbacks):
                 session.finalize()
+        gate = None
+        n_tx_items = len(collector.items)
+        if block_gate is not None:
+            policy, signed_datas = block_gate
+            gate = policy.prepare(signed_datas, collector)
 
         # pass 2: dispatch the device batch (async when the verifier
         # supports it; the resolver blocks only when called).  Repeats
@@ -651,7 +672,12 @@ class TxValidator:
             chunks = getattr(mask_fn, "chunks", None)
             if chunks is not None:
                 dispatch_span.set(chunks=chunks)
-        return StagedBlock(block, self, works, mask_fn, session, rwsets)
+            if gate is not None:
+                # block-signature items that rode this batch
+                dispatch_span.set(
+                    block_sigs=len(collector.items) - n_tx_items)
+        return StagedBlock(block, self, works, mask_fn, session, rwsets,
+                           gate)
 
     def finish(self, staged: "StagedBlock") -> List[int]:
         """Pass 3: await the device verdicts, then sequential flag
@@ -660,6 +686,11 @@ class TxValidator:
         effects of earlier VALID ones."""
         block, works = staged.block, staged.works
         mask = staged.resolve_mask()
+        if staged.gate is not None and not staged.gate.finish(mask):
+            # the MCS gate, deferred: nothing of this block has been
+            # flagged, applied or committed, and the caller's pipeline
+            # commits nothing staged after it
+            raise signatures_unsatisfied(block.header.number)
         session = staged.session
         if session is not None and len(session):
             # ONE evaluator pass produces every chaincode-level and

@@ -416,13 +416,16 @@ def test_provider_spans_nest_under_the_seam_that_called(monkeypatch):
 
     checks = [s for s in spans if s["name"] == "mcs_verify"]
     assert checks and all(parent_name(s) == "recv" for s in checks)
-    # the block signature's round trip: marshal and enqueue under it
-    assert all({"der_marshal", "device_enqueue"} <= children(s)
-               for s in checks)
+    # the host half of the gate only: no device call opens under it;
+    # the block signature rides the block's own dispatch
+    assert all(not children(s) for s in checks)
     dispatches = [s for s in spans if s["name"] == "device_dispatch"]
     assert dispatches and all(
         {"der_marshal", "device_enqueue"} <= children(s)
         for s in dispatches)
+    assert all(s["attrs"]["block_sigs"] == 1 for s in dispatches)
+    assert {s["attrs"]["block"] for s in dispatches} == \
+        {s["attrs"]["block"] for s in checks}
     for s in spans:
         if s["name"] in ("der_marshal", "device_enqueue") \
                 and s["thread"] == dispatches[0]["thread"]:
