@@ -629,29 +629,6 @@ def measure_commitpipe(n_blocks: int, txs_per_block: int, depth: int,
             totals = {k: v["secs"]
                       for k, v in tracing.substage_totals().items()}
 
-        # tensor-policy differential arm: with FABRIC_MOD_TPU_TENSOR_
-        # POLICY armed for the arms above, re-run the sync committer
-        # with the knob scrubbed — per-block txflags and the state
-        # fingerprint must be BIT-IDENTICAL tensor-vs-closure before
-        # any rate is reported (the acceptance oracle)
-        from fabric_mod_tpu.utils import knobs as _kn
-        tensor_armed = _kn.get_bool("FABRIC_MOD_TPU_TENSOR_POLICY")
-        closure_rate = None
-        if tensor_armed:
-            saved_tp = os.environ.pop("FABRIC_MOD_TPU_TENSOR_POLICY")
-            try:
-                with tracing.active(False):
-                    cl_flags, cl_fp, cl_rate = run_sync(tmp + "/closure")
-            finally:
-                os.environ["FABRIC_MOD_TPU_TENSOR_POLICY"] = saved_tp
-            if cl_flags != sync_flags or cl_fp != sync_fp:
-                raise AssertionError(
-                    "tensor-policy verdicts/state diverge from the "
-                    "closure path — the tensor compiler is wrong")
-            closure_rate = cl_rate
-            log(f"tensor-vs-closure differential: identical "
-                f"(closure sync {cl_rate:,.0f} tx/s)")
-
     flags_ok = pipe_flags == sync_flags
     state_ok = pipe_fp == sync_fp
     depth1_ok = d1_flags == sync_flags and d1_fp == sync_fp
@@ -677,10 +654,9 @@ def measure_commitpipe(n_blocks: int, txs_per_block: int, depth: int,
             totals.items())},
     }
     bucket_parts = {
-        "stage": ("unpack", "device_dispatch", "policy_gather"),
+        "stage": ("unpack", "device_dispatch"),
         "await": ("verdict_await",),
-        "commit": ("policy_device", "policy_finish", "mvcc",
-                   "ledger_write"),
+        "commit": ("policy_finish", "mvcc", "ledger_write"),
     }
     for bucket, parts in bucket_parts.items():
         have = sum(totals.get(p, 0.0) for p in parts)
@@ -701,10 +677,8 @@ def measure_commitpipe(n_blocks: int, txs_per_block: int, depth: int,
                 f"stage attribution drifted: {bucket} bucket "
                 f"{want:.3f}s vs sub-span sum {have:.3f}s "
                 f"({'+'.join(parts)}) — tolerance {tol:.3f}s")
-    # the headline the vectorized-policy work is judged by: how much
-    # of the commit bucket is still policy evaluation
-    policy_secs = sum(totals.get(p, 0.0)
-                      for p in ("policy_device", "policy_finish"))
+    # how much of the commit bucket is policy evaluation
+    policy_secs = totals.get("policy_finish", 0.0)
     commit_secs = max(tr_secs["commit"], 1e-9)
     attribution["commit_policy_share"] = round(
         policy_secs / commit_secs, 3)
@@ -716,7 +690,7 @@ def measure_commitpipe(n_blocks: int, txs_per_block: int, depth: int,
         raise AssertionError(
             "commitpipe stream produced only VALID flags — the "
             "barrier-dependent verdicts the oracle relies on are gone")
-    out = {
+    return {
         "pipelined_tx_per_sec": round(pipe_rate, 1),
         "sync_tx_per_sec": round(sync_rate, 1),
         "blocks": n_blocks,
@@ -730,12 +704,7 @@ def measure_commitpipe(n_blocks: int, txs_per_block: int, depth: int,
         "traced_identical": True,          # asserted above
         "stage_attribution": attribution,
         "verifier": "sw" if use_sw else "device",
-        "tensor_policy": tensor_armed,
     }
-    if closure_rate is not None:
-        out["tensor_vs_closure_identical"] = True   # asserted above
-        out["closure_sync_tx_per_sec"] = round(closure_rate, 1)
-    return out
 
 
 def _sk(i: int) -> str:
@@ -1035,128 +1004,6 @@ def measure_statescale(sizes, n_blocks: int = 8,
         "blocks": n_blocks, "txs_per_block": txs_per_block,
         "distinct_flags": sorted({f for per in flags0 for f in per}),
         "verifier": "sw", "durable": durable, "traced_arms": True,
-    }
-
-
-def measure_policyeval(n_txs: int, reps: int, use_sw: bool) -> dict:
-    """Tensor-vs-closure policy evaluation A/B over one 2-of-3 block
-    (with deliberate under-endorsed lanes so the verdicts carry
-    signal): the SAME block validated by a closure-path validator and
-    a tensor-path validator, txflags asserted bit-identical BEFORE any
-    rate is reported.  The timed unit is TxValidator.validate — the
-    full stage+finish round including the (shared) verify cost, so the
-    ratio is the honest end-to-end effect, and the substage split
-    shows where the policy milliseconds went."""
-    from fabric_mod_tpu.observability import tracing
-
-    if use_sw:
-        from fabric_mod_tpu.bccsp.sw import SwCSP
-        from fabric_mod_tpu.bccsp.tpu import FakeBatchVerifier
-        verifier = FakeBatchVerifier(SwCSP())
-    else:
-        from fabric_mod_tpu.bccsp.tpu import TpuVerifier
-        verifier = TpuVerifier(cache_size=0)
-    block, make_validator = _block_world(n_txs, under_endorse_every=16)
-
-    def arm_env(armed: bool):
-        if armed:
-            os.environ["FABRIC_MOD_TPU_TENSOR_POLICY"] = "1"
-        else:
-            os.environ.pop("FABRIC_MOD_TPU_TENSOR_POLICY", None)
-
-    def run_once(validator, armed: bool, traced=False):
-        arm_env(armed)
-        if traced:
-            tracing.recorder().reset()
-            with tracing.active():
-                flags = validator.validate(block)
-                totals = {k: round(v["secs"], 4)
-                          for k, v in tracing.substage_totals().items()}
-            return flags, 0.0, totals
-        t0 = time.perf_counter()
-        flags = validator.validate(block)
-        return flags, time.perf_counter() - t0, None
-
-    saved = os.environ.pop("FABRIC_MOD_TPU_TENSOR_POLICY", None)
-    try:
-        v_closure = make_validator(verifier)
-        v_tensor = make_validator(verifier)
-        closure_flags, _, _ = run_once(v_closure, False)  # warm
-        tensor_flags, _, _ = run_once(v_tensor, True)     # warm
-        # INTERLEAVED min-of-k (the measure_marshal stance): the two
-        # arms alternate so noisy-neighbor slowdowns in the shared
-        # pure-python verify hit both alike — end-to-end tx/s is
-        # verify-bound by design, the ratio must not be machine mood
-        closure_best = tensor_best = float("inf")
-        for _ in range(max(reps, 2)):
-            got, dt, _ = run_once(v_closure, False)
-            closure_best = min(closure_best, dt)
-            if got != closure_flags:
-                raise AssertionError(
-                    "policyeval closure verdicts unstable across reps")
-            got, dt, _ = run_once(v_tensor, True)
-            tensor_best = min(tensor_best, dt)
-            if got != tensor_flags:
-                raise AssertionError(
-                    "policyeval tensor verdicts unstable across reps")
-        # substage split of one traced validate per arm: the POLICY
-        # seconds are the A/B's real subject
-        _, _, closure_tot = run_once(v_closure, False,
-                                     traced=True)
-        _, _, tensor_tot = run_once(v_tensor, True, traced=True)
-        # the session's instance/fallback census from one armed staging
-        arm_env(True)
-        staged = v_tensor.stage(block)
-        v_tensor.finish(staged)
-        session = staged.session
-        instances = len(session) if session is not None else 0
-        fallbacks = session.fallbacks if session is not None else 0
-    finally:
-        if saved is None:
-            os.environ.pop("FABRIC_MOD_TPU_TENSOR_POLICY", None)
-        else:
-            os.environ["FABRIC_MOD_TPU_TENSOR_POLICY"] = saved
-
-    closure_rate = n_txs / closure_best
-    tensor_rate = n_txs / tensor_best
-    POLICY_SPANS = ("policy_gather", "policy_device", "policy_finish")
-    closure_policy_s = sum(closure_tot.get(p, 0.0)
-                           for p in POLICY_SPANS)
-    tensor_policy_s = sum(tensor_tot.get(p, 0.0) for p in POLICY_SPANS)
-    log(f"closure policy eval: {closure_rate:,.0f} validated tx/s, "
-        f"policy {closure_policy_s * 1000:.1f} ms/block")
-    log(f"tensor policy eval: {tensor_rate:,.0f} validated tx/s "
-        f"({tensor_rate / closure_rate:.2f}x), policy "
-        f"{tensor_policy_s * 1000:.1f} ms/block "
-        f"({closure_policy_s / max(tensor_policy_s, 1e-9):.1f}x)")
-
-    # -- the verdict gate (before ANY rate is reported) ------------------
-    if tensor_flags != closure_flags:
-        bad = [i for i, (a, b) in enumerate(zip(tensor_flags,
-                                                closure_flags)) if a != b]
-        raise AssertionError(
-            f"tensor policy verdicts diverge from closures at {bad[:10]}")
-    distinct = sorted(set(closure_flags))
-    if distinct == [0]:
-        raise AssertionError(
-            "policyeval block produced only VALID flags — the "
-            "under-endorsed lanes the oracle relies on are gone")
-
-    return {
-        "tensor_tx_per_sec": round(tensor_rate, 1),
-        "closure_tx_per_sec": round(closure_rate, 1),
-        "policy_secs_closure": round(closure_policy_s, 4),
-        "policy_secs_tensor": round(tensor_policy_s, 4),
-        "policy_speedup": round(
-            closure_policy_s / max(tensor_policy_s, 1e-9), 2),
-        "txs": n_txs,
-        "distinct_flags": distinct,
-        "flags_identical": True,            # asserted above
-        "tensor_instances": instances,
-        "tensor_fallbacks": fallbacks,
-        "substage_secs_tensor": dict(sorted(tensor_tot.items())),
-        "substage_secs_closure": dict(sorted(closure_tot.items())),
-        "verifier": "sw" if use_sw else "device",
     }
 
 
@@ -2712,7 +2559,6 @@ def _needs_device(args) -> bool:
     if args.metric in HOST_ONLY_METRICS:
         return False
     backend = {"commitpipe": args.commitpipe_verifier,
-               "policyeval": args.policyeval_verifier,
                "multichannel": args.multichannel_verifier,
                "broadcaststorm": args.storm_verifier}
     return backend.get(args.metric, "device") != "sw"
@@ -2772,22 +2618,14 @@ def _worker_metric(args) -> int:
     #   --mixed-add    -> affine-table mixed-addition ladder
     #   --memo-cache   -> verdict memo-cache size (0 disables)
     #   --inflight     -> in-flight dispatch window depth
-    #   --precision    -> limb matmul precision (BENCH-SCOPED; the env
-    #                     var is only honored through this entrypoint)
-    if args.tensor_policy is not None:
-        if args.tensor_policy:
-            os.environ["FABRIC_MOD_TPU_TENSOR_POLICY"] = "1"
-        else:
-            os.environ.pop("FABRIC_MOD_TPU_TENSOR_POLICY", None)
+    #   --precision    -> limb matmul precision (bench-scoped)
     if args.mixed_add is not None:
         os.environ["FABRIC_MOD_TPU_MIXED_ADD"] = str(args.mixed_add)
     if args.memo_cache is not None:
         os.environ["FABRIC_MOD_TPU_VERDICT_CACHE"] = str(args.memo_cache)
     if args.inflight is not None:
         os.environ["FABRIC_MOD_TPU_INFLIGHT"] = str(args.inflight)
-    precision = (args.precision
-                 or os.environ.get("FABRIC_MOD_TPU_PRECISION", "highest"))
-    if precision.lower() == "high":
+    if args.precision == "high":
         from fabric_mod_tpu.ops import limbs9
         limbs9.set_precision_mode("high")
 
@@ -2958,21 +2796,6 @@ def _worker_metric(args) -> int:
         }
         _emit(out)
         return 0
-    if args.metric == "policyeval":
-        extras = measure_policyeval(
-            max(32, min(args.batch, 1000)), max(1, args.reps),
-            use_sw=args.policyeval_verifier == "sw")
-        rate = extras.pop("tensor_tx_per_sec")
-        out = {
-            "metric": "policyeval_validated_tx_per_sec_2of3",
-            "value": rate,
-            "unit": "tx/s",
-            "vs_baseline": round(
-                rate / extras["closure_tx_per_sec"], 3),
-            **extras,
-        }
-        _emit(out)
-        return 0
     if args.metric == "multichannel":
         # blocks-per-channel scale with --batch at 4 txs/block,
         # floor 4 / cap 32 (the sweep multiplies by channels x points)
@@ -3086,7 +2909,7 @@ def main() -> int:
                     choices=("verify", "block", "e2e", "idemix", "gossip",
                              "marshal", "diffverify", "hashverify",
                              "commitpipe", "broadcaststorm", "soak",
-                             "policyeval", "multichannel",
+                             "multichannel",
                              "deliverfanout", "statescale",
                              "dissemination"),
                     default=None,
@@ -3115,16 +2938,6 @@ def main() -> int:
                     default="device",
                     help="commitpipe: signature backend for BOTH arms "
                          "(sw = no XLA compile; the CPU smoke target)")
-    ap.add_argument("--policyeval-verifier", choices=("device", "sw"),
-                    default="device",
-                    help="policyeval: signature backend for BOTH arms "
-                         "(sw = no XLA compile; the CPU smoke target)")
-    ap.add_argument("--tensor-policy", type=int, choices=(0, 1),
-                    default=None,
-                    help="1: arm FABRIC_MOD_TPU_TENSOR_POLICY for the "
-                         "worker (commitpipe then adds the tensor-vs-"
-                         "closure differential arm); 0: force the "
-                         "closure path")
     ap.add_argument("--peers", type=int, default=None,
                     help="gossip: storm peer count (default 50; the "
                          "metric name carries it); multichannel: the "

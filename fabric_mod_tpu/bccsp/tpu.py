@@ -358,21 +358,6 @@ class TpuVerifier:
     def verify_many(self, items: Sequence[VerifyItem]) -> np.ndarray:
         return self.verify_many_async(items)()
 
-    def verify_many_fused_async(self, items: Sequence[VerifyItem]):
-        """The tensor-policy FUSION seam: identical pipeline to
-        `verify_many_async`, but the resolver hands back the verdict
-        mask in whatever form the winning path produced — a LAZY jax
-        device array when every lane MISSED the memo-cache (cold or
-        disabled), so a downstream jitted program
-        (policy/tensorpolicy.py) consumes the mask without a
-        device->host->device round trip; the cache write-back is then
-        deferred to the resolver's `.writeback()` attribute, which the
-        consumer calls at its own host-sync point.  Batches with cache
-        hits degrade the resolver to the usual numpy mask; verdict
-        VALUES are identical either way, and `np.asarray(resolver())`
-        is always a correct host view."""
-        return self._verify_async(items, keep_device=True)
-
     def verify_many_async(self, items: Sequence[VerifyItem]):
         """Memo-probe + dedup + marshal + DISPATCH, returning a
         zero-arg resolver for the verdicts.  Between dispatch and
@@ -380,10 +365,6 @@ class TpuVerifier:
         for the next bucket — the commit pipeline's double buffer
         (SURVEY §2.9 row 2; reference analog: the payload buffer
         decoupling pull from commit at gossip/state/state.go:583)."""
-        return self._verify_async(items, keep_device=False)
-
-    def _verify_async(self, items: Sequence[VerifyItem],
-                      keep_device: bool):
         n = len(items)
         if n == 0:
             return lambda: np.zeros(0, bool)
@@ -417,35 +398,6 @@ class TpuVerifier:
         # device calls this batch became, for the caller's span
         chunks = getattr(resolve, "chunks", 1)
         miss_idx = np.asarray(miss_lanes)
-
-        if keep_device and len(miss_lanes) == len(uniq_keys):
-            # the fused path: EVERY lane is a miss (cache cold for this
-            # batch, or disabled), so nothing needs host assembly —
-            # hand the raw (possibly device-resident, still-lazy) mask
-            # through; a jax fancy-gather keeps the dedup expansion on
-            # device too.  Cache write-back needs a host sync, so it
-            # is DEFERRED to `.writeback()`, which the consumer calls
-            # at its own sync point (StagedBlock.resolve_mask) — the
-            # default-cache production config keeps the device handoff
-            # live instead of silently degrading to the host branch.
-            identity_lanes = len(uniq_items) == n
-            state: dict = {}
-
-            def finish_fused():
-                raw = state.get("raw")
-                if raw is None:
-                    raw = state["raw"] = resolve()
-                if identity_lanes:
-                    return raw
-                return raw[lanes]
-
-            def writeback() -> None:
-                raw = state.get("raw")
-                if cache is not None and raw is not None:
-                    cache.put_many(uniq_keys, np.asarray(raw, bool))
-            finish_fused.writeback = writeback
-            finish_fused.chunks = chunks
-            return finish_fused
 
         def finish() -> np.ndarray:
             mask = np.asarray(resolve(), bool)  # fmtlint: allow[jax-hot-path] -- THE sanctioned resolve seam: verdicts sync exactly once, in the commit stage, behind the in-flight window
@@ -574,12 +526,6 @@ class FakeBatchVerifier:
         dispatch: the sw verify runs when the resolver is called (in
         the commit stage), preserving the pipeline's thread layout."""
         return lambda: self.verify_many(items)
-
-    def verify_many_fused_async(self, items: Sequence[VerifyItem]):
-        """Host twin of TpuVerifier's fusion seam: the mask is a numpy
-        array, so the tensor-policy session routes it through the
-        vectorized numpy interpreter (no XLA on the sw path)."""
-        return self.verify_many_async(items)
 
 
 # ---------------------------------------------------------------------------

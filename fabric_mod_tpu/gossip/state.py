@@ -13,9 +13,9 @@ poppable, so commit latency is wakeup latency, not a poll interval
 block and idle CPU burn).  The anti-entropy tick keeps its own
 interval, as in the reference's separate goroutine.
 
-With FABRIC_MOD_TPU_COMMIT_PIPELINE set, drained blocks feed the
-channel's shared PipelinedCommitter (peer/commitpipe.py) instead of
-the synchronous store_block — stage(N+1) overlaps finish+commit(N).
+On a channel bound to a shard router, drained blocks feed the
+router's PipelinedCommitter (peer/commitpipe.py) instead of the
+synchronous store_block — stage(N+1) overlaps finish+commit(N).
 """
 from __future__ import annotations
 
@@ -138,9 +138,9 @@ class GossipStateProvider:
         return self.buffer.push(block)
 
     def _commit_pipeline(self):
-        """The channel's shared PipelinedCommitter, when enabled (only
-        peer.Channel exposes one; bare committer stubs in tests
-        don't)."""
+        """The channel's PipelinedCommitter, when it has one (a
+        router-bound peer.Channel; bare committer stubs in tests have
+        no getter)."""
         getter = getattr(self._channel, "commit_pipeline", None)
         return getter() if getter is not None else None
 
@@ -158,8 +158,8 @@ class GossipStateProvider:
         return pipe
 
     def drain(self, max_blocks: int = 1000) -> int:
-        """Commit everything poppable now; returns count.  With the
-        commit pipeline enabled the blocks are SUBMITTED in order and
+        """Commit everything poppable now; returns count.  Through a
+        commit pipeline the blocks are SUBMITTED in order and
         commit asynchronously — `flush()` (or `stop()`) waits them
         out."""
         n = 0

@@ -32,9 +32,7 @@ DECLARED_SPANS: Set[str] = {
     "mcs_verify",
     "mvcc",
     "mvcc_vector",
-    "policy_device",
     "policy_finish",
-    "policy_gather",
     "raft.replicate",
     "recv",
     "relay.push",

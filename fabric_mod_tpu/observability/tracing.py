@@ -37,8 +37,7 @@ and NO span objects are allocated):
   ``start_timeline(consumer, block_num)`` per block; every span that
   finishes while that timeline is installed (``timeline_scope``)
   becomes one of its sub-stage entries (recv, unpack, der_marshal,
-  device_dispatch, verdict_await, policy_gather, policy_device,
-  policy_finish, mvcc, ledger_write,
+  device_dispatch, verdict_await, policy_finish, mvcc, ledger_write,
   fingerprint).  The timeline object itself is the cross-thread
   carrier: the commitpipe stage loop starts it, StagedBlock carries
   it, the commit loop resumes it — one per-block record of where the

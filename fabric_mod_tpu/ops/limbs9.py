@@ -63,11 +63,11 @@ HALF = BASE // 2  # rounding offset
 #
 # The cheaper 3-pass emulation (Precision.HIGH) is exact ONLY for the
 # 0/1 fold matrices — it exists for an on-chip A/B, and it can make
-# verify verdicts silently WRONG if it leaks into production.  The
-# knob is therefore scoped to the bench entrypoint: bench.py calls
-# `set_precision_mode("high")` in its measurement worker; nothing else
-# may.  (ADVICE r5: the old FABRIC_MOD_TPU_PRECISION env var switched
-# every deployment that inherited it, with no runtime guard.)
+# verify verdicts silently WRONG if it leaks into production (on the
+# chip it turns every valid lane False: PERF.md section 6, PR 26).  No
+# environment variable selects it: `set_precision_mode("high")` is
+# called by the benchmark's control (benchmarks/seeds.py --control) and
+# bench.py's --precision, and by nothing else.
 import sys as _sys
 
 PRECISION = jax.lax.Precision.HIGHEST
@@ -100,15 +100,6 @@ def set_precision_mode(mode: str) -> str:
 
 
 from fabric_mod_tpu.utils import knobs as _knobs
-
-if _knobs.get_str("FABRIC_MOD_TPU_PRECISION").lower() == "high":
-    # The env var is no longer honored here (it used to silently change
-    # verify semantics in any process that inherited it).  The bench
-    # worker translates it via set_precision_mode; everyone else gets
-    # default precision and this notice.
-    print("fabric_mod_tpu: ignoring FABRIC_MOD_TPU_PRECISION=high outside "
-          "the bench entrypoint (see ops/limbs9.set_precision_mode)",
-          file=_sys.stderr, flush=True)
 
 _F = jnp.float32
 

@@ -4,7 +4,7 @@ from fabric_mod_tpu.peer.txvalidator import (  # noqa: F401
     Committer, TxValidator, ValidationInfoProvider)
 from fabric_mod_tpu.peer.channel import Channel          # noqa: F401
 from fabric_mod_tpu.peer.commitpipe import (             # noqa: F401
-    PipelinedCommitter, ValidatorCommitTarget, pipeline_depth)
+    PipelinedCommitter, ValidatorCommitTarget)
 from fabric_mod_tpu.peer.chaincode import (              # noqa: F401
     ChaincodeRegistry, ChaincodeStub, KvContract)
 from fabric_mod_tpu.peer.deliverclient import DeliverClient  # noqa: F401

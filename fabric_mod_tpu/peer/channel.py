@@ -19,8 +19,6 @@ from typing import Callable, List, Optional
 from fabric_mod_tpu.channelconfig import (
     Bundle, ConfigTxError, extract_config_update, propose_config_update)
 from fabric_mod_tpu.channelconfig.configtx import config_from_block
-from fabric_mod_tpu.observability.metrics import (MetricOpts,
-                                                  default_provider)
 from fabric_mod_tpu.peer.mcs import (MessageCryptoService,
                                      block_validation_policy)
 from fabric_mod_tpu.peer.txvalidator import (
@@ -33,11 +31,6 @@ from fabric_mod_tpu.concurrency.locks import RegisteredLock
 # Default endorsement policy reference when the namespace has none
 # (reference: lifecycle's default /Channel/Application/Endorsement)
 DEFAULT_ENDORSEMENT_REF = "/Channel/Application/Endorsement"
-
-_REBUILD_OPTS = MetricOpts(
-    "fabric", "commitpipe", "rebuilds_total",
-    help="Poisoned commit pipelines discarded and rebuilt from the "
-         "committed height (one bad block never bricks the channel).")
 
 
 class Channel:
@@ -53,11 +46,7 @@ class Channel:
         self._csp = csp
         self._plugin_registry = plugin_registry
         self._lock = RegisteredLock("peer.channel._lock")
-        self._commit_pipe = None           # lazy; see commit_pipeline()
         self._shard_router = None          # set via use_shard_router()
-        # serializes pipe (re)builds: never held by pipe worker
-        # threads, so the unbounded drain-join inside cannot deadlock
-        self._pipe_rebuild_lock = RegisteredLock("peer.channel._pipe_rebuild_lock")
         if vinfo is None:
             # lifecycle-backed: committed chaincode definitions resolve
             # each namespace's endorsement policy (peer/lifecycle.py)
@@ -151,8 +140,7 @@ class Channel:
             tx_id_exists=self.ledger.tx_id_exists,
             config_apply=self._validate_and_apply_config,
             state_metadata=state_vp,
-            plugin_registry=self._plugin_registry,
-            config_sequence=bundle.sequence)
+            plugin_registry=self._plugin_registry)
         with self._lock:
             self._bundle = bundle
             self._validator = validator
@@ -200,11 +188,11 @@ class Channel:
         """validate -> MVCC -> commit (the reference's coordinator
         StoreBlock composition, gossip/state/state.go:817).
 
-        With FABRIC_MOD_TPU_COMMIT_PIPELINE set, the commit routes
-        through the channel's shared PipelinedCommitter: this call is
-        still synchronous (waits for THIS block's commit, returns its
-        final flags), but overlapping callers pipeline — stage(N+1)
-        proceeds while commit(N) runs."""
+        On a router-bound channel the commit routes through the
+        router's slice-pinned PipelinedCommitter: this call is still
+        synchronous (waits for THIS block's commit, returns its final
+        flags), but overlapping callers pipeline — stage(N+1) proceeds
+        while commit(N) runs."""
         pipe = self.commit_pipeline()
         if pipe is not None:
             try:
@@ -218,7 +206,7 @@ class Channel:
                 # and a gate rejection returns the SAME healthy pipe
                 # so we re-raise without a pointless resubmit.
                 retry = self.commit_pipeline()
-                if retry is None or retry is pipe:
+                if retry is pipe:
                     raise
                 return retry.store_block(block)
         flags = self.validator().validate(block)
@@ -227,86 +215,28 @@ class Channel:
     def use_shard_router(self, router) -> None:
         """Bind this channel to a ChannelShardRouter (sharding/):
         commit_pipeline() then delegates to the router's slice-pinned
-        engine — the router carries the same rebuild-on-poison
-        contract, plus placement.  The router must already hold this
+        engine — the router carries the rebuild-on-poison contract,
+        plus placement.  The router must already hold this
         channel (add_channel); binding is one-way for the channel's
         lifetime (unbinding mid-stream would race two engines onto
-        one ledger).  A knob-built pipe that predates the binding is
-        DRAINED here first, for the same reason — and the router
-        target binds only AFTER that drain, so a direct router caller
-        (submit_block/pipeline_for) cannot build the slice engine
-        while the old one still commits."""
-        with self._pipe_rebuild_lock:
-            with self._lock:
-                old, self._commit_pipe = self._commit_pipe, None
-            if old is not None:
-                old.close()
-            # only after the old engine fully drained: from here on
-            # the router may build, and every commit_pipeline() caller
-            # gets, the slice-pinned engine
-            router.bind_target(self.channel_id, self)
-            with self._lock:
-                self._shard_router = router
+        one ledger)."""
+        router.bind_target(self.channel_id, self)
+        with self._lock:
+            self._shard_router = router
 
     def commit_pipeline(self):
-        """The channel's shared PipelinedCommitter when the
-        FABRIC_MOD_TPU_COMMIT_PIPELINE knob enables one (or a shard
-        router is bound — router-bound channels always pipeline,
-        pinned to their slice), else None.
-        Shared so every commit producer on this channel (gossip drain,
-        store_block callers) feeds ONE in-order pipeline.
-
-        A failed pipeline is sticky only until its error has been
-        surfaced: the caller that hit it gets the exception (from
-        submit/wait), and the next call here discards the poisoned
-        pipe and builds a fresh one from the committed height — the
-        retry semantics the synchronous path always had (one bad
-        block never bricks the channel).  The rebuild fully drains
-        the old engine FIRST (unbounded close, outside self._lock so
-        an in-flight config_apply can still take it) — two engines
-        never run against the ledger at once."""
+        """The router's slice-pinned PipelinedCommitter when a shard
+        router is bound, else None (the synchronous path).  Every
+        commit producer on a bound channel (gossip drain, store_block
+        callers) thereby feeds ONE in-order pipeline, and the router
+        owns its rebuild-on-poison contract: the caller that hit a
+        failed pipe gets the exception, the next call here gets a
+        fresh engine built from the committed height."""
         with self._lock:
             router = self._shard_router
-        if router is not None:
-            return router.pipeline_for(self.channel_id)
-        from fabric_mod_tpu.peer.commitpipe import pipeline_depth
-        depth = pipeline_depth()
-        if depth <= 0:
+        if router is None:
             return None
-        def healthy():
-            with self._lock:
-                pipe = self._commit_pipe
-            return pipe if (pipe is not None and pipe.error is None
-                            and not pipe.closed) else None
-        pipe = healthy()
-        if pipe is not None:
-            return pipe                    # hot path: no rebuild lock
-        with self._pipe_rebuild_lock:
-            with self._lock:
-                router = self._shard_router
-            if router is not None:
-                # a use_shard_router() bind landed while we waited on
-                # the rebuild lock: building a knob pipe now would put
-                # a second engine on the ledger — delegate instead
-                return router.pipeline_for(self.channel_id)
-            pipe = healthy()
-            if pipe is not None:
-                return pipe                # another caller rebuilt
-            with self._lock:
-                old, self._commit_pipe = self._commit_pipe, None
-            if old is not None:
-                old.close()                # join until the engine died
-                # crash-resume observability: a discarded poisoned
-                # engine is the channel's recovery event — a nonzero
-                # rate here is the ops signal that blocks are failing
-                # and being re-driven through fresh pipes
-                default_provider().counter(_REBUILD_OPTS).add(1)
-            from fabric_mod_tpu.peer.commitpipe import PipelinedCommitter
-            pipe = PipelinedCommitter(self, depth=depth,
-                                      consumer="channel")
-            with self._lock:
-                self._commit_pipe = pipe
-            return pipe
+        return router.pipeline_for(self.channel_id)
 
     # pipelined split: stage (host unpack + async device dispatch) may
     # run ahead of the previous block's commit; commit_staged resolves

@@ -38,12 +38,6 @@ committed state.  Everything else about verdict order is already
 safe ahead-of-commit: duplicate-txid and key-level override
 resolution run in `finish`, strictly in block order.
 
-Knob: FABRIC_MOD_TPU_COMMIT_PIPELINE=<depth> (0/unset: disabled, the
-synchronous path everywhere; >=1: consumers route commits through a
-shared PipelinedCommitter of that depth).  The deliver client always
-pipelines (its double buffer predates this engine) and uses the knob
-only to override its default depth of 2.
-
 Every stage is instrumented (MetricsProvider -> opsserver /metrics):
   fabric_commitpipe_stage_seconds    host unpack + dispatch per block
   fabric_commitpipe_await_seconds    device-verdict wait per block
@@ -70,7 +64,6 @@ from fabric_mod_tpu.observability import tracing
 from fabric_mod_tpu.observability.metrics import (MetricOpts,
                                                   default_provider)
 from fabric_mod_tpu.observability.opsserver import default_health
-from fabric_mod_tpu.utils import knobs
 from fabric_mod_tpu.observability.logging import get_logger
 
 log = get_logger("peer.commitpipe")
@@ -90,8 +83,8 @@ _OCCUPANCY_OPTS = MetricOpts(
     "fabric", "commitpipe", "occupancy",
     help="Blocks staged but not yet committed (pipeline fill; bounded "
          "by the configured depth).  Labeled per consumer: multiple "
-         "live engines (a deliver client's private pipe + a channel's "
-         "shared one) must not overwrite each other's fill level.",
+         "live engines (a deliver client's pipe + a shard router's) "
+         "must not overwrite each other's fill level.",
     label_names=("consumer",))
 _BARRIER_OPTS = MetricOpts(
     "fabric", "commitpipe", "barriers_total",
@@ -117,11 +110,9 @@ def _metrics():
 _pipe_seq = itertools.count()
 
 
-def pipeline_depth(default: int = 0) -> int:
-    """The FABRIC_MOD_TPU_COMMIT_PIPELINE knob: pipeline depth, 0 (or
-    unset/garbage) = disabled, i.e. the synchronous commit path."""
-    return max(0, knobs.get_int("FABRIC_MOD_TPU_COMMIT_PIPELINE",
-                                default))
+# The depth every engine is built at.  A third staged block moved no
+# cell on the chip (PERF.md section 6, PR 31: depth 3 beside depth 2).
+DEPTH = 2
 
 
 class ValidatorCommitTarget:
@@ -156,19 +147,17 @@ class PipelinedCommitter:
     submit and are daemons; `close()` drains and joins them.
     """
 
-    def __init__(self, channel, depth: Optional[int] = None,
+    def __init__(self, channel, depth: int = DEPTH,
                  in_queue: int = 8,
                  on_commit: Optional[Callable] = None,
                  on_error: Optional[Callable] = None,
                  consumer: str = "adhoc"):
         """`channel`: stage_block/commit_staged/.ledger (peer.Channel
         or ValidatorCommitTarget).  `depth`: max staged-but-uncommitted
-        blocks (None -> the env knob, floor 1).  `on_commit(block,
-        flags)` fires after each commit, `on_error(exc)` once on the
-        first failure.  `consumer` labels the occupancy gauge (keep
-        the set small: "deliver", "channel", "adhoc")."""
-        if depth is None:
-            depth = pipeline_depth(2)
+        blocks (floor 1).  `on_commit(block, flags)` fires after each
+        commit, `on_error(exc)` once on the first failure.  `consumer`
+        labels the occupancy gauge (keep the set small: "deliver",
+        "shard<slice>", "adhoc")."""
         self._channel = channel
         self.depth = max(1, depth)
         # in-queue: many producers (submit callers + close sentinel),
@@ -221,8 +210,8 @@ class PipelinedCommitter:
         # pipeline flips /healthz — the registry existed since the ops
         # server landed, this is the first commit-path registrant.
         # Keyed per INSTANCE (consumer labels repeat: every channel's
-        # engine is consumer="channel" — a shared key would let the
-        # newest registration mask another channel's poisoned pipe);
+        # deliver client is consumer="deliver" — a shared key would let
+        # the newest registration mask another channel's poisoned pipe);
         # close() unregisters, so the registry tracks live pipes only.
         self._health_key = f"commitpipe[{consumer}#{next(_pipe_seq)}]"
         default_health().register(self._health_key, self._health_check)

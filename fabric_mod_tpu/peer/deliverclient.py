@@ -55,7 +55,7 @@ from fabric_mod_tpu.observability import tracing
 from fabric_mod_tpu.observability.metrics import (MetricOpts,
                                                   default_provider)
 from fabric_mod_tpu.peer.channel import Channel
-from fabric_mod_tpu.peer.commitpipe import PipelinedCommitter, pipeline_depth
+from fabric_mod_tpu.peer.commitpipe import DEPTH, PipelinedCommitter
 from fabric_mod_tpu.peer.mcs import BlockVerificationError
 from fabric_mod_tpu.protos import messages as m
 from fabric_mod_tpu.protos import protoutil
@@ -102,13 +102,11 @@ class DeliverClient:
                  queue_size: int = 8,
                  on_error: Optional[Callable[[Exception], None]] = None,
                  on_commit: Optional[Callable[[m.Block], None]] = None,
-                 depth: Optional[int] = None):
+                 depth: int = DEPTH):
         """`on_commit(block)` fires after each commit — the gossip
         service uses it to fan committed blocks out to non-leader
         peers (reference: the leader's gossip of deliver payloads).
-        `depth` bounds staged-but-uncommitted blocks; default: the
-        FABRIC_MOD_TPU_COMMIT_PIPELINE knob, else 2 (the double
-        buffer this client has always run)."""
+        `depth` bounds staged-but-uncommitted blocks."""
         self._channel = channel
         self._source = source
         # a failover source re-fetches a refused block from another
@@ -119,8 +117,7 @@ class DeliverClient:
         # parks tickless: stop() both flags the loop AND (via the
         # service's on_set hook) notifies the writer's condition
         self._stop = CancellationEvent()
-        self._depth = depth if depth is not None else \
-            (pipeline_depth() or 2)
+        self._depth = depth
         self._queue_size = queue_size
         self._on_error = on_error
         # stage/commit seconds of pipes already closed (run() builds a
@@ -213,11 +210,12 @@ class DeliverClient:
         self._pipe = self._make_pipe()
 
     def _tip_hash(self) -> Optional[bytes]:
-        height = self._channel.ledger.height
-        if height == 0:
+        # the store's own record: a ledger bootstrapped from a
+        # snapshot holds no block below its height to hash
+        ledger = self._channel.ledger
+        if ledger.height == 0:
             return None
-        tip = self._channel.ledger.get_block_by_number(height - 1)
-        return protoutil.block_header_hash(tip.header)
+        return ledger.blockstore.last_block_hash
 
     def _note_rejected(self, number: int) -> None:
         self.rejected.append(number)

@@ -38,7 +38,6 @@ from fabric_mod_tpu.observability.metrics import (MetricOpts,
                                                   default_provider)
 from fabric_mod_tpu.peer.mcs import signatures_unsatisfied
 from fabric_mod_tpu.policy import ApplicationPolicyEvaluator, BatchCollector
-from fabric_mod_tpu.policy import tensorpolicy
 from fabric_mod_tpu.protos import batchdecode
 from fabric_mod_tpu.protos import messages as m
 from fabric_mod_tpu.protos import protoutil
@@ -165,12 +164,6 @@ class StagedBlock:
     engine that staged this block attaches it, the committing side
     resumes it — context propagation by carrying the context.
 
-    `session` (FABRIC_MOD_TPU_TENSOR_POLICY armed only, else None) is
-    the block's tensor-policy session: resolve_mask hands it the
-    verify mask BEFORE the host sync, so a device-resident mask flows
-    straight into the jitted policy program (fused downstream of the
-    batch verify) while the host copy is still materializing.
-
     `gate` (None unless the block was staged with its block
     signatures, as the deliver client's blocks are) is the pending
     BlockValidation evaluation of the orderer's signature set: its
@@ -178,17 +171,16 @@ class StagedBlock:
     before anything else."""
 
     __slots__ = ("block", "validator", "works", "mask_fn", "_mask",
-                 "trace_timeline", "session", "rwsets", "gate")
+                 "trace_timeline", "rwsets", "gate")
 
-    def __init__(self, block, validator, works, mask_fn, session=None,
-                 rwsets=None, gate=None):
+    def __init__(self, block, validator, works, mask_fn, rwsets=None,
+                 gate=None):
         self.block = block
         self.validator = validator
         self.works = works
         self.mask_fn = mask_fn
         self._mask = None
         self.trace_timeline = None
-        self.session = session
         # the stage-time columnar rwset planes (batchdecode.
         # BlockRWSets | None) — commit_block's vectorized MVCC
         # consumes them so the block's tx bodies are decoded ONCE
@@ -205,17 +197,7 @@ class StagedBlock:
             # sub-stage is attributed HERE so neither path can hide it
             with tracing.span("verdict_await",
                               block=self.block.header.number):
-                raw = self.mask_fn()
-                if self.session is not None:
-                    # bind (and, on a device mask, dispatch) the
-                    # whole-block policy program before the host sync
-                    self.session.attach_mask(raw)
-                self._mask = np.asarray(raw, bool)
-                # the fused verify seam defers its verdict-cache
-                # write-back to the consumer's sync point — this is it
-                writeback = getattr(self.mask_fn, "writeback", None)
-                if writeback is not None:
-                    writeback()
+                self._mask = np.asarray(self.mask_fn(), bool)
         return self._mask
 
     @property
@@ -245,17 +227,12 @@ class TxValidator:
                  config_apply: Optional[Callable[[m.Envelope], None]] = None,
                  state_metadata: Optional[Callable[[str, str],
                                                    Optional[bytes]]] = None,
-                 plugin_registry=None,
-                 config_sequence: int = 0):
+                 plugin_registry=None):
         self.channel_id = channel_id
         self._msp_mgr = msp_mgr
         self._policy_eval = policy_eval
         self._verifier = verifier
         self._vinfo = vinfo
-        # keys the tensor-policy principal memo: a validator is built
-        # per bundle, and the sequence makes sure a config update can
-        # never be answered from a previous epoch's principal matrix
-        self._config_seq = config_sequence
         # named validation plugins (reference: handlers/library
         # registry.go:79); definitions naming an unknown plugin fail
         # closed in _stage_tx
@@ -277,7 +254,7 @@ class TxValidator:
     # -- pass 1: host unpack + staging -----------------------------------
     def _stage_tx(self, env: m.Envelope, work: _TxWork,
                   collector: BatchCollector, inblock_vp,
-                  session=None, spine=None, body=None) -> None:
+                  spine=None, body=None) -> None:
         """Syntactic validation + creator/endorsement staging for one
         tx.  Sets work.flag on terminal failure, else leaves VALID
         pending the device verdicts.  `spine` (protos/batchdecode) is
@@ -350,7 +327,7 @@ class TxValidator:
             # this tx's staged body view (single action — the scanner
             # rejects multi-action txs into the fallback), so staging
             # reads fields instead of re-decoding six proto layers
-            self._stage_body(body, work, collector, inblock_vp, session)
+            self._stage_body(body, work, collector, inblock_vp)
             return
 
         # endorsement policy per action (reference: VSCC v20
@@ -389,24 +366,15 @@ class TxValidator:
                                   identity=e.endorser,
                                   signature=e.signature)
                        for e in endorsements]
-                # session rides only through evaluators that opt in;
-                # plugin evaluators keep their 3-arg prepare contract
-                if session is not None and getattr(
-                        evaluator, "supports_tensor_session", False):
-                    cc_pending = evaluator.prepare(
-                        policy_bytes, sds, collector, session)
-                else:
-                    cc_pending = evaluator.prepare(
-                        policy_bytes, sds, collector)
+                cc_pending = evaluator.prepare(policy_bytes, sds, collector)
                 key_evals = self._stage_key_policies(
-                    rwset, sds, collector, inblock_vp, work, session)
+                    rwset, sds, collector, inblock_vp, work)
                 work.actions.append(_ActionEval(cc_pending, key_evals))
         except Exception:
             work.flag = V.INVALID_ENDORSER_TRANSACTION
             return
 
-    def _stage_body(self, body, work, collector, inblock_vp,
-                    session=None) -> None:
+    def _stage_body(self, body, work, collector, inblock_vp) -> None:
         """Stage one scanner-accepted endorser-tx body — the columnar
         twin of _stage_tx's generic action loop, consuming the values
         batchdecode already proved instead of re-decoding them.  Every
@@ -431,15 +399,9 @@ class TxValidator:
                               identity=endorser,
                               signature=signature)
                    for endorser, signature in body.endorsements]
-            if session is not None and getattr(
-                    evaluator, "supports_tensor_session", False):
-                cc_pending = evaluator.prepare(
-                    policy_bytes, sds, collector, session)
-            else:
-                cc_pending = evaluator.prepare(
-                    policy_bytes, sds, collector)
+            cc_pending = evaluator.prepare(policy_bytes, sds, collector)
             key_evals = self._stage_key_policies_columnar(
-                body, sds, collector, inblock_vp, work, session)
+                body, sds, collector, inblock_vp, work)
             work.actions.append(_ActionEval(cc_pending, key_evals))
         except Exception:
             work.flag = V.INVALID_ENDORSER_TRANSACTION
@@ -470,8 +432,7 @@ class TxValidator:
                 pass
         return self._vinfo.validation_info(ns)
 
-    def _stage_key_policies(self, rwset, sds, collector, inblock_vp,
-                            work, session=None):
+    def _stage_key_policies(self, rwset, sds, collector, inblock_vp, work):
         """Stage every candidate key-level endorsement policy of this
         action's written keys (reference: validator_keylevel.go — the
         committed VALIDATION_PARAMETER plus any same-block overrides
@@ -494,11 +455,11 @@ class TxValidator:
                     vp = self._state_metadata(ns, key)
                     if vp:
                         committed_pending = self._policy_eval.prepare(
-                            vp, sds, collector, session)
+                            vp, sds, collector)
                 cands = inblock_vp.get((ns, key), ())
-                inblock = [(idx, self._policy_eval.prepare(
-                    vp, sds, collector, session))
-                           for idx, vp in cands]
+                inblock = [
+                    (idx, self._policy_eval.prepare(vp, sds, collector))
+                    for idx, vp in cands]
                 # EVERY written key gets an eval entry: keys without an
                 # effective VP resolve to None in pass 3 and force the
                 # cc-wide policy — otherwise a tx satisfying one key's
@@ -516,7 +477,7 @@ class TxValidator:
         return key_evals
 
     def _stage_key_policies_columnar(self, body, sds, collector,
-                                     inblock_vp, work, session=None):
+                                     inblock_vp, work):
         """_stage_key_policies over a columnar TxBody: `body.groups`
         is the per-ns-occurrence written view the generic path derives
         from parse_tx_rwset — same occurrence order, same per-
@@ -533,11 +494,11 @@ class TxValidator:
                     vp = self._state_metadata(ns, key)
                     if vp:
                         committed_pending = self._policy_eval.prepare(
-                            vp, sds, collector, session)
+                            vp, sds, collector)
                 cands = inblock_vp.get((ns, key), ())
-                inblock = [(idx, self._policy_eval.prepare(
-                    vp, sds, collector, session))
-                           for idx, vp in cands]
+                inblock = [
+                    (idx, self._policy_eval.prepare(vp, sds, collector))
+                    for idx, vp in cands]
                 key_evals.append(
                     _KeyEval(ns, key, committed_pending, inblock))
             for mkey, entries in metas:
@@ -564,10 +525,6 @@ class TxValidator:
         `finish` refuses the block unless the policy is satisfied."""
         works: List[_TxWork] = []
         collector = BatchCollector()
-        session = None
-        if tensorpolicy.enabled():
-            session = tensorpolicy.TensorSession(self._msp_mgr,
-                                                 self._config_seq)
         # (ns, key) -> [(tx_idx, ApplicationPolicy bytes)]: the
         # VALIDATION_PARAMETER writes of EARLIER txs in this block —
         # the intra-block dependency structure of validator_keylevel.go
@@ -616,18 +573,9 @@ class TxValidator:
                         work.flag = V.BAD_PAYLOAD
                         continue
                 body = rwsets.bodies[idx] if rwsets is not None else None
-                self._stage_tx(env, work, collector, inblock_vp,
-                               session, spine, body)
+                self._stage_tx(env, work, collector, inblock_vp, spine, body)
                 for ns, key, vp in work.vp_writes:
                     inblock_vp.setdefault((ns, key), []).append((idx, vp))
-        if session is not None and len(session):
-            # build the block's dense policy tensors (the MSP
-            # principal matrix lands here, memoized per pair)
-            with tracing.span("policy_gather",
-                              block=block.header.number,
-                              instances=len(session),
-                              fallbacks=session.fallbacks):
-                session.finalize()
         gate = None
         n_tx_items = len(collector.items)
         if block_gate is not None:
@@ -653,16 +601,7 @@ class TxValidator:
         with tracing.span("device_dispatch",
                           block=block.header.number,
                           items=len(collector.items)) as dispatch_span:
-            # with a tensor session, prefer the verifier's FUSED seam:
-            # its resolver may hand back a device-resident mask the
-            # policy program consumes without a host round trip
-            async_fn = None
-            if session is not None:
-                async_fn = getattr(self._verifier,
-                                   "verify_many_fused_async", None)
-            if async_fn is None:
-                async_fn = getattr(self._verifier, "verify_many_async",
-                                   None)
+            async_fn = getattr(self._verifier, "verify_many_async", None)
             if async_fn is not None:
                 mask_fn = async_fn(collector.items)
             else:
@@ -676,8 +615,7 @@ class TxValidator:
                 # block-signature items that rode this batch
                 dispatch_span.set(
                     block_sigs=len(collector.items) - n_tx_items)
-        return StagedBlock(block, self, works, mask_fn, session, rwsets,
-                           gate)
+        return StagedBlock(block, self, works, mask_fn, rwsets, gate)
 
     def finish(self, staged: "StagedBlock") -> List[int]:
         """Pass 3: await the device verdicts, then sequential flag
@@ -691,16 +629,6 @@ class TxValidator:
             # flagged, applied or committed, and the caller's pipeline
             # commits nothing staged after it
             raise signatures_unsatisfied(block.header.number)
-        session = staged.session
-        if session is not None and len(session):
-            # ONE evaluator pass produces every chaincode-level and
-            # key-level verdict of the block (jitted program on a
-            # device mask, vectorized numpy on a host mask); the
-            # host loop below then reads precomputed booleans
-            with tracing.span("policy_device",
-                              block=block.header.number,
-                              instances=len(session)):
-                session.verdicts()
         flags: List[int] = []
         seen_txids = set()
         applied_vp: Dict[tuple, int] = {}   # (ns, key) -> writer tx_idx

@@ -5,10 +5,7 @@ the "AND('Org1.member', ...)" DSL (policydsl), organize policies into
 the channel's hierarchical manager (manager), and evaluate application
 endorsement policies (application).  Evaluation is two-phase so a
 block's worth of policy checks share ONE device batch-verify —
-see cauthdsl.py's module docstring — and, with
-FABRIC_MOD_TPU_TENSOR_POLICY armed, a whole block's policy verdicts
-evaluate as dense tensors in one program fused downstream of that
-batch verify (tensorpolicy).
+see cauthdsl.py's module docstring.
 """
 from fabric_mod_tpu.policy.cauthdsl import (  # noqa: F401
     BatchCollector, CompiledPolicy, PendingEval, PolicyError)

@@ -17,11 +17,6 @@ from fabric_mod_tpu.protos.protoutil import SignedData
 
 
 class ApplicationPolicyEvaluator:
-    # the validator passes its tensor session only to evaluators that
-    # declare this — third-party validation plugins keep the 3-arg
-    # prepare(policy, sds, collector) contract untouched
-    supports_tensor_session = True
-
     def __init__(self, msp_mgr,
                  channel_policy_manager: Optional[PolicyManager] = None,
                  sequence: int = 0):
@@ -69,9 +64,8 @@ class ApplicationPolicyEvaluator:
 
     def prepare(self, policy_bytes: bytes,
                 signed_datas: Sequence[SignedData],
-                collector: BatchCollector, session=None):
-        return self._resolve(policy_bytes).prepare(
-            signed_datas, collector, session)
+                collector: BatchCollector):
+        return self._resolve(policy_bytes).prepare(signed_datas, collector)
 
     def evaluate(self, policy_bytes: bytes,
                  signed_datas: Sequence[SignedData],

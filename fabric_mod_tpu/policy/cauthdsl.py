@@ -138,43 +138,20 @@ class CompiledPolicy:
     (reference: cauthdsl/policy.go `policy` + the provider at :25)
     """
 
-    # sentinel: tensor compilation not attempted yet (None is a valid
-    # outcome meaning "non-tensorizable")
-    _TENSOR_UNSET = object()
-
     def __init__(self, envelope: m.SignaturePolicyEnvelope, msp_mgr):
         if envelope.rule is None:
             raise PolicyError("policy envelope has no rule")
         self._msp_mgr = msp_mgr
         self._closure = _compile(envelope.rule, envelope.identities, msp_mgr)
         self.envelope = envelope
-        self._tensor = CompiledPolicy._TENSOR_UNSET
-
-    def tensor_program(self):
-        """The policy's flattened tensor form (policy/tensorpolicy.py),
-        compiled once and cached; None when the tree is
-        non-tensorizable (over the caps) and evaluations must stay on
-        the closure path."""
-        if self._tensor is CompiledPolicy._TENSOR_UNSET:
-            from fabric_mod_tpu.policy.tensorpolicy import (
-                compile_tensor_program)
-            self._tensor = compile_tensor_program(self.envelope)
-        return self._tensor
 
     # -- phase 1: dedup + validate + stage verifies ----------------------
     def prepare(self, signed_datas: Sequence[SignedData],
-                collector: BatchCollector, session=None):
+                collector: BatchCollector):
         """Dedup identities, drop undeserializable/invalid ones, stage
         each survivor's signature check into `collector` (reference:
         common/policies/policy.go:365-403, which dedups then verifies
-        every signature before the policy walk).
-
-        With a `session` (policy/tensorpolicy.TensorSession) the
-        evaluation registers as one row of the block's dense tensors
-        and the returned pending resolves from the session's single
-        whole-block evaluator pass; without one (or when this policy
-        is non-tensorizable) the classic closure PendingEval comes
-        back — verdicts are identical either way."""
+        every signature before the policy walk)."""
         idents: List = []
         slots: List[tuple] = []
         seen = set()
@@ -196,10 +173,6 @@ class CompiledPolicy:
             else:                             # non-P256: host verify now
                 slots.append((None, ident.verify(sd.data, sd.signature)))
             idents.append(ident)
-        if session is not None:
-            pending = session.stage(self.tensor_program(), idents, slots)
-            if pending is not None:
-                return pending
         return PendingEval(self._closure, idents, slots)
 
     def satisfied_by_principals(self, idents: Sequence) -> bool:

@@ -58,12 +58,9 @@ class ImplicitMetaPolicyObj:
             self.threshold = 1
 
     def prepare(self, signed_datas: Sequence[SignedData],
-                collector: BatchCollector, session=None):
-        # the meta threshold itself is a trivial host sum; the
-        # sub-policies each ride the tensor session when they can
+                collector: BatchCollector):
         return _MetaPending(
-            [s.prepare(signed_datas, collector, session)
-             for s in self._subs],
+            [s.prepare(signed_datas, collector) for s in self._subs],
             self.threshold)
 
     def evaluate_signed_data(self, signed_datas: Sequence[SignedData],

@@ -10,8 +10,7 @@ layer that turns K chips x N channels into aggregate throughput:
   with least-loaded assignment and bounded rebalance on join/leave;
 * :mod:`router` — :class:`ChannelShardRouter`, which pins each
   channel's :class:`~fabric_mod_tpu.peer.commitpipe.PipelinedCommitter`
-  and tensor-policy sessions (via the slice verifier its validator
-  stages against) to its slice;
+  (via the slice verifier its validator stages against) to its slice;
 * :mod:`verifyservice` — :class:`CrossChannelVerifyService`, the
   generalization of :class:`~fabric_mod_tpu.bccsp.tpu.
   BatchingVerifyService` from one program to a service: ONE flusher

@@ -10,15 +10,14 @@ composes the three pieces:
 * one verifier PER SLICE (production: ``TpuVerifier(mesh=slice)``
   over ``parallel.slice_meshes``; host mode: whatever
   `verifier_factory` returns) — each channel's validator stages its
-  whole-block fused dispatches (and with them its tensor-policy
-  sessions, policy/tensorpolicy.py) against its slice's verifier, so
-  N channels' block programs run side by side on disjoint devices;
+  whole-block dispatches against its slice's verifier, so N channels'
+  block programs run side by side on disjoint devices;
 * one :class:`~fabric_mod_tpu.sharding.verifyservice.
   CrossChannelVerifyService` over those verifiers — the shared
   small-verify front door every channel's gossip/MCS/config checks
   coalesce through;
 * one :class:`~fabric_mod_tpu.peer.commitpipe.PipelinedCommitter`
-  per channel, consumer-labeled by slice, with the peer.Channel
+  per channel, consumer-labeled by slice, with the
   rebuild-on-poison contract: a failed pipe surfaces its error to
   the caller that hit it, then the next `pipeline_for` drains the
   corpse and rebuilds from the committed height — one bad block
@@ -40,6 +39,7 @@ from fabric_mod_tpu.concurrency import RegisteredLock
 from fabric_mod_tpu.observability.logging import get_logger
 from fabric_mod_tpu.observability.metrics import (MetricOpts,
                                                   default_provider)
+from fabric_mod_tpu.peer.commitpipe import DEPTH, PipelinedCommitter
 from fabric_mod_tpu.sharding.shardmap import ShardMap
 from fabric_mod_tpu.sharding.verifyservice import CrossChannelVerifyService
 from fabric_mod_tpu.utils import knobs
@@ -66,27 +66,12 @@ def shard_count(default: int = 0) -> int:
     return max(0, knobs.get_int("FABRIC_MOD_TPU_SHARDS", default))
 
 
-def shard_depth() -> int:
-    """Per-channel commit-pipeline depth under the router: the
-    FABRIC_MOD_TPU_SHARD_DEPTH knob, falling back to
-    FABRIC_MOD_TPU_COMMIT_PIPELINE, and to depth 2 (the deliver
-    client's default) when both are unset — floor 1 either way:
-    router-bound channels always pipeline; serial behavior is depth
-    1, not 'no engine'."""
-    d = knobs.get_int("FABRIC_MOD_TPU_SHARD_DEPTH")
-    if d <= 0:
-        from fabric_mod_tpu.peer.commitpipe import pipeline_depth
-        d = pipeline_depth(2)
-    return max(1, d)
-
-
 class ChannelVerifyHandle:
     """The per-channel verifier facade a Channel/TxValidator holds.
 
-    Whole-block lanes (`verify_many_async`, `verify_many_fused_async`
-    — the validator's staging seams, and with them the tensor-policy
-    sessions) go STRAIGHT to the channel's slice verifier: they are
-    already full fused dispatches, pinned to the slice mesh.  The
+    The whole-block lane (`verify_many_async`, the validator's
+    staging seam) goes STRAIGHT to the channel's slice verifier: it is
+    already a full dispatch, pinned to the slice mesh.  The
     small-verify lane (`verify_many`, `submit` — MCS block checks,
     config signature sets) rides the SHARED cross-channel service,
     tagged, so it coalesces with every other channel's traffic.
@@ -109,13 +94,6 @@ class ChannelVerifyHandle:
     # -- whole-block lane (slice-pinned) ---------------------------------
     def verify_many_async(self, items: Sequence[VerifyItem]):
         return self._slice_verifier().verify_many_async(items)
-
-    def verify_many_fused_async(self, items: Sequence[VerifyItem]):
-        v = self._slice_verifier()
-        fn = getattr(v, "verify_many_fused_async", None)
-        if fn is not None:
-            return fn(items)
-        return v.verify_many_async(items)
 
     # -- small-verify lane (shared, coalesced, tagged) -------------------
     def verify_many(self, items: Sequence[VerifyItem]):
@@ -152,7 +130,7 @@ class ChannelShardRouter:
 
     def __init__(self, n_slices: Optional[int] = None, meshes=None,
                  verifier_factory: Optional[Callable] = None,
-                 depth: Optional[int] = None, rebalance: bool = True,
+                 depth: int = DEPTH, rebalance: bool = True,
                  max_batch: int = 2048, deadline_s: float = 0.002):
         if n_slices is None:
             n_slices = max(1, shard_count())
@@ -255,7 +233,7 @@ class ChannelShardRouter:
     # -- per-channel commit engines --------------------------------------
     def pipeline_for(self, channel_id: str):
         """The channel's slice-pinned PipelinedCommitter, with the
-        peer.Channel rebuild-on-poison contract: a healthy pipe is
+        rebuild-on-poison contract: a healthy pipe is
         returned lock-free-ish; a poisoned/closed one is drained and
         replaced (two engines never run against one ledger at once).
         """
@@ -288,13 +266,10 @@ class ChannelShardRouter:
             if old is not None:
                 old.close()                # drain the poisoned engine
                 self._m_rebuilds.add(1)
-            from fabric_mod_tpu.peer.commitpipe import PipelinedCommitter
-            depth = self._depth if self._depth is not None \
-                else shard_depth()
             with self._lock:
                 slice_idx = self.map.slice_of(channel_id, 0)
             pipe = PipelinedCommitter(
-                b.target, depth=depth,
+                b.target, depth=self._depth,
                 consumer=f"shard{slice_idx}")
             with self._lock:
                 b.pipe = pipe

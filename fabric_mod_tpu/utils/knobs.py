@@ -193,10 +193,6 @@ declare("FABRIC_MOD_TPU_PALLAS", "bool", None,
 declare("FABRIC_MOD_TPU_FUSED_HASH", "bool", None,
         "1 makes msp identities emit raw-message verify items: "
         "SHA-256 on device in the same jitted program as the verify")
-declare("FABRIC_MOD_TPU_PRECISION", "str", None,
-        "bench-scoped ONLY: \"high\" selects the 3-pass limb-matmul "
-        "emulation via set_precision_mode; ignored (with a notice) "
-        "everywhere else")
 declare("FABRIC_MOD_TPU_UNROLL_LOW_CARRY", "bool", None,
         "1 defaults the unrolled low-carry lane on (bench A/B seam; "
         "set_unroll_low_carry overrides per thread)")
@@ -221,14 +217,6 @@ declare("FABRIC_MOD_TPU_BREAKER_PROBE_S", "float", 5.0,
         "disables the prober thread")
 
 # -- commit path ------------------------------------------------------------
-declare("FABRIC_MOD_TPU_COMMIT_PIPELINE", "int", 0,
-        "pipeline depth for the gossip drain loop and "
-        "Channel.store_block; 0/unset = synchronous")
-declare("FABRIC_MOD_TPU_TENSOR_POLICY", "bool", None,
-        "1 evaluates a whole block's policy verdicts as dense "
-        "mask/threshold tensors in one program fused downstream of "
-        "the batch verify (non-tensorizable trees fall back per "
-        "policy); unset = the closure path")
 declare("FABRIC_MOD_TPU_VECTOR_MVCC", "bool", None,
         "1 runs MVCC over the columnar rwset planes batch-decoded at "
         "stage time: ONE get_versions_many statedb call per block "
@@ -240,11 +228,6 @@ declare("FABRIC_MOD_TPU_VECTOR_MVCC", "bool", None,
 declare("FABRIC_MOD_TPU_SHARDS", "int", 0,
         "mesh slices the channel-shard router carves (sharding/); "
         "0/unset = sharding disabled (single-slice behavior)")
-declare("FABRIC_MOD_TPU_SHARD_DEPTH", "int", 0,
-        "per-channel commit-pipeline depth under the shard router; "
-        "0 = fall back to FABRIC_MOD_TPU_COMMIT_PIPELINE, defaulting "
-        "to depth 2 when that is unset too (floor 1 — router-bound "
-        "channels always pipeline)")
 declare("FABRIC_MOD_TPU_SHARD_HOSTS", "int", 1,
         "expected jax.distributed process count of the multi-host "
         "spec (sharding/multihost.py); >1 is specified but stubbed — "

@@ -86,16 +86,13 @@ FMT_RACECHECK=1 JAX_PLATFORMS=cpu python -m pytest -q \
 FMT_TRACE=1 FMT_RACECHECK=1 JAX_PLATFORMS=cpu python -m pytest -q \
     -p no:cacheprovider -p no:randomly -m 'not slow' \
     tests/test_tracing.py tests/test_commitpipe.py
-# 0f. the tensor-policy slice: the randomized tree differential
-#     (tensor verdicts == closure verdicts incl. the greedy used-flag
-#     edge cases), the numpy-vs-jax evaluator identity, the
-#     non-tensorizable fallback path, the batch spine-decode
-#     value-identity + fuzz, and the block-level differential through
-#     the real validator — the tensor compiler is re-proven against
-#     the closures on every change
+# 0f. the policy slice: the closure walk against a plain statement
+#     of cauthdsl.go's rule over seeded trees (incl. the greedy
+#     used-flag edge cases), and the batch spine-decode
+#     value-identity + fuzz
 JAX_PLATFORMS=cpu python -m pytest -q \
     -p no:cacheprovider -p no:randomly -m 'not slow' \
-    tests/test_tensorpolicy.py tests/test_protos.py
+    tests/test_policy.py tests/test_protos.py
 # 0g. the shard slice, FMT_RACECHECK=1 over 8 fake host devices (the
 #     conftest forces xla_force_host_platform_device_count=8): slice
 #     meshes carve the virtual device set and run the REAL
@@ -189,10 +186,7 @@ export FABRIC_MOD_TPU_BENCH_TIMEOUT="${FABRIC_MOD_TPU_BENCH_TIMEOUT:-2400}"
 # the unthrottled staged-vs-unstaged pair on the sw verifier (the
 # correctness/consistency gate of the staged engine at smoke scale —
 # the batch-ECONOMICS curve needs the device verifier, on the chip)
-# commitpipe runs TENSOR-ARMED (--tensor-policy 1): its gates then
-# include the tensor-vs-closure txflags + state-fingerprint identity
-# on top of the pipelined/sync/traced differentials; policyeval is
-# the dedicated tensor-vs-closure A/B over one mixed-verdict block
+# commitpipe: the pipelined/sync/depth1/traced differentials
 # multichannel: the channel-sharded scale sweep on host-mode slices
 # (sw verifiers, no XLA) — every point's per-channel txflags + state
 # fingerprints gate bit-identical sharded-vs-N-independent-unsharded
@@ -207,8 +201,7 @@ export FABRIC_MOD_TPU_BENCH_TIMEOUT="${FABRIC_MOD_TPU_BENCH_TIMEOUT:-2400}"
 # 100k point run on every change; the 1M point is not smoke-scale
 exec python bench.py --cpu --batch "${SMOKE_BATCH:-64}" --reps 1 \
     --metric diffverify --metric hashverify \
-    --metric commitpipe --commitpipe-verifier sw --tensor-policy 1 \
-    --metric policyeval --policyeval-verifier sw \
+    --metric commitpipe --commitpipe-verifier sw \
     --metric broadcaststorm --clients 4 --staged-batch 32 \
     --metric multichannel --multichannel-verifier sw --peers 8 \
     --metric deliverfanout --subscribers 400 \

@@ -111,34 +111,6 @@ def test_sha256_batch_hash_2048x4_blocks(one_chip, no_persistent_cache):
     assert out.shape == (BUCKET, 8) and out.dtype == jnp.uint32
 
 
-def test_tensor_policy_scan_512_instances(one_chip, no_persistent_cache):
-    """The jitted whole-block policy evaluator at the shapes
-    `TensorSession._pad_for_device` gives 512 staged instances of a
-    500-tx block against the 2048-lane verify mask."""
-    from fabric_mod_tpu.policy import tensorpolicy as tp
-    n = tp._pow2_at_least(512, 8)
-    t_ops = tp._pow2_at_least(1, 16)
-    assert (n, t_ops) == (512, 16)
-
-    def s(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    args = (s((tp._pow2_at_least(BUCKET, 64),), jnp.bool_),   # mask
-            s((n, tp.MAX_IDENTS), jnp.int32),                 # gather
-            s((n, tp.MAX_IDENTS), jnp.bool_),                 # host_ok
-            s((n, tp.MAX_IDENTS), jnp.bool_),                 # present
-            s((n, tp.MAX_IDENTS, tp.MAX_PRINCIPALS), jnp.bool_),
-            s((t_ops, n), jnp.int32),                         # ops_t
-            s((t_ops, n), jnp.int32))                         # args_t
-    t0 = time.perf_counter()
-    compiled = tp._jax_eval_fn().lower(*args).compile()
-    mem = _report("tensor-policy scan (512 instances)",
-                  time.perf_counter() - t0, compiled)
-    assert mem["total"] < HBM_BYTES, mem
-    (out,) = jax.tree_util.tree_leaves(compiled.out_info)
-    assert out.shape == (n,) and out.dtype == jnp.bool_
-
-
 @pytest.mark.xfail(
     strict=True, raises=NotImplementedError,
     reason="the TPU compiler's verdict on the Pallas ladder, after the "

@@ -23,7 +23,7 @@ from fabric_mod_tpu.msp.identities import SigningIdentity
 from fabric_mod_tpu.msp.mspimpl import Msp, MspManager
 from fabric_mod_tpu.peer import (Committer, PipelinedCommitter,
                                  TxValidator, ValidationInfoProvider,
-                                 ValidatorCommitTarget)
+                                 ValidatorCommitTarget, commitpipe)
 from fabric_mod_tpu.peer.lifecycle import LIFECYCLE_NS
 from fabric_mod_tpu.peer.txvalidator import VALIDATION_PARAMETER
 from fabric_mod_tpu.policy import ApplicationPolicyEvaluator, from_string
@@ -204,12 +204,145 @@ def test_differential_mixed_barrier_stream(sync_ref, pipe_ref):
     assert flat.count(V.VALID) == len(flat) - 2
 
 
-def test_depth1_matches_sync_exactly(world, stream, sync_ref, tmp_path):
-    sync_flags, sync_fp = sync_ref
-    d1_flags, d1_fp, _ = _run_pipelined(world, stream, tmp_path / "d1",
-                                        depth=1)
-    assert d1_flags == sync_flags
-    assert d1_fp == sync_fp
+def _mixed_verdicts(world):
+    """Three blocks of sixteen: valid, under-endorsed, duplicate-
+    endorser and tampered-creator-signature transactions, so the
+    flags carry three codes."""
+    blocks, prev = [], b""
+    for n in range(3):
+        envs = []
+        for j in range(16):
+            i = 16 * n + j
+            if i % 7 == 3:
+                endorsers = ("Org1",)                 # under 2-of-3
+            elif i % 7 == 5:
+                endorsers = ("Org1", "Org1")          # one org twice
+            else:
+                endorsers = ("Org1", "Org2")
+            env = _tx(world, _write("mycc", f"m{i}"), endorsers)
+            if i % 11 == 9:
+                env.signature = bytes(reversed(env.signature))
+            envs.append(env)
+        b = protoutil.new_block(n, prev, envs)
+        prev = protoutil.block_header_hash(b.header)
+        blocks.append(b.encode())
+    return blocks
+
+
+def _inblock_override(world):
+    """A VALIDATION_PARAMETER pin and writes of the pinned key in the
+    SAME block (the candidates `finish` resolves in block order), then
+    a write under the committed pin."""
+    pin = _policy("'Org3.peer'")
+    blocks, prev = [], b""
+    for envs in (
+            [_tx(world, _write("mycc", "pinned", b"v0")),
+             _tx(world, _write("mycc", "o0"))],
+            [_tx(world, _vp_write("pinned", pin)),
+             _tx(world, _write("mycc", "pinned", b"v1")),       # EPF
+             _tx(world, _write("mycc", "pinned", b"v2"),
+                 endorsers=("Org3",)),                           # VALID
+             _tx(world, _write("mycc", "o1"))],
+            [_tx(world, _write("mycc", "pinned", b"v3")),       # EPF
+             _tx(world, _write("mycc", "o2"))]):
+        b = protoutil.new_block(len(blocks), prev, envs)
+        prev = protoutil.block_header_hash(b.header)
+        blocks.append(b.encode())
+    return blocks
+
+
+_CORPORA = {
+    "barrier-stream": (_mixed_stream, {V.VALID,
+                                       V.ENDORSEMENT_POLICY_FAILURE}),
+    "mixed-verdicts": (_mixed_verdicts, {V.VALID,
+                                         V.ENDORSEMENT_POLICY_FAILURE,
+                                         V.BAD_CREATOR_SIGNATURE}),
+    "inblock-override": (_inblock_override, {V.VALID,
+                                             V.ENDORSEMENT_POLICY_FAILURE}),
+}
+
+
+@pytest.fixture(scope="module")
+def corpora(world, stream, sync_ref, tmp_path_factory):
+    """name -> (blocks, flags and state fingerprint of `validate` +
+    commit, block after block: the synchronous Committer)."""
+    got = {"barrier-stream": (stream,) + tuple(sync_ref)}
+    for name in ("mixed-verdicts", "inblock-override"):
+        blocks = _CORPORA[name][0](world)
+        got[name] = (blocks,) + _run_sync(
+            world, blocks, tmp_path_factory.mktemp("cp_" + name))
+    return got
+
+
+@pytest.mark.parametrize("depth", [1, commitpipe.DEPTH])
+@pytest.mark.parametrize("corpus", sorted(_CORPORA))
+def test_pipelined_matches_sync(world, corpora, tmp_path, corpus, depth):
+    """Every corpus through PipelinedCommitter at depth 1 (the serial
+    differential) and at DEPTH (what every engine is built at): flags
+    and state fingerprint are those of `validate` + commit."""
+    blocks, sync_flags, sync_fp = corpora[corpus]
+    flags, fp, pipe = _run_pipelined(world, blocks, tmp_path / "p",
+                                     depth=depth)
+    assert pipe.error is None
+    assert flags == sync_flags
+    assert fp == sync_fp
+    assert {f for per in sync_flags for f in per} == _CORPORA[corpus][1]
+
+
+def test_every_engine_is_built_at_the_one_depth(world, tmp_path):
+    """The deliver client's engine, the shard router's and a bare
+    PipelinedCommitter all take commitpipe.DEPTH when no caller says
+    otherwise: one number, one place."""
+    from fabric_mod_tpu.peer import DeliverClient
+    from fabric_mod_tpu.sharding import ChannelShardRouter
+    led, validator = _make_target(world, tmp_path / "one")
+    target = ValidatorCommitTarget(validator, led)
+    bare = PipelinedCommitter(target)
+    client = DeliverClient(target, source=None)
+    router = ChannelShardRouter(
+        n_slices=1,
+        verifier_factory=lambda i, mesh: FakeBatchVerifier(world["csp"]))
+    try:
+        router.add_channel(CHANNEL, target)
+        assert commitpipe.DEPTH == 2
+        assert bare.depth == client._pipe.depth == \
+            router.pipeline_for(CHANNEL).depth == commitpipe.DEPTH
+    finally:
+        router.close()
+        client._pipe.close()
+        bare.close()
+
+
+@pytest.mark.parametrize("name,value", [
+    ("FABRIC_MOD_TPU_TENSOR_POLICY", "1"),
+    ("FABRIC_MOD_TPU_COMMIT_PIPELINE", "3"),
+    ("FABRIC_MOD_TPU_SHARD_DEPTH", "3"),
+    ("FABRIC_MOD_TPU_PRECISION", "high"),
+])
+def test_retired_knob_is_declared_nowhere_and_read_by_nothing(
+        world, corpora, tmp_path, monkeypatch, name, value):
+    """The four names the chip ruled on: gone from the registry, and a
+    three-block chain validated with the name set gives the flags and
+    the fingerprint of the run without it, through the engine at the
+    depth it always has."""
+    from fabric_mod_tpu.utils import knobs
+    assert name not in knobs.declared()
+    blocks, want_flags, want_fp = corpora["mixed-verdicts"]
+    assert len(blocks) == 3
+    monkeypatch.setenv(name, value)
+    assert _run_sync(world, blocks, tmp_path / "s") == (want_flags,
+                                                        want_fp)
+    led, validator = _make_target(world, tmp_path / "p")
+    flags = []
+    pipe = PipelinedCommitter(
+        ValidatorCommitTarget(validator, led),
+        on_commit=lambda _b, f: flags.append(list(f)))
+    assert pipe.depth == commitpipe.DEPTH
+    for raw in blocks:
+        pipe.submit(m.Block.decode(raw))
+    assert pipe.flush(timeout_s=120.0)
+    pipe.close()
+    assert (flags, led.state_fingerprint()) == (want_flags, want_fp)
 
 
 class _Recorder:
@@ -412,67 +545,6 @@ def test_gossip_drain_through_pipeline(world, simple4, tmp_path):
     for i in range(len(simple4)):
         blk = chan.ledger.get_block_by_number(i)
         assert list(protoutil.block_txflags(blk)) == [V.VALID]
-    chan.commit_pipeline().close()
-
-
-def test_channel_store_block_routes_through_knob(world, tmp_path,
-                                                 monkeypatch):
-    """A real peer.Channel: FABRIC_MOD_TPU_COMMIT_PIPELINE unset keeps
-    the synchronous path (commit_pipeline() is None); set, store_block
-    routes through the channel's shared PipelinedCommitter and still
-    returns each block's final flags."""
-    from fabric_mod_tpu.channelconfig import Bundle, genesis
-    from fabric_mod_tpu.channelconfig.configtx import config_from_block
-    from fabric_mod_tpu.peer.channel import Channel
-
-    ca = calib.CA("ca.knob", "Org1")
-    gen = genesis.standard_network(
-        "knobch", {"Org1": [calib.cert_pem(ca.cert)]},
-        {"OrdererOrg": [calib.cert_pem(ca.cert)]})
-    _, config = config_from_block(gen)
-    bundle = Bundle("knobch", config, world["csp"])
-    led = KvLedger(str(tmp_path / "knob"), "knobch")
-    monkeypatch.delenv("FABRIC_MOD_TPU_COMMIT_PIPELINE", raising=False)
-    chan = Channel("knobch", led, FakeBatchVerifier(world["csp"]),
-                   bundle, world["csp"])
-    chan.init_from_genesis(gen)
-    assert chan.commit_pipeline() is None
-
-    monkeypatch.setenv("FABRIC_MOD_TPU_COMMIT_PIPELINE", "3")
-    pipe = chan.commit_pipeline()
-    assert pipe is not None and pipe.depth == 3
-    assert chan.commit_pipeline() is pipe      # shared, lazy singleton
-    prev = protoutil.block_header_hash(gen.header)
-    for i in range(1, 4):
-        # a well-formed tx for the WRONG channel: decodes everywhere,
-        # fails validation — commits with its flag set, proving the
-        # store_block call went through the pipeline end to end
-        blk = protoutil.new_block(
-            i, prev, [_tx(world, _write("mycc", f"n{i}"))])
-        prev = protoutil.block_header_hash(blk.header)
-        flags = chan.store_block(blk)
-        assert flags == [V.BAD_CHANNEL_HEADER]  # committed, flagged
-    assert led.height == 4
-
-    # a misordered submit is arbitrated at the gate: its caller gets
-    # the error and the pipe stays healthy (no rebuild)
-    rogue = protoutil.new_block(9, b"", [_tx(world, _write("mycc", "r"))])
-    with pytest.raises(Exception, match="out of order"):
-        chan.store_block(rogue)
-    assert chan.commit_pipeline() is pipe
-
-    # a real commit failure (right number, wrong prev-hash) poisons
-    # the pipe; its error surfaces to ITS caller, and the next commit
-    # gets a rebuilt pipe — one bad block never bricks the channel
-    bad_prev = protoutil.new_block(4, b"\x00" * 32,
-                                   [_tx(world, _write("mycc", "bp"))])
-    with pytest.raises(Exception, match="previous_hash"):
-        chan.store_block(bad_prev)
-    blk4 = protoutil.new_block(4, prev,
-                               [_tx(world, _write("mycc", "n4"))])
-    assert chan.store_block(blk4) == [V.BAD_CHANNEL_HEADER]
-    assert led.height == 5
-    assert chan.commit_pipeline() is not pipe  # rebuilt after the error
     chan.commit_pipeline().close()
 
 

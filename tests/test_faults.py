@@ -373,7 +373,7 @@ def deliver_net(tmp_path_factory):
     net.close()
 
 
-def _fresh_peer_channel(net, root):
+def _fresh_peer_channel(net, root, verifier=None):
     """A second committing peer for the same channel: fresh ledger,
     same genesis — the uninterrupted differential arm."""
     from fabric_mod_tpu.bccsp.tpu import FakeBatchVerifier
@@ -383,7 +383,8 @@ def _fresh_peer_channel(net, root):
     from fabric_mod_tpu.peer.channel import Channel
     _, config = config_from_block(net.genesis_block)
     led = KvLedger(str(root), net.channel_id)
-    chan = Channel(net.channel_id, led, FakeBatchVerifier(net.csp),
+    chan = Channel(net.channel_id, led,
+                   verifier or FakeBatchVerifier(net.csp),
                    Bundle(net.channel_id, config, net.csp), net.csp)
     if led.height == 0:
         chan.init_from_genesis(net.genesis_block)
@@ -466,6 +467,35 @@ def test_failover_source_survives_the_same_drop(deliver_net, tmp_path):
         srv_b.stop()
 
 
+def test_snapshot_joined_peer_pulls_the_tail(deliver_net, tmp_path):
+    """A ledger bootstrapped from a snapshot holds no block below its
+    height: the deliver client's chain check starts from the store's
+    record of the tip's hash (it read the tip block, and a
+    snapshot-joined peer that won deliver leadership died at every
+    start: the soak's peer_join, now and then)."""
+    from fabric_mod_tpu.ledger.snapshot import bootstrap_from_snapshot
+    from fabric_mod_tpu.peer.deliverclient import DeliverClient
+    net = deliver_net
+    tip = net.support.store.height
+    src = _fresh_peer_channel(net, tmp_path / "snap_src")
+    DeliverClient(src, net.deliver).run(stop_at=2, idle_timeout_s=5.0)
+    assert src.ledger.height == 3
+    src.ledger.snapshot_to(str(tmp_path / "snap"))
+    bootstrap_from_snapshot(str(tmp_path / "snap"),
+                            str(tmp_path / "snap_joined")).close()
+    joined = _fresh_peer_channel(net, tmp_path / "snap_joined")
+    assert joined.ledger.height == 3
+    assert joined.ledger.get_block_by_number(2) is None
+    client = DeliverClient(joined, net.deliver)
+    client.run(stop_at=tip - 1, idle_timeout_s=5.0)
+    assert client.rejected == []
+    assert joined.ledger.height == tip
+    DeliverClient(src, net.deliver).run(stop_at=tip - 1,
+                                        idle_timeout_s=5.0)
+    assert joined.ledger.state_fingerprint() == \
+        src.ledger.state_fingerprint()
+
+
 # ---------------------------------------------------------------------------
 # commit pipeline: crash mid-stream, resume from ledger height
 # ---------------------------------------------------------------------------
@@ -515,33 +545,76 @@ def test_commitpipe_crash_resume_fingerprint(deliver_net, tmp_path):
     pipe3.close()
 
 
-def test_channel_store_block_retries_through_fresh_pipe(
-        deliver_net, tmp_path, monkeypatch):
-    """Channel.store_block's rebuild path under an injected engine
-    crash: the caller's block still commits (one retry through a
-    rebuilt pipe), the channel is not bricked, state matches sync."""
-    monkeypatch.setenv("FABRIC_MOD_TPU_COMMIT_PIPELINE", "2")
+def _router_bound_channel(net, root):
+    """A peer channel committing through a one-slice shard router (the
+    one engine a `Channel` hands out); returns (channel, router)."""
+    from fabric_mod_tpu.bccsp.tpu import FakeBatchVerifier
+    from fabric_mod_tpu.sharding import ChannelShardRouter
+    router = ChannelShardRouter(
+        n_slices=1,
+        verifier_factory=lambda i, mesh: FakeBatchVerifier(net.csp))
+    chan = _fresh_peer_channel(net, root,
+                               verifier=router.add_channel(net.channel_id))
+    chan.use_shard_router(router)
+    return chan, router
+
+
+def test_channel_commit_pipeline_is_the_routers_engine_or_none(
+        deliver_net, tmp_path):
+    """An unbound channel has no engine of its own (`store_block` is
+    the synchronous body); a router-bound one hands out the router's,
+    built at the one depth."""
+    from fabric_mod_tpu.peer import commitpipe
     net = deliver_net
     blocks = [net.support.store.get_block_by_number(n)
               for n in range(1, net.support.store.height)]
-    chan = _fresh_peer_channel(net, tmp_path / "chan_crash")
-    first_pipe = chan.commit_pipeline()
-    assert first_pipe is not None
-    plan = faults.FaultPlan().add("commitpipe.commit", nth=2)
-    with faults.active(plan):
+    ref = _fresh_peer_channel(net, tmp_path / "unbound")
+    assert ref.commit_pipeline() is None
+    for blk in blocks:
+        ref.store_block(blk)
+    assert ref.commit_pipeline() is None
+    chan, router = _router_bound_channel(net, tmp_path / "bound")
+    try:
+        pipe = chan.commit_pipeline()
+        assert pipe is router.pipeline_for(net.channel_id)
+        assert chan.commit_pipeline() is pipe           # one engine
+        assert pipe.depth == commitpipe.DEPTH
         for blk in blocks:
-            chan.store_block(blk)          # no exception surfaces
-    assert plan.fires() == 1
-    rebuilt = chan.commit_pipeline()
-    assert rebuilt is not first_pipe                  # rebuilt
-    assert chan.ledger.height == len(blocks) + 1
-    rebuilt.close()
+            chan.store_block(blk)
+        assert chan.commit_pipeline() is pipe           # still healthy
+    finally:
+        router.close()
+    assert chan.ledger.state_fingerprint() == \
+        ref.ledger.state_fingerprint()
+
+
+def test_router_bound_channel_retries_through_fresh_pipe(
+        deliver_net, tmp_path):
+    """Channel.store_block's retry under an injected engine crash, on
+    the engine the router owns: the caller's block still commits (one
+    retry through the pipe `pipeline_for` rebuilt), the channel is
+    not bricked, state matches the synchronous path."""
+    net = deliver_net
+    blocks = [net.support.store.get_block_by_number(n)
+              for n in range(1, net.support.store.height)]
+    chan, router = _router_bound_channel(net, tmp_path / "chan_crash")
+    try:
+        first_pipe = chan.commit_pipeline()
+        plan = faults.FaultPlan().add("commitpipe.commit", nth=2)
+        with faults.active(plan):
+            for blk in blocks:
+                chan.store_block(blk)          # no exception surfaces
+        assert plan.fires() == 1
+        assert chan.commit_pipeline() is not first_pipe   # rebuilt
+        assert first_pipe.closed and first_pipe.error is not None
+        assert chan.ledger.height == len(blocks) + 1
+    finally:
+        router.close()
     from fabric_mod_tpu.observability.metrics import default_provider
     text = default_provider().render_prometheus()
-    assert any(line.startswith("fabric_commitpipe_rebuilds_total ")
+    assert any(line.startswith("fabric_sharding_pipe_rebuilds_total ")
                and float(line.split()[-1]) >= 1
                for line in text.splitlines()), "rebuild not counted"
-    monkeypatch.delenv("FABRIC_MOD_TPU_COMMIT_PIPELINE")
     ref = _fresh_peer_channel(net, tmp_path / "chan_sync")
     for blk in blocks:
         ref.store_block(blk)

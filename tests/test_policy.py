@@ -175,6 +175,232 @@ def test_nested_noutof_trial_commit_semantics(world):
     assert pol.evaluate_signed_data([_sd(o["Org1"]["peer"], b"d")])
 
 
+# --- the closure walk against cauthdsl.go's rule, stated plainly ------------
+#
+# Principal satisfaction is a lookup table and validity a list of
+# booleans: no crypto, so hundreds of seeded trees run in a blink.
+
+class FakeIdent:
+    def __init__(self, key):
+        self.key = key
+
+
+class FakeMgr:
+    """satisfies_principal from an (ident key, principal byte) table."""
+
+    def __init__(self, table):
+        self.table = table
+
+    def satisfies_principal(self, ident, principal):
+        return self.table.get((ident.key, principal.principal[0]), False)
+
+
+def _leaf(i):
+    return m.SignaturePolicy(signed_by=i)
+
+
+def _nout(n, *rules):
+    return m.SignaturePolicy(n_out_of=m.NOutOf(n=n, rules=list(rules)))
+
+
+def _envelope(rule, n_prins):
+    prins = [m.MSPPrincipal(principal_classification=1,
+                            principal=bytes([j])) for j in range(n_prins)]
+    return m.SignaturePolicyEnvelope(rule=rule, identities=prins)
+
+
+def _walk_verdict(env, table, idents, valid):
+    """The program's answer: `cauthdsl._compile`'s closure behind a
+    PendingEval, even slots read from the batch mask and odd slots
+    carrying a host verdict, as `CompiledPolicy.prepare` builds them."""
+    from fabric_mod_tpu.policy import cauthdsl
+    closure = cauthdsl._compile(env.rule, env.identities, FakeMgr(table))
+    mask, slots = [], []
+    for i, ok in enumerate(valid):
+        if i % 2 == 0:
+            slots.append((len(mask), False))
+            mask.append(ok)
+        else:
+            slots.append((None, ok))
+    return cauthdsl.PendingEval(closure, idents, slots).finish(mask)
+
+
+def _fabric_rule(rule, table, idents, used):
+    """Fabric's cauthdsl.go `compile`, as a pure function: (satisfied,
+    the used flags after).  A SignedBy takes the first identity not
+    yet used that satisfies its principal.  An NOutOf runs EVERY child
+    (no early exit), each on a copy of the flags as they stand; the
+    copy is kept only if that child was satisfied; the node holds if
+    at least n children were."""
+    if rule.n_out_of is None:
+        for i, ident in enumerate(idents):
+            if not used[i] and table.get((ident.key, rule.signed_by)):
+                return True, used[:i] + [True] + used[i + 1:]
+        return False, used
+    verified = 0
+    for child in rule.n_out_of.rules:
+        ok, trial = _fabric_rule(child, table, idents, used)
+        if ok:
+            verified, used = verified + 1, trial
+    return verified >= rule.n_out_of.n, used
+
+
+def _rule_verdict(env, table, idents, valid):
+    # only identities whose signature verified reach the walk
+    vid = [i for i, ok in zip(idents, valid) if ok]
+    return _fabric_rule(env.rule, table, vid, [False] * len(vid))[0]
+
+
+def _rand_tree(rng, n_prins, depth, max_depth, thresholds):
+    """A seeded tree: a leaf with probability 0.4 below the first
+    level (always at `max_depth`), else an NOutOf of 1-3 children
+    whose threshold `thresholds(rng, k)` draws."""
+    if depth >= max_depth or (depth > 0 and rng.random() < 0.4):
+        return _leaf(rng.randrange(n_prins))
+    k = rng.randrange(1, 4)
+    subs = [_rand_tree(rng, n_prins, depth + 1, max_depth, thresholds)
+            for _ in range(k)]
+    return _nout(thresholds(rng, k), *subs)
+
+
+def _depth(rule):
+    if rule.n_out_of is None:
+        return 0
+    return 1 + max((_depth(r) for r in rule.n_out_of.rules), default=0)
+
+
+def _within(rng, k):
+    return rng.randrange(1, k + 1)
+
+
+def _flat(rng, n_prins, k):
+    return _nout(_within(rng, k),
+                 *[_leaf(rng.randrange(n_prins)) for _ in range(k)])
+
+
+def _chain(rng, n_prins, levels):
+    """A tree at least `levels` NOutOf nodes deep: a spine of nodes,
+    each with the next spine node and up to two random siblings."""
+    node = _leaf(rng.randrange(n_prins))
+    for _ in range(levels):
+        sibs = [_rand_tree(rng, n_prins, 1, 3, _within)
+                for _ in range(rng.randrange(0, 3))]
+        kids = sibs + [node]
+        rng.shuffle(kids)
+        node = _nout(_within(rng, len(kids)), *kids)
+    return node
+
+
+# what a shape class does not say: one to four principals, up to five
+# identities, seven signatures in ten valid
+_SHAPE_DEFAULTS = dict(
+    n_prins=lambda rng: rng.randrange(1, 5),
+    n_id=lambda rng: rng.randrange(0, 6),
+    valid=lambda rng: rng.random() < 0.7,
+    check=lambda env: True)
+
+# shape class -> its tree generator and what it overrides of the above
+_SHAPES = {
+    # one NOutOf over leaves: the shape of every cell's policy
+    "flat": dict(tree=lambda rng, p: _flat(rng, p, rng.randrange(1, 6))),
+    "nested-two-deep": dict(
+        tree=lambda rng, p: _rand_tree(rng, p, 0, 2, _within)),
+    "nested-past-four-deep": dict(
+        tree=lambda rng, p: _chain(rng, p, rng.randrange(5, 8)),
+        check=lambda env: _depth(env.rule) >= 5),
+    # one or two principals under many leaves: the used flags decide
+    "duplicate-principals": dict(
+        n_prins=lambda rng: rng.randrange(1, 3),
+        tree=lambda rng, p: _rand_tree(rng, p, 0, 3, _within)),
+    # somewhere n exceeds the children: that node can never hold
+    "threshold-above-children": dict(
+        tree=lambda rng, p: _rand_tree(
+            rng, p, 0, 3,
+            lambda r, k: k + 1 if r.random() < 0.3 else _within(r, k))),
+    # n = 0 always holds, and its children still consume identities
+    "threshold-zero": dict(
+        tree=lambda rng, p: _rand_tree(
+            rng, p, 0, 3,
+            lambda r, k: 0 if r.random() < 0.4 else _within(r, k))),
+    "no-identities": dict(
+        tree=lambda rng, p: _rand_tree(
+            rng, p, 0, 3, lambda r, k: r.randrange(0, k + 1)),
+        n_id=lambda rng: 0),
+    "every-identity-invalid": dict(
+        tree=lambda rng, p: _rand_tree(
+            rng, p, 0, 3, lambda r, k: r.randrange(0, k + 1)),
+        n_id=lambda rng: rng.randrange(1, 6),
+        valid=lambda rng: False),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_SHAPES))
+def test_closure_walk_is_fabrics_rule_on_seeded_trees(shape):
+    """`cauthdsl._compile` against the rule of Fabric's cauthdsl.go
+    written out above, over 300 seeded trees of one shape class with
+    random satisfaction tables and random signature verdicts."""
+    import random
+    import zlib
+    gen = {**_SHAPE_DEFAULTS, **_SHAPES[shape]}
+    rng = random.Random(zlib.crc32(shape.encode()))
+    seen = set()
+    for _ in range(300):
+        n_prins = gen["n_prins"](rng)
+        env = _envelope(gen["tree"](rng, n_prins), n_prins)
+        assert gen["check"](env)
+        n_id = gen["n_id"](rng)
+        idents = [FakeIdent(i) for i in range(n_id)]
+        table = {(i, j): rng.random() < 0.5
+                 for i in range(n_id) for j in range(n_prins)}
+        valid = [gen["valid"](rng) for _ in range(n_id)]
+        want = _rule_verdict(env, table, idents, valid)
+        assert _walk_verdict(env, table, idents, valid) is want, \
+            (env, table, valid)
+        seen.add(want)
+    # both answers occur in every class: with no identity that counts,
+    # only a tree whose root holds at threshold 0 is satisfied
+    assert seen == {True, False}
+
+
+A, B = _leaf(0), _leaf(1)
+
+
+@pytest.mark.parametrize("rule,table,valid,want", [
+    # id0 satisfies A and B, id1 only A: greedy gives id0 to A, B
+    # finds nobody, though the matching id1->A, id0->B exists
+    pytest.param(_nout(2, A, B),
+                 {(0, 0): True, (0, 1): True, (1, 0): True},
+                 [True, True], False, id="greedy-is-not-maximal-matching"),
+    # the inner OutOf(1, A, A) keeps running after its threshold is
+    # met and consumes BOTH identities: the outer A finds nobody
+    pytest.param(_nout(2, _nout(1, A, A), A),
+                 {(0, 0): True, (1, 0): True},
+                 [True, True], False, id="no-early-exit"),
+    pytest.param(A, {(0, 0): True}, [False], False,
+                 id="invalid-identity-never-satisfies"),
+    pytest.param(A, {(0, 0): True}, [True], True,
+                 id="the-same-identity-valid-does"),
+])
+def test_closure_walk_named_edge_cases(rule, table, valid, want):
+    """The greedy used-flag edge cases with their literal answers (a
+    failed child does not consume:
+    test_nested_noutof_trial_commit_semantics, above)."""
+    env = _envelope(rule, 2)
+    idents = [FakeIdent(i) for i in range(len(valid))]
+    assert _walk_verdict(env, table, idents, valid) is want
+    assert _rule_verdict(env, table, idents, valid) is want
+
+
+def test_compile_policy_bytes_memoized(world):
+    from fabric_mod_tpu.policy.manager import compile_policy_bytes
+    env_bytes = from_string(
+        "OutOf(2, 'Org1.peer', 'Org2.peer', 'Org3.peer')").encode()
+    a = compile_policy_bytes(env_bytes, world["mgr"], 3)
+    assert compile_policy_bytes(env_bytes, world["mgr"], 3) is a
+    # the config sequence keys the memo
+    assert compile_policy_bytes(env_bytes, world["mgr"], 4) is not a
+
+
 # --- implicit meta + manager ------------------------------------------------
 
 def _org_writers(world):

@@ -503,13 +503,23 @@ def test_multihost_initialize_is_a_stub_behind_the_knob(monkeypatch):
         initialize_multihost()
 
 
-def test_shard_knob_defaults_route_single_slice(monkeypatch):
-    from fabric_mod_tpu.sharding.router import shard_count, shard_depth
+def test_shard_knob_defaults_route_single_slice(world, tmp_path,
+                                                monkeypatch):
+    from fabric_mod_tpu.peer import commitpipe
+    from fabric_mod_tpu.sharding.router import shard_count
     monkeypatch.delenv("FABRIC_MOD_TPU_SHARDS", raising=False)
-    monkeypatch.delenv("FABRIC_MOD_TPU_SHARD_DEPTH", raising=False)
-    monkeypatch.delenv("FABRIC_MOD_TPU_COMMIT_PIPELINE", raising=False)
     assert shard_count() == 0                  # sharding off by default
-    assert shard_depth() >= 1                  # router-bound: floor 1
     monkeypatch.setenv("FABRIC_MOD_TPU_SHARDS", "4")
-    monkeypatch.setenv("FABRIC_MOD_TPU_SHARD_DEPTH", "3")
-    assert shard_count() == 4 and shard_depth() == 3
+    assert shard_count() == 4
+    # the engines' depth is no knob: a router that is told none
+    # builds every channel's pipe at the one constant
+    router = ChannelShardRouter(
+        verifier_factory=lambda i, mesh: FakeBatchVerifier(world["csp"]))
+    try:
+        assert router.n_slices == 4
+        router.add_channel("d", _target(world, "d",
+                                        FakeBatchVerifier(world["csp"]),
+                                        tmp_path / "d"))
+        assert router.pipeline_for("d").depth == commitpipe.DEPTH
+    finally:
+        router.close()
