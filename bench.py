@@ -825,7 +825,7 @@ def _statescale_world(n_blocks: int, txs_per_block: int,
 
 
 def measure_statescale(sizes, n_blocks: int = 8,
-                       txs_per_block: int = 32,
+                       txs_per_block: int = 128,
                        durable: bool = False) -> dict:
     """Vectorized-MVCC differential sweep at real state scale: the
     SAME signed block stream committed into ledgers prefilled at each
@@ -838,7 +838,10 @@ def measure_statescale(sizes, n_blocks: int = 8,
     move on this well-formed stream — all BEFORE any rate is reported.
     Both arms run FMT_TRACE-armed, so the reported stage+mvcc bucket
     seconds are like-for-like (and at >=100k keys the vectorized
-    bucket must actually be smaller)."""
+    bucket must actually be smaller).  Blocks are kept at or above
+    batchdecode.COLUMNAR_MIN_ROWS rows: a smaller block is staged
+    without the columnar decode and both arms would run the serial
+    MVCC."""
     import tempfile
 
     from fabric_mod_tpu.bccsp.sw import SwCSP

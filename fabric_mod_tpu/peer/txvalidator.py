@@ -12,7 +12,11 @@ Where the reference fans out one goroutine per transaction behind a
 semaphore and verifies each ECDSA signature as it reaches it, this
 validator makes the data flow explicit and device-shaped:
 
-  pass 1 (host)   unpack every tx; syntactic checks; creator identity
+  pass 1 (host)   unpack every tx (a block of batchdecode.
+                  COLUMNAR_MIN_ROWS rows or more: two columnar
+                  pre-passes, then per-tx staging over their rows; a
+                  smaller one: the generic per-tx decode chain alone);
+                  syntactic checks; creator identity
                   validation; stage creator signature + every
                   endorsement signature of every tx into ONE
                   BatchCollector (the policy engine's two-phase
@@ -69,6 +73,12 @@ _BODY_FALLBACK_OPTS = MetricOpts(
     help="Endorser-tx bodies the columnar batch decoder could not "
          "prove clean — staged through the generic per-tx decode "
          "instead (identical outcome, serial speed).")
+_DECODE_PATH_OPTS = MetricOpts(
+    "fabric", "validator", "decode_path_blocks_total",
+    help="Blocks staged, by the decode path their row count chose "
+         "(protos/batchdecode.COLUMNAR_MIN_ROWS): generic = the "
+         "per-tx decode chain, columnar = the two batch pre-passes.",
+    label_names=("path",))
 
 
 @functools.lru_cache(maxsize=None)
@@ -79,6 +89,13 @@ def _stage_metrics():
             prov.counter(_DEDUP_SAVED_OPTS),
             prov.counter(_RAW_ITEMS_OPTS),
             prov.counter(_BODY_FALLBACK_OPTS))
+
+
+@functools.lru_cache(maxsize=None)
+def _decode_path_metrics():
+    blocks = default_provider().counter(_DECODE_PATH_OPTS)
+    return {path: blocks.with_labels(path)
+            for path in ("generic", "columnar")}
 
 
 @functools.lru_cache(maxsize=None)
@@ -182,8 +199,9 @@ class StagedBlock:
         self._mask = None
         self.trace_timeline = None
         # the stage-time columnar rwset planes (batchdecode.
-        # BlockRWSets | None) — commit_block's vectorized MVCC
-        # consumes them so the block's tx bodies are decoded ONCE
+        # BlockRWSets; None for a block under COLUMNAR_MIN_ROWS) —
+        # commit_block's vectorized MVCC consumes them so the block's
+        # tx bodies are decoded ONCE
         self.rwsets = rwsets
         self.gate = gate
 
@@ -260,12 +278,14 @@ class TxValidator:
         pending the device verdicts.  `spine` (protos/batchdecode) is
         the batch pre-pass's already-decoded envelope/payload/header
         layers — value-identical to the generic decode below, which
-        stays as the per-tx fallback for rows the scanner rejected.
-        `body` (batchdecode.TxBody) is the columnar batch decoder's
-        staged endorser-tx body for this row: the exact ns / prp /
-        endorsement / written-key values the generic decode chain
-        below would produce, already validated transitively — rows it
-        could not prove take the generic chain (counted).
+        is the path of EVERY row of a block under batchdecode.
+        COLUMNAR_MIN_ROWS (stage runs no pre-pass there) and the
+        per-tx fallback for rows the scanner rejected in a larger
+        one.  `body` (batchdecode.TxBody) is the columnar batch
+        decoder's staged endorser-tx body for this row: the exact ns /
+        prp / endorsement / written-key values the generic decode
+        chain below would produce, already validated transitively —
+        rows it could not prove take the generic chain (counted).
         (reference: msgvalidation.go:248 ValidateTransaction)"""
         if not env.payload:
             work.flag = V.NIL_ENVELOPE
@@ -507,6 +527,40 @@ class TxValidator:
                         work.vp_writes.append((ns, mkey, value))
         return key_evals
 
+    def _decode_columnar(self, block: m.Block):
+        """The two batch pre-passes of a block at or above
+        batchdecode.COLUMNAR_MIN_ROWS: (spines, rwsets) for `stage`'s
+        per-tx loop.  Rows either decoder could not prove clean come
+        back None and take the generic per-tx decode there (identical
+        outcomes)."""
+        datas = block.data.data
+        # batch pre-pass: the whole block's envelope/payload/header
+        # spine in one vectorized scan
+        spines = batchdecode.decode_block_spine(datas)
+        # batch body pre-pass: every spine-accepted endorser tx's
+        # payload.data goes through ONE columnar rwset decode
+        # (protos/batchdecode.decode_block_rwsets); accepted bodies
+        # are shared by VP resolution, key-level policy staging, and
+        # — vectorized — MVCC at commit
+        with tracing.span("body_decode", block=block.header.number,
+                          txs=len(datas)):
+            body_datas: List[Optional[bytes]] = [None] * len(datas)
+            for idx, spine in enumerate(spines):
+                if spine is not None and spine.ch.type == \
+                        m.HeaderType.ENDORSER_TRANSACTION:
+                    body_datas[idx] = spine.payload.data
+            rwsets = batchdecode.decode_block_rwsets(body_datas)
+        if rwsets is not None:
+            # header facts ride along: value-identical to the generic
+            # envelope_channel_header decode commit would otherwise
+            # repeat per tx
+            for idx, spine in enumerate(spines):
+                if spine is not None:
+                    rwsets.txids[idx] = spine.ch.tx_id
+                    rwsets.types[idx] = spine.ch.type
+            _stage_metrics()[3].add(rwsets.fallbacks)
+        return spines, rwsets
+
     # -- the three passes -------------------------------------------------
     def stage(self, block: m.Block,
               block_gate: Optional[tuple] = None) -> "StagedBlock":
@@ -516,6 +570,16 @@ class TxValidator:
         N+1 while block N commits is the commit pipeline's double
         buffer — legal exactly when block N sets no state the staging
         reads (see StagedBlock.needs_barrier).
+
+        The decode path follows the block's row count: at or above
+        batchdecode.COLUMNAR_MIN_ROWS the two columnar pre-passes run
+        first (`_decode_columnar`) and `_stage_tx` reads their rows;
+        under it none runs, every row takes `_stage_tx`'s generic
+        chain and `StagedBlock.rwsets` is None (commit decodes the
+        rwsets itself).  The batch's lanes, every `_TxWork` and the
+        flags are the same either way; the `unpack` span's `decoder`
+        attribute and fabric_validator_decode_path_blocks_total{path}
+        say which ran.
 
         `block_gate` is (policy, signed_datas): the orderer's block
         signatures (peer/mcs.check_block) and the BlockValidation
@@ -529,38 +593,20 @@ class TxValidator:
         # VALIDATION_PARAMETER writes of EARLIER txs in this block —
         # the intra-block dependency structure of validator_keylevel.go
         inblock_vp: Dict[tuple, list] = {}
+        datas = block.data.data
+        decoder = ("columnar"
+                   if len(datas) >= batchdecode.COLUMNAR_MIN_ROWS
+                   else "generic")
         with tracing.span("unpack", block=block.header.number,
-                          txs=len(block.data.data)):
-            # batch pre-pass: the whole block's envelope/payload/
-            # header spine in one vectorized scan; rows the scanner
-            # could not prove clean come back None and take the
-            # generic per-tx decode below (identical outcomes)
-            spines = batchdecode.decode_block_spine(block.data.data)
-            # batch body pre-pass: every spine-accepted endorser tx's
-            # payload.data goes through ONE columnar rwset decode
-            # (protos/batchdecode.decode_block_rwsets); accepted
-            # bodies are shared by VP resolution, key-level policy
-            # staging, and — vectorized — MVCC at commit
-            with tracing.span("body_decode",
-                              block=block.header.number,
-                              txs=len(block.data.data)):
-                body_datas: List[Optional[bytes]] = \
-                    [None] * len(block.data.data)
-                for idx, spine in enumerate(spines):
-                    if spine is not None and spine.ch.type == \
-                            m.HeaderType.ENDORSER_TRANSACTION:
-                        body_datas[idx] = spine.payload.data
-                rwsets = batchdecode.decode_block_rwsets(body_datas)
-            if rwsets is not None:
-                # header facts ride along: value-identical to the
-                # generic envelope_channel_header decode commit would
-                # otherwise repeat per tx
-                for idx, spine in enumerate(spines):
-                    if spine is not None:
-                        rwsets.txids[idx] = spine.ch.tx_id
-                        rwsets.types[idx] = spine.ch.type
-                _stage_metrics()[3].add(rwsets.fallbacks)
-            for idx, data in enumerate(block.data.data):
+                          txs=len(datas), decoder=decoder):
+            if decoder == "columnar":
+                spines, rwsets = self._decode_columnar(block)
+            else:
+                # a small block: the pre-passes' fixed cost (19
+                # scan_message passes, each a chain of numpy calls)
+                # is more than its rows' own generic decode
+                spines, rwsets = [None] * len(datas), None
+            for idx, data in enumerate(datas):
                 work = _TxWork()
                 works.append(work)
                 spine = spines[idx]
@@ -592,6 +638,7 @@ class TxValidator:
         staged_hist, dedup_ctr, raw_ctr, _fb_ctr = _stage_metrics()
         staged_hist.observe(len(collector.items))
         dedup_ctr.add(collector.requests - len(collector.items))
+        _decode_path_metrics()[decoder].add(1)
         # Raw-message items (identities emit them under FABRIC_MOD_
         # TPU_FUSED_HASH) flow through the same collector/dedup into
         # p256.batch_verify_raw — counted so the fused rollout is

@@ -23,6 +23,14 @@ only sound for a single one), trailing bytes, malformed UTF-8 — comes back
 as ``None`` and the caller re-runs the generic per-tx decoder, which
 owns the verdict for malformed inputs.  The scanner therefore can
 never *change* a validation outcome, only skip redundant host work.
+
+When each path runs: `TxValidator.stage` (peer/txvalidator.py), the
+commit path's caller of `decode_block_spine` and `decode_block_rwsets`,
+runs them for blocks of `COLUMNAR_MIN_ROWS` rows or more (the measured
+crossover is in that constant's comment) and the generic per-tx chain
+alone for smaller ones.  The choice is the caller's: the decoders
+decode whatever they are handed (peer/fanout.py batches under its own
+flag); only a batch under 4 rows is refused here.
 """
 from __future__ import annotations
 
@@ -39,6 +47,26 @@ _MAX_FIELDS = 12
 # 10-byte two's-complement tail is not worth it for fields that are
 # timestamps and enums in practice
 _MAX_VARINT = 9
+# The row count from which `TxValidator.stage` runs the two columnar
+# decoders below (decode_block_spine, decode_block_rwsets) before its
+# per-tx loop; a smaller block takes the generic per-tx decode chain
+# for every row.  The columnar passes cost a fixed chain of numpy
+# calls a block (19 scan_message passes), the generic chain costs per
+# row.  `stage` alone, ms a block, three orgs and two endorsements a
+# transaction (scripts/decode_crossover.py, medians, PR 33):
+#
+#   rows a block          10     25     50     75    100    500
+#   chip machine's host
+#     generic           2.08   4.81   9.23  13.64  18.11  96.96
+#     columnar          8.84  10.43  12.91  15.23  17.97  56.36
+#   sandbox (CPU)
+#     generic           1.75   4.21   7.60  11.17  15.54  73.22
+#     columnar          6.29   8.07   9.74  12.03  15.15  57.62
+#
+# The lines cross at ~98 rows on the chip machine's host and ~95 in
+# the sandbox; the constant sits just under both, so that blocks of 100
+# and more keep the columnar path.
+COLUMNAR_MIN_ROWS = 96
 
 
 class SpineRow:

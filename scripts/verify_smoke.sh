@@ -171,9 +171,12 @@ FMT_RACECHECK=1 JAX_PLATFORMS=cpu python -m pytest -q \
     -p no:cacheprovider -p no:randomly -m 'not slow' \
     tests/test_crash_recovery.py
 # vectorized-armed commitpipe differential: the whole pipelined/sync/
-# depth1/traced gate set re-run with FABRIC_MOD_TPU_VECTOR_MVCC hot,
-# so the columnar MVCC path is proven inside the real commit pipeline
-# (not just the dedicated statescale A/B) on every change
+# depth1/traced gate set re-run with FABRIC_MOD_TPU_VECTOR_MVCC hot.
+# Its blocks hold 8 transactions, under batchdecode.COLUMNAR_MIN_ROWS,
+# so since PR 33 they are staged without the columnar decode and this
+# line proves that the armed knob changes nothing for small blocks;
+# the columnar MVCC itself is held by tests/test_vectormvcc.py (slice
+# 0j: blocks at the constant) and the statescale A/B (128-tx blocks)
 FABRIC_MOD_TPU_VECTOR_MVCC=1 python bench.py --cpu \
     --batch "${SMOKE_BATCH:-64}" --reps 1 \
     --metric commitpipe --commitpipe-verifier sw

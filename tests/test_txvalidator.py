@@ -13,7 +13,9 @@ from fabric_mod_tpu.msp import ca as calib
 from fabric_mod_tpu.msp.identities import SigningIdentity
 from fabric_mod_tpu.msp.mspimpl import Msp, MspManager
 from fabric_mod_tpu.peer import Committer, TxValidator, ValidationInfoProvider
+from fabric_mod_tpu.peer.txvalidator import VALIDATION_PARAMETER
 from fabric_mod_tpu.policy import ApplicationPolicyEvaluator, from_string
+from fabric_mod_tpu.protos import batchdecode
 from fabric_mod_tpu.protos import messages as m
 from fabric_mod_tpu.protos import protoutil
 
@@ -84,6 +86,33 @@ def _block(envs, num=0, prev=b""):
     return protoutil.new_block(num, prev, envs)
 
 
+def _tamper_endorsement(world, env, idx, xor):
+    """`env` with the last byte of its `idx`-th endorsement signature
+    XORed, re-signed so that the creator check still passes."""
+    payload = protoutil.unmarshal_envelope_payload(env)
+    tx = protoutil.extract_endorser_tx(payload)
+    cap = m.ChaincodeActionPayload.decode(tx.actions[0].payload)
+    e = cap.action.endorsements[idx]
+    cap.action.endorsements[idx] = m.Endorsement(
+        endorser=e.endorser,
+        signature=e.signature[:-1] + bytes([e.signature[-1] ^ xor]))
+    tx.actions[0] = m.TransactionAction(payload=cap.encode())
+    return protoutil.sign_envelope(
+        m.Payload(header=payload.header, data=tx.encode()),
+        world["orgs"]["Org1"]["client"])
+
+
+def _config_env(world):
+    o = world["orgs"]
+    ch = protoutil.make_channel_header(m.HeaderType.CONFIG, CHANNEL,
+                                       tx_id="cfg")
+    sh = protoutil.make_signature_header(
+        o["Org1"]["client"].serialize(), b"nonce")
+    return protoutil.sign_envelope(
+        protoutil.make_payload(ch, sh, b"config-envelope"),
+        o["Org1"]["client"])
+
+
 def test_valid_block_single_dispatch(world):
     validator, verifier = _validator(world)
     envs = [_tx(world, key=f"k{i}") for i in range(8)]
@@ -122,21 +151,8 @@ def test_same_org_double_endorsement_insufficient(world):
 
 
 def test_tampered_endorsement_rejected(world):
-    env = _tx(world)
-    payload = protoutil.unmarshal_envelope_payload(env)
-    tx = protoutil.extract_endorser_tx(payload)
-    cap = m.ChaincodeActionPayload.decode(tx.actions[0].payload)
     # flip a byte in the first endorsement signature
-    e0 = cap.action.endorsements[0]
-    sig = bytearray(e0.signature)
-    sig[-1] ^= 0xFF
-    cap.action.endorsements[0] = m.Endorsement(
-        endorser=e0.endorser, signature=bytes(sig))
-    tx.actions[0] = m.TransactionAction(payload=cap.encode())
-    new_payload = m.Payload(header=payload.header, data=tx.encode())
-    # re-sign the envelope so the creator check still passes
-    env2 = protoutil.sign_envelope(
-        new_payload, world["orgs"]["Org1"]["client"])
+    env2 = _tamper_endorsement(world, _tx(world), 0, 0xFF)
     validator, _ = _validator(world)
     assert validator.validate(_block([env2])) == [V.ENDORSEMENT_POLICY_FAILURE]
 
@@ -213,13 +229,7 @@ def test_config_tx_requires_config_machinery(world):
     wired config applier they are INVALID_CONFIG_TRANSACTION, and an
     applier's verdict decides (reference: validator.go:400-421 — a
     creator signature alone never commits governance)."""
-    o = world["orgs"]
-    ch = protoutil.make_channel_header(m.HeaderType.CONFIG, CHANNEL,
-                                       tx_id="cfg")
-    sh = protoutil.make_signature_header(o["Org1"]["client"].serialize(),
-                                         b"nonce")
-    payload = protoutil.make_payload(ch, sh, b"config-envelope")
-    env = protoutil.sign_envelope(payload, o["Org1"]["client"])
+    env = _config_env(world)
     validator, _ = _validator(world)
     assert validator.validate(_block([env])) == \
         [V.INVALID_CONFIG_TRANSACTION]
@@ -318,3 +328,187 @@ def test_vscc_name_resolves_to_builtin(world):
     assert validator._plugins.names() == ["vscc"]
     flags = validator.validate(_block([_tx(world)]))
     assert flags == [V.VALID]
+
+
+# --- the decode path is chosen by the block's row count -----------------
+# (protos/batchdecode.COLUMNAR_MIN_ROWS): under the constant every row
+# takes _stage_tx's generic chain, at or above it the two columnar
+# pre-passes run first.  Same bytes, same outcome, either way.
+
+T = batchdecode.COLUMNAR_MIN_ROWS
+GARBAGE = b"\xff\xff garbage"
+
+
+class RecordingVerifier(CountingVerifier):
+    """Keeps each dispatched batch: the device's lanes, in order."""
+
+    def __init__(self):
+        super().__init__()
+        self.batches = []
+
+    def verify_many(self, items):
+        self.batches.append(list(items))
+        return super().verify_many(items)
+
+
+def _vp_tx(world, key):
+    """Writes `key` and pins it to Org3's peer (a vp_writes row)."""
+    o = world["orgs"]
+    b = RWSetBuilder()
+    b.add_write("mycc", key, b"v")
+    b.add_metadata_write(
+        "mycc", key, VALIDATION_PARAMETER, m.ApplicationPolicy(
+            signature_policy=from_string("'Org3.peer'")).encode())
+    return protoutil.create_signed_tx(
+        CHANNEL, "mycc", b.build().encode(), o["Org1"]["client"],
+        [o["Org1"]["peer"], o["Org2"]["peer"]])
+
+
+SPECIAL_ROWS = {
+    "single": lambda w: _tx(w, endorser_names=("Org1",), key="single"),
+    "corrupt": lambda w: _tamper_endorsement(
+        w, _tx(w, key="corrupt"), 1, 1),
+    "malformed": lambda w: m.Envelope(payload=GARBAGE, signature=b"sig"),
+    "config": _config_env,
+    "vp": lambda w: _vp_tx(w, "pinned"),
+}
+
+
+_PLAIN = []
+
+
+def _plain_envs(world, n):
+    """`n` well-endorsed transactions on keys of their own, signed
+    once: the world fixture's identities are module-wide."""
+    while len(_PLAIN) < n:
+        _PLAIN.append(_tx(world, key=f"k{len(_PLAIN)}"))
+    return _PLAIN[:n]
+
+
+def _mixed_envs(world, n, only=None):
+    """`n` envelopes.  One row: the kind `only` names (None: a valid
+    transaction).  More: every special row among valid ones, and
+    after them a second writer of the pinned key (an in-block
+    key-level candidate)."""
+    if n == 1:
+        return [SPECIAL_ROWS[only](world)] if only else _plain_envs(world, 1)
+    envs = _plain_envs(world, n)
+    for pos, kind in zip(range(1, n, 2), SPECIAL_ROWS):
+        envs[pos] = SPECIAL_ROWS[kind](world)
+    if n > 12:
+        envs[11] = _tx(world, key="pinned")
+    return envs
+
+
+def _stage_under(world, block, min_rows, monkeypatch):
+    monkeypatch.setattr(batchdecode, "COLUMNAR_MIN_ROWS", min_rows)
+    validator, verifier = _validator(world, RecordingVerifier())
+    validator._config_apply = lambda env: None
+    staged = validator.stage(block)
+    flags = validator.finish(staged)
+    return staged, flags, verifier.batches
+
+
+DECODE_CASES = ([(1, kind) for kind in (None, *SPECIAL_ROWS)]
+                + [(n, None) for n in (T - 1, T, T + 1)])
+DECODE_IDS = [f"{n}-{kind or 'rows'}" for n, kind in DECODE_CASES]
+
+
+@pytest.mark.parametrize("n,only", DECODE_CASES, ids=DECODE_IDS)
+def test_decode_paths_stage_the_same(world, monkeypatch, n, only):
+    """The lanes of the device batch, every _TxWork and the txflags
+    do not depend on which decoder read the block."""
+    envs = _mixed_envs(world, n, only)
+    seen = {}
+    for path in ("generic", "columnar"):
+        blk = _block(envs)
+        if n > 1:
+            blk.data.data.append(GARBAGE)       # no Envelope at all
+        staged, flags, batches = _stage_under(
+            world, blk, len(blk.data.data) + 1 if path == "generic" else 0,
+            monkeypatch)
+        assert (staged.rwsets is None) == (path == "generic" or n < 4)
+        seen[path] = dict(
+            batches=batches, flags=flags,
+            txflags=bytes(protoutil.block_txflags(blk)),
+            works=[(w.flag, w.txid, w.vp_writes, w.is_config,
+                    w.creator_slot, w.written_ns, len(w.actions),
+                    [[(ke.ns, ke.key, ke.committed is not None,
+                       [i for i, _ in ke.inblock])
+                      for ke in a.key_evals] for a in w.actions])
+                   for w in staged.works])
+    assert seen["generic"] == seen["columnar"]
+    assert len(seen["generic"]["batches"]) == 1
+    flags = seen["generic"]["flags"]
+    if n > 1:
+        assert flags[-1] == V.BAD_PAYLOAD
+        assert flags[1:10:2] == [
+            V.ENDORSEMENT_POLICY_FAILURE, V.ENDORSEMENT_POLICY_FAILURE,
+            V.BAD_PAYLOAD, V.VALID, V.VALID]
+        # the pin written at row 9 is in force for row 11's write of
+        # the same key: Org1 + Org2 do not satisfy 'Org3.peer'
+        assert flags[11] == V.ENDORSEMENT_POLICY_FAILURE
+        assert seen["generic"]["works"][9][2], "a vp_writes row"
+        assert flags.count(V.VALID) == n - 4
+
+
+@pytest.mark.parametrize("vector_mvcc", [False, True],
+                         ids=["serial-mvcc", "vector-mvcc-knob"])
+@pytest.mark.parametrize("n", [1, T - 1, T, T + 1])
+def test_decode_paths_commit_the_same_state(world, tmp_path, monkeypatch,
+                                            n, vector_mvcc):
+    """commit_block(..., rwsets=None) after a generic stage leaves the
+    state a columnar stage's planes leave, under either MVCC."""
+    if vector_mvcc:
+        monkeypatch.setenv("FABRIC_MOD_TPU_VECTOR_MVCC", "1")
+    else:
+        monkeypatch.delenv("FABRIC_MOD_TPU_VECTOR_MVCC", raising=False)
+    envs = _mixed_envs(world, n)
+    seen = {}
+    for path, min_rows in (("generic", n + 1), ("columnar", 0)):
+        monkeypatch.setattr(batchdecode, "COLUMNAR_MIN_ROWS", min_rows)
+        led = KvLedger(str(tmp_path / path), CHANNEL)
+        validator, _ = _validator(world, tx_id_exists=led.tx_id_exists)
+        validator._config_apply = lambda env: None
+        blk = _block(envs)
+        staged = validator.stage(blk)
+        if path == "generic":
+            assert staged.rwsets is None
+        final = led.commit_block(blk, validator.finish(staged),
+                                 rwsets=staged.rwsets)
+        seen[path] = (list(final), led.state_fingerprint(),
+                      led.state_fingerprint_full())
+        led.close()
+    assert seen["generic"] == seen["columnar"]
+    assert seen["generic"][0].count(V.VALID) == (n if n == 1 else n - 4)
+
+
+def test_decode_path_engages_and_is_observable(world):
+    """A 10-tx block takes the generic chain and says so: the unpack
+    span's `decoder`, no body_decode span, the per-path block counter.
+    A block at the constant takes the columnar pre-passes."""
+    from fabric_mod_tpu.observability import tracing
+    from fabric_mod_tpu.peer.txvalidator import _decode_path_metrics
+    assert 10 < T, "the test network's 10-tx blocks are small blocks"
+    counters = _decode_path_metrics()
+    tracing.recorder().reset()
+    try:
+        for n, path, other in ((10, "generic", "columnar"),
+                               (T, "columnar", "generic")):
+            validator, _ = _validator(world)
+            before = {p: c.value for p, c in counters.items()}
+            with tracing.active():
+                staged = validator.stage(
+                    _block(_plain_envs(world, n), num=n))
+            spans = [s for s in tracing.recorder().recent_spans()
+                     if s["attrs"].get("block") == n]
+            unpack = [s for s in spans if s["name"] == "unpack"]
+            assert [s["attrs"]["decoder"] for s in unpack] == [path]
+            assert unpack[0]["attrs"]["txs"] == n
+            assert len([s for s in spans if s["name"] == "body_decode"]) \
+                == (path == "columnar")
+            assert (staged.rwsets is not None) == (path == "columnar")
+            assert counters[path].value == before[path] + 1
+            assert counters[other].value == before[other]
+    finally:
+        tracing.recorder().reset()

@@ -351,7 +351,7 @@ def world():
 CHANNEL = "vmvcc"
 
 
-def _signed_stream(world, n_blocks=6, txs_per_block=6, seed=5):
+def _signed_stream(world, n_blocks=6, txs_per_block=6, seed=5, n_keys=12):
     from fabric_mod_tpu.policy import from_string
     rng = random.Random(seed)
     s = world["signers"]
@@ -362,15 +362,15 @@ def _signed_stream(world, n_blocks=6, txs_per_block=6, seed=5):
         envs = []
         for tx in range(txs_per_block):
             b = RWSetBuilder()
-            k = "k%d" % rng.randrange(12)
+            k = "k%d" % rng.randrange(n_keys)
             if rng.random() < 0.5:
                 ver = (rng.randrange(max(bn, 1)), 0) if bn else None
                 b.add_read("mycc", k, ver)
-            b.add_write("mycc", "k%d" % rng.randrange(12),
+            b.add_write("mycc", "k%d" % rng.randrange(n_keys),
                         None if rng.random() < 0.15
                         else b"v%d.%d" % (bn, tx))
             if rng.random() < 0.2:
-                b.add_metadata_write("mycc", "k%d" % rng.randrange(12),
+                b.add_metadata_write("mycc", "k%d" % rng.randrange(n_keys),
                                      VALIDATION_PARAMETER, vp)
             if rng.random() < 0.2:
                 b.add_range_query("mycc", "k1", "k4",
@@ -417,14 +417,30 @@ def _run_stream(world, blocks, root):
 
 
 def test_e2e_knob_differential(world, tmp_path, monkeypatch):
+    from fabric_mod_tpu.ledger import kvledger
     from fabric_mod_tpu.peer.txvalidator import _stage_metrics
-    blocks = _signed_stream(world)
+    # blocks AT the row count from which stage runs the columnar
+    # decoders: under it a block hands commit no planes and the knob
+    # has nothing to vectorize (the serial path, by its contract)
+    rows = batchdecode.COLUMNAR_MIN_ROWS
+    blocks = _signed_stream(world, txs_per_block=rows,
+                            n_keys=2 * rows)
+    vector_calls = []
+    vectorized = kvledger.validate_and_prepare_batch_vectorized
+
+    def counted(*args, **kw):
+        vector_calls.append(1)
+        return vectorized(*args, **kw)
+    monkeypatch.setattr(kvledger, "validate_and_prepare_batch_vectorized",
+                        counted)
     monkeypatch.delenv("FABRIC_MOD_TPU_VECTOR_MVCC", raising=False)
     gf, gfp = _run_stream(world, blocks, tmp_path / "generic")
+    assert not vector_calls
     fb0 = _stage_metrics()[3].value
     monkeypatch.setenv("FABRIC_MOD_TPU_VECTOR_MVCC", "1")
     vf, vfp = _run_stream(world, blocks, tmp_path / "vector")
     fb1 = _stage_metrics()[3].value
+    assert len(vector_calls) == len(blocks), "the vectorized MVCC ran"
     assert gf == vf
     assert gfp == vfp
     assert fb1 == fb0, "well-formed stream must decode without fallback"
