@@ -144,18 +144,27 @@ class Network:
 
     def pump_committed(self, want_txs: int, timeout: float = 30.0
                        ) -> int:
-        """Run a deliver client until `want_txs` total txs committed."""
+        """Run a deliver client until `want_txs` total txs committed.
+
+        Each call starts a deliver client and stops it again: a caller
+        that commits block after block (a traffic generator's rounds)
+        keeps one `deliver_client()` running instead and waits on the
+        ledger's height."""
         client = self.deliver_client()
         t = RegisteredThread(
             target=lambda: client.run(idle_timeout_s=5.0),
             name="e2e-deliver", structure="e2e")
         t.start()
         deadline = time.time() + timeout
-        committed = 0
+        # a running count: each poll reads only the blocks that were
+        # committed since the last one
+        committed, counted = 0, 1
         while time.time() < deadline:
-            committed = sum(
+            height = self.ledger.height
+            committed += sum(
                 len(self.ledger.get_block_by_number(i).data.data)
-                for i in range(1, self.ledger.height))
+                for i in range(counted, height))
+            counted = height
             if committed >= want_txs:
                 break
             time.sleep(0.02)

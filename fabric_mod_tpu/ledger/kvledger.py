@@ -52,6 +52,18 @@ H_STATE_COMMIT = _mp.new_histogram(MetricOpts(
 G_HEIGHT = _mp.new_gauge(MetricOpts(
     "ledger", "", "blockchain_height", "Committed chain height",
     ("channel",)))
+# what MVCC did, added once per block
+C_MVCC_READS = _mp.new_counter(MetricOpts(
+    "fabric", "ledger", "mvcc_reads_total",
+    "Recorded reads of the transactions that reached the MVCC check "
+    "(those that signature and policy validation left VALID)"))
+C_MVCC_INVALID = _mp.new_counter(MetricOpts(
+    "fabric", "ledger", "mvcc_invalid_total",
+    "Transactions the MVCC check invalidated, by validation code",
+    ("code",)))
+_MVCC_CODES = (
+    (m.TxValidationCode.MVCC_READ_CONFLICT, "MVCC_READ_CONFLICT"),
+    (m.TxValidationCode.PHANTOM_READ_CONFLICT, "PHANTOM_READ_CONFLICT"))
 
 
 class LedgerError(Exception):
@@ -394,57 +406,71 @@ class KvLedger:
                 raise LedgerError(
                     f"flags length {len(incoming_flags)} != "
                     f"{len(envs)} txs")
-            # "mvcc" covers the commit-side host unpack (rwset
-            # extraction) + the version compares — together the
-            # conflict-detection cost the vectorized-MVCC roadmap
-            # item targets
+            # "mvcc" covers the commit-side host unpack (span
+            # `rwset_extract`) + the version compares (span
+            # `mvcc_validate`) — together the conflict-detection cost
+            # the vectorized-MVCC roadmap item targets
             vec = rwsets is not None and vector_mvcc_enabled()
             with tracing.span("mvcc", block=num):
-                txs = []
-                any_col = False
-                for tx_num, (env, flag) in enumerate(
-                        zip(envs, incoming_flags)):
-                    if rwsets is not None and \
-                            rwsets.txids[tx_num] is not None:
-                        # stage-time spine facts, value-identical to
-                        # the generic header decode below
-                        txid = rwsets.txids[tx_num]
-                        ch_type = rwsets.types[tx_num]
-                    else:
-                        try:
-                            ch = protoutil.envelope_channel_header(env)
-                            txid, ch_type = ch.tx_id, ch.type
-                        except Exception:
+                with tracing.span("rwset_extract", block=num):
+                    txs = []
+                    any_col = False
+                    for tx_num, (env, flag) in enumerate(
+                            zip(envs, incoming_flags)):
+                        if rwsets is not None and \
+                                rwsets.txids[tx_num] is not None:
+                            # stage-time spine facts, value-identical
+                            # to the generic header decode below
+                            txid = rwsets.txids[tx_num]
+                            ch_type = rwsets.types[tx_num]
+                        else:
+                            try:
+                                ch = protoutil.envelope_channel_header(
+                                    env)
+                                txid, ch_type = ch.tx_id, ch.type
+                            except Exception:
+                                txs.append(
+                                    ("", None,
+                                     m.TxValidationCode.BAD_PAYLOAD))
+                                continue
+                        if ch_type != m.HeaderType.ENDORSER_TRANSACTION:
+                            # config/control txs carry no rwset; they
+                            # commit with no state effects (their
+                            # effect is the bundle swap done by the
+                            # channel machinery upstream)
+                            txs.append((txid, m.TxReadWriteSet(), flag))
+                        elif vec and rwsets.bodies[tx_num] is not None \
+                                and (self._transient is None
+                                     or not rwsets.bodies[tx_num].has_pvt):
+                            # pvt-bearing txs keep the materialized
+                            # rwset when a transient store is wired —
+                            # _commit_pvt walks its collection hashes
+                            txs.append((txid, COLUMNAR, flag))
+                            any_col = True
+                        else:
                             txs.append(
-                                ("", None,
-                                 m.TxValidationCode.BAD_PAYLOAD))
-                            continue
-                    if ch_type != m.HeaderType.ENDORSER_TRANSACTION:
-                        # config/control txs carry no rwset; they
-                        # commit with no state effects (their effect is
-                        # the bundle swap done by the channel machinery
-                        # upstream)
-                        txs.append((txid, m.TxReadWriteSet(), flag))
-                    elif vec and rwsets.bodies[tx_num] is not None and \
-                            (self._transient is None
-                             or not rwsets.bodies[tx_num].has_pvt):
-                        # pvt-bearing txs keep the materialized rwset
-                        # when a transient store is wired — _commit_pvt
-                        # walks its collection hashes
-                        txs.append((txid, COLUMNAR, flag))
-                        any_col = True
-                    else:
-                        txs.append(
-                            (txid, tx_rwset_from_envelope(env), flag))
-                with H_STATE_VALIDATION.time():
+                                (txid, tx_rwset_from_envelope(env), flag))
+                stats = {}
+                with tracing.span(
+                        "mvcc_validate", block=num, txs=len(txs),
+                        path="vector" if any_col else "serial") as sp, \
+                        H_STATE_VALIDATION.time():
                     if any_col:
                         flags, batch, tx_writes = \
                             validate_and_prepare_batch_vectorized(
-                                txs, self.state, num, rwsets)
+                                txs, self.state, num, rwsets, stats)
                     else:
                         flags, batch, tx_writes = \
                             validate_and_prepare_batch(
-                                txs, self.state, num)
+                                txs, self.state, num, stats)
+                    invalid = [(name, flags.count(code))
+                               for code, name in _MVCC_CODES]
+                    sp.set(reads=stats["reads"],
+                           conflicts=sum(n for _, n in invalid))
+            C_MVCC_READS.add(stats["reads"])
+            for name, n in invalid:
+                if n:
+                    C_MVCC_INVALID.with_labels(name).add(n)
             protoutil.set_block_txflags(block, bytes(flags))
             with tracing.span("ledger_write", block=num):
                 with H_BLOCK_COMMIT.time():

@@ -61,7 +61,7 @@ def validate_range_query(db: VersionedDB, batch: UpdateBatch, ns: str,
 
 def validate_and_prepare_batch(
         txs: List[Tuple[str, Optional[m.TxReadWriteSet], int]],
-        db: VersionedDB, block_num: int
+        db: VersionedDB, block_num: int, stats: Optional[dict] = None
 ) -> Tuple[List[int], UpdateBatch, List[Tuple[int, str, str]]]:
     """Serial MVCC pass over a block.
 
@@ -71,11 +71,15 @@ def validate_and_prepare_batch(
     per-tx validation codes, the state UpdateBatch of the surviving
     writes versioned (block_num, tx_num), and the per-tx write list
     [(tx_num, ns, key)] for the history DB (parsed once here so the
-    commit path never re-decodes rwsets).
+    commit path never re-decodes rwsets).  `stats`, if given,
+    receives `reads`: how many recorded reads the transactions that
+    reached the check carry (a check stops at a transaction's first
+    conflict; its other reads count all the same).
     """
     flags: List[int] = []
     batch = UpdateBatch()
     tx_writes: List[Tuple[int, str, str]] = []
+    n_reads = 0
     for tx_num, (txid, rwset, incoming) in enumerate(txs):
         if incoming != m.TxValidationCode.VALID:
             flags.append(incoming)
@@ -88,6 +92,7 @@ def validate_and_prepare_batch(
         except Exception:
             flags.append(m.TxValidationCode.BAD_RWSET)
             continue
+        n_reads += sum(len(kv.reads) for _, kv in ns_sets)
         verdict = m.TxValidationCode.VALID
         for ns, kv in ns_sets:
             for read in kv.reads:
@@ -118,6 +123,8 @@ def validate_and_prepare_batch(
                     {e.name: e.value for e in mw.entries},
                     (block_num, tx_num))
         flags.append(m.TxValidationCode.VALID)
+    if stats is not None:
+        stats["reads"] = n_reads
     return flags, batch, tx_writes
 
 
@@ -146,7 +153,7 @@ def vector_mvcc_enabled() -> bool:
 
 
 def validate_and_prepare_batch_vectorized(
-        txs, db, block_num: int, planes
+        txs, db, block_num: int, planes, stats: Optional[dict] = None
 ) -> Tuple[List[int], UpdateBatch, List[Tuple[int, str, str]]]:
     """Vectorized twin of :func:`validate_and_prepare_batch`.
 
@@ -157,6 +164,7 @@ def validate_and_prepare_batch_vectorized(
     `db.get_versions_many` call resolves every committed version the
     block touches; read conflicts become numpy compares against that
     join plus a `touched` bitmap standing in for `batch.get`.
+    `stats` as the generic pass.
     """
     import numpy as np
 
@@ -355,6 +363,7 @@ def validate_and_prepare_batch_vectorized(
         batch = UpdateBatch()
         tx_writes: List[Tuple[int, str, str]] = []
         touched = np.zeros(max(nk, 1), bool)
+        n_reads = 0
 
         def walk(lo, hi, qlo, qhi):
             """Generic check order for a tx WITH range queries: per ns
@@ -387,6 +396,7 @@ def validate_and_prepare_batch_vectorized(
                 continue
             lo, hi = rb[tx_num], rb[tx_num + 1]
             qlo, qhi = qb[tx_num], qb[tx_num + 1]
+            n_reads += int(hi - lo)
             if qlo == qhi:
                 verdict = VALID
                 if lo < hi and (r_bad[lo:hi].any()
@@ -411,4 +421,6 @@ def validate_and_prepare_batch_vectorized(
                 batch.put_metadata(mt_ns[idx], mt_key[idx],
                                    mt_ent[idx], (block_num, tx_num))
             flags.append(VALID)
+    if stats is not None:
+        stats["reads"] = n_reads
     return flags, batch, tx_writes

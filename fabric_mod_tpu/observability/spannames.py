@@ -31,12 +31,14 @@ DECLARED_SPANS: Set[str] = {
     "ledger_write",
     "mcs_verify",
     "mvcc",
+    "mvcc_validate",
     "mvcc_vector",
     "policy_finish",
     "raft.replicate",
     "recv",
     "relay.push",
     "relay.repair",
+    "rwset_extract",
     "shard.dispatch",
     "stage_wait_block",
     "stage_wait_slot",

@@ -190,3 +190,116 @@ class KvContract:
                                         stub.args[2].decode())
             return val if val is not None else b""
         raise ChaincodeError(f"unknown op {op!r}")
+
+
+class SmallbankContract:
+    """Smallbank (Alomari, Cahill, Fekete, Roehm, ICDE 2008): every
+    account `id` has a checking balance under key `c_<id>` and a
+    savings balance under `s_<id>`, each a signed decimal integer as
+    bytes.  Every operation reads through `get_state` and writes
+    through `put_state`, so the simulator records the versions it saw:
+    these are the read-modify-write transactions MVCC decides.
+
+        transact_savings(a, v)   s_a += v; refused if that is negative
+        deposit_checking(a, v)   c_a += v; v may not be negative
+        send_payment(a, b, v)    c_a -= v, c_b += v
+        write_check(a, v)        c_a -= v, and one more unit off where
+                                 v exceeds s_a + c_a (the paper's
+                                 overdraft penalty)
+        amalgamate(a, b)         c_b += s_a + c_a, then s_a = c_a = 0
+        balance(a)               returns s_a + c_a, writes nothing
+        create_accounts(lo, hi, v)
+                                 the loader: writes c_i = s_i = v for
+                                 lo <= i < hi and reads nothing
+
+    Departures from Blockbench's Fabric chaincode `smallbank.go` (Dinh
+    et al., SIGMOD 2017; recalled, not read here): its operations are
+    named `updateSaving`, `updateBalance`, `sendPayment`, `writeCheck`,
+    `almagate` (sic) and `getBalance`, take string account names and
+    keep the two balances under `saving_<name>` and `checking_<name>`;
+    it has no loader, because it reads an ABSENT account as a default
+    balance and so creates it at its first write.  Here an operation on
+    an account that was never created is a `ChaincodeError`: a missing
+    key is a fault of the traffic, not money.  Checking balances are
+    signed, as the paper's `write_check` makes them: `send_payment`
+    overdraws rather than refuses, so that an account `amalgamate` has
+    emptied stays usable.
+    """
+
+    # operation -> how many whole numbers it takes
+    ARITY = {"transact_savings": 2, "deposit_checking": 2,
+             "send_payment": 3, "write_check": 2, "amalgamate": 2,
+             "balance": 1, "create_accounts": 3}
+
+    def invoke(self, stub: ChaincodeStub) -> bytes:
+        if not stub.args:
+            raise ChaincodeError("no args")
+        op = stub.args[0].decode()
+        if self.ARITY.get(op) != len(stub.args) - 1:
+            raise ChaincodeError(
+                f"no op {op!r} of {len(stub.args) - 1} arguments")
+        try:
+            nums = [int(a) for a in stub.args[1:]]
+        except ValueError as e:
+            raise ChaincodeError(f"{op}: {e}") from e
+        return getattr(self, "_op_" + op)(stub, *nums)
+
+    @staticmethod
+    def _read(stub: ChaincodeStub, key: str) -> int:
+        raw = stub.get_state(key)
+        if raw is None:
+            raise ChaincodeError(f"no account behind {key!r}")
+        return int(raw)
+
+    @staticmethod
+    def _write(stub: ChaincodeStub, key: str, balance: int) -> None:
+        stub.put_state(key, b"%d" % balance)
+
+    def _op_transact_savings(self, stub, a: int, v: int) -> bytes:
+        savings = self._read(stub, f"s_{a}") + v
+        if savings < 0:
+            raise ChaincodeError(f"savings of {a} would be {savings}")
+        self._write(stub, f"s_{a}", savings)
+        return b"ok"
+
+    def _op_deposit_checking(self, stub, a: int, v: int) -> bytes:
+        if v < 0:
+            raise ChaincodeError(f"a deposit of {v}")
+        self._write(stub, f"c_{a}", self._read(stub, f"c_{a}") + v)
+        return b"ok"
+
+    def _op_send_payment(self, stub, a: int, b: int, v: int) -> bytes:
+        if a == b or v < 0:
+            raise ChaincodeError(f"a payment of {v} from {a} to {b}")
+        from_a = self._read(stub, f"c_{a}")
+        to_b = self._read(stub, f"c_{b}")
+        self._write(stub, f"c_{a}", from_a - v)
+        self._write(stub, f"c_{b}", to_b + v)
+        return b"ok"
+
+    def _op_write_check(self, stub, a: int, v: int) -> bytes:
+        savings = self._read(stub, f"s_{a}")
+        checking = self._read(stub, f"c_{a}")
+        penalty = 1 if v > savings + checking else 0
+        self._write(stub, f"c_{a}", checking - v - penalty)
+        return b"ok"
+
+    def _op_amalgamate(self, stub, a: int, b: int) -> bytes:
+        if a == b:
+            raise ChaincodeError(f"amalgamate {a} into itself")
+        total = self._read(stub, f"s_{a}") + self._read(stub, f"c_{a}")
+        to_b = self._read(stub, f"c_{b}")
+        self._write(stub, f"s_{a}", 0)
+        self._write(stub, f"c_{a}", 0)
+        self._write(stub, f"c_{b}", to_b + total)
+        return b"ok"
+
+    def _op_balance(self, stub, a: int) -> bytes:
+        return b"%d" % (self._read(stub, f"s_{a}")
+                        + self._read(stub, f"c_{a}"))
+
+    def _op_create_accounts(self, stub, lo: int, hi: int, v: int) -> bytes:
+        for i in range(lo, hi):
+            self._write(stub, f"c_{i}", v)
+            self._write(stub, f"s_{i}", v)
+        return b"ok"
