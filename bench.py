@@ -829,8 +829,10 @@ def measure_statescale(sizes, n_blocks: int = 8,
                        durable: bool = False) -> dict:
     """Vectorized-MVCC differential sweep at real state scale: the
     SAME signed block stream committed into ledgers prefilled at each
-    `sizes` point, generic (knob scrubbed) vs FABRIC_MOD_TPU_VECTOR_
-    MVCC=1 arms.  At EVERY point, per-block txflags and the state
+    `sizes` point, in two arms: "vector" hands commit_block the planes
+    stage decoded (the vectorized MVCC over them), "generic" withholds
+    them (rwsets=None: every envelope decoded on the commit side, the
+    serial MVCC).  At EVERY point, per-block txflags and the state
     fingerprint are asserted bit-identical across arms (and across
     sizes — the stream only touches the common prefilled keyspace),
     the incremental fingerprint is asserted equal to the full-scan
@@ -840,15 +842,14 @@ def measure_statescale(sizes, n_blocks: int = 8,
     seconds are like-for-like (and at >=100k keys the vectorized
     bucket must actually be smaller).  Blocks are kept at or above
     batchdecode.COLUMNAR_MIN_ROWS rows: a smaller block is staged
-    without the columnar decode and both arms would run the serial
-    MVCC."""
+    without the columnar decode, there are no planes to hand over and
+    both arms would run the serial MVCC."""
     import tempfile
 
     from fabric_mod_tpu.bccsp.sw import SwCSP
     from fabric_mod_tpu.bccsp.tpu import FakeBatchVerifier
     from fabric_mod_tpu.ledger.statedb import UpdateBatch
     from fabric_mod_tpu.observability import tracing
-    from fabric_mod_tpu.peer import Committer
     from fabric_mod_tpu.peer.txvalidator import _stage_metrics
     from fabric_mod_tpu.protos import messages as m
 
@@ -860,7 +861,7 @@ def measure_statescale(sizes, n_blocks: int = 8,
         n_blocks, txs_per_block, min(sizes))
     n_txs = n_blocks * txs_per_block
 
-    def run_arm(root, n_keys):
+    def run_arm(root, n_keys, planes):
         led, validator = make_committer(verifier, root, durable)
         t0 = time.perf_counter()
         for lo in range(0, n_keys, 200_000):
@@ -872,15 +873,17 @@ def measure_statescale(sizes, n_blocks: int = 8,
         t0 = time.perf_counter()
         led.state_fingerprint()        # seed the incremental fold
         seed_secs = time.perf_counter() - t0
-        committer = Committer(validator, led)
         fb0 = _stage_metrics()[3].value
         flags = []
         tracing.recorder().reset()
         with tracing.active():
             t0 = time.perf_counter()
             for raw in blocks:
-                flags.append(list(
-                    committer.store_block(m.Block.decode(raw))))
+                block = m.Block.decode(raw)
+                staged = validator.stage(block)
+                flags.append(list(led.commit_block(
+                    block, validator.finish(staged),
+                    rwsets=staged.rwsets if planes else None)))
             dt = time.perf_counter() - t0
             totals = {k: v["secs"]
                       for k, v in tracing.substage_totals().items()}
@@ -909,92 +912,81 @@ def measure_statescale(sizes, n_blocks: int = 8,
         }
 
     points, flags0 = [], None
-    saved = os.environ.pop("FABRIC_MOD_TPU_VECTOR_MVCC", None)
-    try:
-        with tempfile.TemporaryDirectory(prefix="fmt_statescale_") \
-                as tmp:
-            for n_keys in sizes:
-                gen = run_arm(f"{tmp}/g{n_keys}", n_keys)
-                os.environ["FABRIC_MOD_TPU_VECTOR_MVCC"] = "1"
-                try:
-                    vec = run_arm(f"{tmp}/v{n_keys}", n_keys)
-                finally:
-                    os.environ.pop("FABRIC_MOD_TPU_VECTOR_MVCC", None)
-                # -- gates: every one BEFORE any rate is reported ----
-                if vec["flags"] != gen["flags"]:
-                    bad = [i for i, (a, b) in enumerate(
-                        zip(vec["flags"], gen["flags"])) if a != b]
+    with tempfile.TemporaryDirectory(prefix="fmt_statescale_") \
+            as tmp:
+        for n_keys in sizes:
+            gen = run_arm(f"{tmp}/g{n_keys}", n_keys, planes=False)
+            vec = run_arm(f"{tmp}/v{n_keys}", n_keys, planes=True)
+            # -- gates: every one BEFORE any rate is reported ----
+            if vec["flags"] != gen["flags"]:
+                bad = [i for i, (a, b) in enumerate(
+                    zip(vec["flags"], gen["flags"])) if a != b]
+                raise AssertionError(
+                    f"statescale@{n_keys}: vectorized txflags "
+                    f"diverge from generic at blocks {bad[:5]}")
+            if vec["fp"] != gen["fp"]:
+                raise AssertionError(
+                    f"statescale@{n_keys}: state fingerprint "
+                    "diverges across arms")
+            for arm_name, arm in (("generic", gen),
+                                  ("vector", vec)):
+                if arm["fp"] != arm["fp_full"]:
                     raise AssertionError(
-                        f"statescale@{n_keys}: vectorized txflags "
-                        f"diverge from generic at blocks {bad[:5]}")
-                if vec["fp"] != gen["fp"]:
+                        f"statescale@{n_keys}/{arm_name}: "
+                        "incremental fingerprint != full-scan "
+                        "oracle")
+                if arm["fallbacks"]:
                     raise AssertionError(
-                        f"statescale@{n_keys}: state fingerprint "
-                        "diverges across arms")
-                for arm_name, arm in (("generic", gen),
-                                      ("vector", vec)):
-                    if arm["fp"] != arm["fp_full"]:
-                        raise AssertionError(
-                            f"statescale@{n_keys}/{arm_name}: "
-                            "incremental fingerprint != full-scan "
-                            "oracle")
-                    if arm["fallbacks"]:
-                        raise AssertionError(
-                            f"statescale@{n_keys}/{arm_name}: "
-                            f"{arm['fallbacks']} body-decode "
-                            "fallbacks on the well-formed stream")
-                if flags0 is None:
-                    flags0 = gen["flags"]
-                    distinct = {f for per in flags0 for f in per}
-                    if distinct == {0}:
-                        raise AssertionError(
-                            "statescale stream produced only VALID "
-                            "flags — the conflict/policy verdicts "
-                            "the oracle relies on are gone")
-                elif gen["flags"] != flags0:
+                        f"statescale@{n_keys}/{arm_name}: "
+                        f"{arm['fallbacks']} body-decode "
+                        "fallbacks on the well-formed stream")
+            if flags0 is None:
+                flags0 = gen["flags"]
+                distinct = {f for per in flags0 for f in per}
+                if distinct == {0}:
                     raise AssertionError(
-                        f"statescale@{n_keys}: txflags changed with "
-                        "state size — the stream must only touch the "
-                        "common prefilled keyspace")
-                if n_keys >= 100_000 and vec["stage_mvcc_secs"] >= \
-                        gen["stage_mvcc_secs"]:
-                    raise AssertionError(
-                        f"statescale@{n_keys}: stage+mvcc "
-                        f"{vec['stage_mvcc_secs']:.3f}s vectorized "
-                        f"vs {gen['stage_mvcc_secs']:.3f}s generic — "
-                        "the vectorized path must not be slower at "
-                        "scale")
-                point = {"state_keys": n_keys}
-                for arm_name, arm in (("generic", gen),
-                                      ("vector", vec)):
-                    point[arm_name] = {
-                        "tx_per_sec": round(arm["tx_per_sec"], 1),
-                        "stage_mvcc_secs": round(
-                            arm["stage_mvcc_secs"], 4),
-                        "buckets_secs": arm["buckets_secs"],
-                        "fingerprint_secs": {
-                            "seed_scan": round(arm["seed_secs"], 4),
-                            "incremental": round(arm["incr_secs"], 6),
-                            "full_scan": round(arm["full_secs"], 4)},
-                        "prefill_secs": round(arm["prefill_secs"], 3),
-                    }
-                point["flags_identical"] = True
-                point["fingerprint_identical"] = True
-                point["body_decode_fallbacks"] = 0
-                point["stage_mvcc_speedup"] = round(
-                    gen["stage_mvcc_secs"]
-                    / max(vec["stage_mvcc_secs"], 1e-9), 3)
-                log(f"statescale@{n_keys}: generic "
-                    f"{gen['tx_per_sec']:,.0f} tx/s (stage+mvcc "
-                    f"{gen['stage_mvcc_secs']:.3f}s), vector "
-                    f"{vec['tx_per_sec']:,.0f} tx/s (stage+mvcc "
-                    f"{vec['stage_mvcc_secs']:.3f}s)")
-                points.append(point)
-    finally:
-        if saved is not None:
-            os.environ["FABRIC_MOD_TPU_VECTOR_MVCC"] = saved
-        else:
-            os.environ.pop("FABRIC_MOD_TPU_VECTOR_MVCC", None)
+                        "statescale stream produced only VALID "
+                        "flags — the conflict/policy verdicts "
+                        "the oracle relies on are gone")
+            elif gen["flags"] != flags0:
+                raise AssertionError(
+                    f"statescale@{n_keys}: txflags changed with "
+                    "state size — the stream must only touch the "
+                    "common prefilled keyspace")
+            if n_keys >= 100_000 and vec["stage_mvcc_secs"] >= \
+                    gen["stage_mvcc_secs"]:
+                raise AssertionError(
+                    f"statescale@{n_keys}: stage+mvcc "
+                    f"{vec['stage_mvcc_secs']:.3f}s vectorized "
+                    f"vs {gen['stage_mvcc_secs']:.3f}s generic — "
+                    "the vectorized path must not be slower at "
+                    "scale")
+            point = {"state_keys": n_keys}
+            for arm_name, arm in (("generic", gen),
+                                  ("vector", vec)):
+                point[arm_name] = {
+                    "tx_per_sec": round(arm["tx_per_sec"], 1),
+                    "stage_mvcc_secs": round(
+                        arm["stage_mvcc_secs"], 4),
+                    "buckets_secs": arm["buckets_secs"],
+                    "fingerprint_secs": {
+                        "seed_scan": round(arm["seed_secs"], 4),
+                        "incremental": round(arm["incr_secs"], 6),
+                        "full_scan": round(arm["full_secs"], 4)},
+                    "prefill_secs": round(arm["prefill_secs"], 3),
+                }
+            point["flags_identical"] = True
+            point["fingerprint_identical"] = True
+            point["body_decode_fallbacks"] = 0
+            point["stage_mvcc_speedup"] = round(
+                gen["stage_mvcc_secs"]
+                / max(vec["stage_mvcc_secs"], 1e-9), 3)
+            log(f"statescale@{n_keys}: generic "
+                f"{gen['tx_per_sec']:,.0f} tx/s (stage+mvcc "
+                f"{gen['stage_mvcc_secs']:.3f}s), vector "
+                f"{vec['tx_per_sec']:,.0f} tx/s (stage+mvcc "
+                f"{vec['stage_mvcc_secs']:.3f}s)")
+            points.append(point)
     return {
         "points": points,
         "top": {

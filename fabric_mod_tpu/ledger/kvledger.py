@@ -25,7 +25,7 @@ from fabric_mod_tpu import faults
 from fabric_mod_tpu.ledger.blkstorage import BlockStore
 from fabric_mod_tpu.ledger.mvcc import (
     COLUMNAR, validate_and_prepare_batch,
-    validate_and_prepare_batch_vectorized, vector_mvcc_enabled)
+    validate_and_prepare_batch_vectorized)
 from fabric_mod_tpu.ledger.rwsetutil import RWSetBuilder, parse_tx_rwset
 from fabric_mod_tpu.ledger.statedb import UpdateBatch, VersionedDB
 from fabric_mod_tpu.observability import tracing
@@ -61,6 +61,11 @@ C_MVCC_INVALID = _mp.new_counter(MetricOpts(
     "fabric", "ledger", "mvcc_invalid_total",
     "Transactions the MVCC check invalidated, by validation code",
     ("code",)))
+C_MVCC_RWSET_SOURCE = _mp.new_counter(MetricOpts(
+    "fabric", "ledger", "mvcc_rwset_source_total",
+    "Transactions by where commit took their read-write set from: "
+    "the planes stage's columnar decoder handed over, or a decode of "
+    "the envelope on the commit thread", ("source",)))
 _MVCC_CODES = (
     (m.TxValidationCode.MVCC_READ_CONFLICT, "MVCC_READ_CONFLICT"),
     (m.TxValidationCode.PHANTOM_READ_CONFLICT, "PHANTOM_READ_CONFLICT"))
@@ -387,9 +392,12 @@ class KvLedger:
         flags.  `rwsets` (batchdecode.BlockRWSets | None) is the
         validator's stage-time columnar body decode riding the
         staged→commit handoff: header facts (txid/type) are reused
-        instead of re-decoded, and with FABRIC_MOD_TPU_VECTOR_MVCC
-        armed the accepted rows take the vectorized MVCC over the
-        columnar planes (bit-identical flags, one bulk statedb call).
+        instead of re-decoded, and every row the decoder accepted
+        goes to the vectorized MVCC as the COLUMNAR sentinel and is
+        read from the planes, never decoded again here (bit-identical
+        flags, one bulk statedb call).  Without planes (a block under
+        batchdecode.COLUMNAR_MIN_ROWS, a caller that has none) every
+        row is decoded from its envelope and takes the serial MVCC.
         (reference: kv_ledger.go:457 CommitLegacy)"""
         with self._lock:
             num = block.header.number
@@ -409,12 +417,38 @@ class KvLedger:
             # "mvcc" covers the commit-side host unpack (span
             # `rwset_extract`) + the version compares (span
             # `mvcc_validate`) — together the conflict-detection cost
-            # the vectorized-MVCC roadmap item targets
-            vec = rwsets is not None and vector_mvcc_enabled()
+            # the vectorized-MVCC roadmap item targets.
+            #
+            # Where a row's rwset comes from follows from what stage
+            # handed over: a row of `rwsets` the scanner accepted is
+            # read from the planes; a scanner-refused row, a block
+            # staged without planes, a non-endorser row and a
+            # private-data row (while a transient store is wired)
+            # are decoded from the envelope.  One commit of a block of
+            # 2.2 KB blind writes / of Smallbank-shaped rows (1.81
+            # reads, ~1.5 writes), planes against rwsets=None, median
+            # ms a block (scripts/commit_crossover.py; PR 35):
+            #
+            #   rows a block            96     120     250     500
+            #   chip machine's host
+            #     blind, planes       6.48    7.96   13.33   23.29
+            #     blind, envelope    14.06   17.51   32.32   62.21
+            #     smallbank, planes   6.99    8.25   13.78   24.57
+            #     smallbank, envel.  16.08   18.80   37.01   69.59
+            #   sandbox (CPU, a shared machine)
+            #     blind, planes       4.71    8.79    9.35   23.14
+            #     blind, envelope    10.42   22.53   29.09   56.65
+            #     smallbank, planes   4.92    6.69   10.94   21.25
+            #     smallbank, envel.  12.24   15.89   28.19   62.47
+            #
+            # Planes win by x2.2-2.8 at every size from the one at
+            # which stage starts to make them, so there is no row
+            # count here beside COLUMNAR_MIN_ROWS.
+            bodies = rwsets.bodies if rwsets is not None else None
             with tracing.span("mvcc", block=num):
-                with tracing.span("rwset_extract", block=num):
+                with tracing.span("rwset_extract", block=num) as ex:
                     txs = []
-                    any_col = False
+                    n_planes = n_decoded = 0
                     for tx_num, (env, flag) in enumerate(
                             zip(envs, incoming_flags)):
                         if rwsets is not None and \
@@ -439,23 +473,26 @@ class KvLedger:
                             # effect is the bundle swap done by the
                             # channel machinery upstream)
                             txs.append((txid, m.TxReadWriteSet(), flag))
-                        elif vec and rwsets.bodies[tx_num] is not None \
+                        elif bodies is not None \
+                                and bodies[tx_num] is not None \
                                 and (self._transient is None
-                                     or not rwsets.bodies[tx_num].has_pvt):
+                                     or not bodies[tx_num].has_pvt):
                             # pvt-bearing txs keep the materialized
                             # rwset when a transient store is wired —
                             # _commit_pvt walks its collection hashes
                             txs.append((txid, COLUMNAR, flag))
-                            any_col = True
+                            n_planes += 1
                         else:
                             txs.append(
                                 (txid, tx_rwset_from_envelope(env), flag))
+                            n_decoded += 1
+                    ex.set(planes=n_planes, decoded=n_decoded)
                 stats = {}
                 with tracing.span(
                         "mvcc_validate", block=num, txs=len(txs),
-                        path="vector" if any_col else "serial") as sp, \
+                        path="vector" if n_planes else "serial") as sp, \
                         H_STATE_VALIDATION.time():
-                    if any_col:
+                    if n_planes:
                         flags, batch, tx_writes = \
                             validate_and_prepare_batch_vectorized(
                                 txs, self.state, num, rwsets, stats)
@@ -468,6 +505,10 @@ class KvLedger:
                     sp.set(reads=stats["reads"],
                            conflicts=sum(n for _, n in invalid))
             C_MVCC_READS.add(stats["reads"])
+            if n_planes:
+                C_MVCC_RWSET_SOURCE.with_labels("planes").add(n_planes)
+            if n_decoded:
+                C_MVCC_RWSET_SOURCE.with_labels("envelope").add(n_decoded)
             for name, n in invalid:
                 if n:
                     C_MVCC_INVALID.with_labels(name).add(n)

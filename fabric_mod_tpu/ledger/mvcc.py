@@ -129,7 +129,8 @@ def validate_and_prepare_batch(
 
 
 # ---------------------------------------------------------------------------
-# Vectorized MVCC (ISSUE 18, FABRIC_MOD_TPU_VECTOR_MVCC): the serial
+# Vectorized MVCC (ISSUE 18; since PR 35 the check of every block that
+# reaches commit with planes, KvLedger.commit_block): the serial
 # per-key python probes above replaced by one bulk get_versions_many
 # call (hash-join over the block's columnar key plane) + numpy version
 # compares.  The per-tx loop stays — MVCC is inherently serial in the
@@ -145,11 +146,6 @@ def validate_and_prepare_batch(
 
 # sentinel rwset marker: this tx's rows live in the columnar planes
 COLUMNAR = object()
-
-
-def vector_mvcc_enabled() -> bool:
-    from fabric_mod_tpu.utils import knobs
-    return knobs.get_bool("FABRIC_MOD_TPU_VECTOR_MVCC")
 
 
 def validate_and_prepare_batch_vectorized(

@@ -216,16 +216,6 @@ declare("FABRIC_MOD_TPU_BREAKER_PROBE_S", "float", 5.0,
         "background probe period while the circuit is open; 0 "
         "disables the prober thread")
 
-# -- commit path ------------------------------------------------------------
-declare("FABRIC_MOD_TPU_VECTOR_MVCC", "bool", None,
-        "1 runs MVCC over the columnar rwset planes batch-decoded at "
-        "stage time: ONE get_versions_many statedb call per block "
-        "(hash-join) + numpy version compares; rows the scanner "
-        "can't prove fall back per-tx, counted; blocks under "
-        "batchdecode.COLUMNAR_MIN_ROWS rows are staged without the "
-        "columnar decode and take the serial path (bit-identical "
-        "flags); unset = the serial per-key path")
-
 # -- channel sharding -------------------------------------------------------
 declare("FABRIC_MOD_TPU_SHARDS", "int", 0,
         "mesh slices the channel-shard router carves (sharding/); "

@@ -139,9 +139,12 @@ FMT_RACECHECK=1 JAX_PLATFORMS=cpu python -m pytest -q \
 #     to the generic decoder, corrupted rows COUNTED into the per-tx
 #     fallback, never a differing verdict), the 60-block vectorized-
 #     vs-generic MVCC differential with mixed columnar/materialized
-#     routing, the knob-armed end-to-end committer differential, the
-#     incremental-vs-full state-fingerprint oracle, and the durable
-#     one-buffered-write batch contract
+#     routing, the end-to-end committer differential (planes handed
+#     over / none staged), commit's routing by what stage handed it
+#     (accepted rows never decoded again, refused and private-data
+#     rows materialized, the source counter), the incremental-vs-full
+#     state-fingerprint oracle, and the durable one-buffered-write
+#     batch contract
 FMT_RACECHECK=1 JAX_PLATFORMS=cpu python -m pytest -q \
     -p no:cacheprovider -p no:randomly -m 'not slow' \
     tests/test_vectormvcc.py
@@ -170,16 +173,6 @@ FMT_RACECHECK=1 JAX_PLATFORMS=cpu python -m pytest -q \
 FMT_RACECHECK=1 JAX_PLATFORMS=cpu python -m pytest -q \
     -p no:cacheprovider -p no:randomly -m 'not slow' \
     tests/test_crash_recovery.py
-# vectorized-armed commitpipe differential: the whole pipelined/sync/
-# depth1/traced gate set re-run with FABRIC_MOD_TPU_VECTOR_MVCC hot.
-# Its blocks hold 8 transactions, under batchdecode.COLUMNAR_MIN_ROWS,
-# so since PR 33 they are staged without the columnar decode and this
-# line proves that the armed knob changes nothing for small blocks;
-# the columnar MVCC itself is held by tests/test_vectormvcc.py (slice
-# 0j: blocks at the constant) and the statescale A/B (128-tx blocks)
-FABRIC_MOD_TPU_VECTOR_MVCC=1 python bench.py --cpu \
-    --batch "${SMOKE_BATCH:-64}" --reps 1 \
-    --metric commitpipe --commitpipe-verifier sw
 # CPU XLA compiles of the verify cores run multiple minutes each (the
 # persistent compile cache is TPU-oriented); give the worker room.
 export FABRIC_MOD_TPU_BENCH_TIMEOUT="${FABRIC_MOD_TPU_BENCH_TIMEOUT:-2400}"

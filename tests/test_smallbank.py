@@ -10,8 +10,10 @@ What is held here, CPU only, on the repo's fixtures (`e2e.Network`,
   `DeliverClient` and the commit pipeline at its real depth: the flags
   and the state it leaves equal the plain rule's
   (`benchmarks/references/smallbank_mvcc.py`), in blocks of 10 (the
-  generic decode) and of 120 (the columnar one), under the serial and
-  the vectorized MVCC;
+  generic decode) and of 120 (the columnar one); each size also with
+  the constant patched across it, so that both sizes commit from
+  decoded envelopes under the serial MVCC and from stage's planes
+  under the vectorized one;
 * every operation of `SmallbankContract` against the rule's own
   recomputation of it;
 * the read check itself: a read one block stale, a read stale within
@@ -59,10 +61,11 @@ PARAMS = {
 def test_peer_flags_and_state_equal_the_rules(tmp_path, monkeypatch,
                                               block_txs, rounds, vector):
     assert 10 < batchdecode.COLUMNAR_MIN_ROWS <= 120     # one size on each side
-    if vector:
-        monkeypatch.setenv("FABRIC_MOD_TPU_VECTOR_MVCC", "1")
-    else:
-        monkeypatch.delenv("FABRIC_MOD_TPU_VECTOR_MVCC", raising=False)
+    # commit takes the rows stage's planes hold and decodes the rest:
+    # no planes for any block (the constant above it), or planes for
+    # blocks of 10 too (the constant at 0)
+    monkeypatch.setattr(batchdecode, "COLUMNAR_MIN_ROWS",
+                        0 if vector else block_txs + 1)
     root = str(tmp_path)
     net = Network(os.path.join(root, "net"), max_message_count=block_txs,
                   batch_timeout="10s")
@@ -89,11 +92,15 @@ def test_peer_flags_and_state_equal_the_rules(tmp_path, monkeypatch,
         assert not client.rejected
         paths = {s["attrs"]["path"] for s in spans
                  if s["name"] == "mvcc_validate"}
-        columnar = block_txs >= batchdecode.COLUMNAR_MIN_ROWS
-        assert paths == ({"vector"} if vector and columnar else {"serial"})
+        assert paths == ({"vector"} if vector else {"serial"})
         assert {s["attrs"]["decoder"] for s in spans
                 if s["name"] == "unpack"} == (
-            {"columnar"} if columnar else {"generic"})
+            {"columnar"} if vector else {"generic"})
+        extracts = [s["attrs"] for s in spans
+                    if s["name"] == "rwset_extract"]
+        n_txs = len(backlog.txs)
+        assert sum(a["planes"] for a in extracts) == (n_txs if vector else 0)
+        assert sum(a["decoded"] for a in extracts) == (0 if vector else n_txs)
         conflicts = sum(s["attrs"]["conflicts"] for s in spans
                         if s["name"] == "mvcc_validate")
         assert conflicts == backlog.expected_codes[V.MVCC_READ_CONFLICT]

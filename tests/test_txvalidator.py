@@ -452,35 +452,47 @@ def test_decode_paths_stage_the_same(world, monkeypatch, n, only):
         assert flags.count(V.VALID) == n - 4
 
 
-@pytest.mark.parametrize("vector_mvcc", [False, True],
-                         ids=["serial-mvcc", "vector-mvcc-knob"])
+@pytest.mark.parametrize("planes", [False, True],
+                         ids=["planes-withheld", "planes-handed-over"])
 @pytest.mark.parametrize("n", [1, T - 1, T, T + 1])
 def test_decode_paths_commit_the_same_state(world, tmp_path, monkeypatch,
-                                            n, vector_mvcc):
-    """commit_block(..., rwsets=None) after a generic stage leaves the
-    state a columnar stage's planes leave, under either MVCC."""
-    if vector_mvcc:
-        monkeypatch.setenv("FABRIC_MOD_TPU_VECTOR_MVCC", "1")
-    else:
-        monkeypatch.delenv("FABRIC_MOD_TPU_VECTOR_MVCC", raising=False)
+                                            n, planes):
+    """commit_block after a generic stage (no planes to hand over)
+    leaves the state a columnar stage leaves, whether commit is handed
+    the columnar stage's planes (the vectorized MVCC over them) or
+    they are withheld (rwsets=None: every envelope decoded, the
+    serial MVCC)."""
+    from fabric_mod_tpu.observability import tracing
     envs = _mixed_envs(world, n)
     seen = {}
-    for path, min_rows in (("generic", n + 1), ("columnar", 0)):
-        monkeypatch.setattr(batchdecode, "COLUMNAR_MIN_ROWS", min_rows)
-        led = KvLedger(str(tmp_path / path), CHANNEL)
-        validator, _ = _validator(world, tx_id_exists=led.tx_id_exists)
-        validator._config_apply = lambda env: None
-        blk = _block(envs)
-        staged = validator.stage(blk)
-        if path == "generic":
-            assert staged.rwsets is None
-        final = led.commit_block(blk, validator.finish(staged),
-                                 rwsets=staged.rwsets)
-        seen[path] = (list(final), led.state_fingerprint(),
-                      led.state_fingerprint_full())
-        led.close()
+    tracing.recorder().reset()
+    try:
+        for path, min_rows in (("generic", n + 1), ("columnar", 0)):
+            monkeypatch.setattr(batchdecode, "COLUMNAR_MIN_ROWS", min_rows)
+            led = KvLedger(str(tmp_path / path), CHANNEL)
+            validator, _ = _validator(world, tx_id_exists=led.tx_id_exists)
+            validator._config_apply = lambda env: None
+            blk = _block(envs)
+            staged = validator.stage(blk)
+            if path == "generic":
+                assert staged.rwsets is None
+            with tracing.active():
+                final = led.commit_block(
+                    blk, validator.finish(staged),
+                    rwsets=staged.rwsets if planes else None)
+            seen[path] = (list(final), led.state_fingerprint(),
+                          led.state_fingerprint_full())
+            led.close()
+        mvcc_paths = [s["attrs"]["path"]
+                      for s in tracing.recorder().recent_spans()
+                      if s["name"] == "mvcc_validate"]
+    finally:
+        tracing.recorder().reset()
     assert seen["generic"] == seen["columnar"]
     assert seen["generic"][0].count(V.VALID) == (n if n == 1 else n - 4)
+    # decode_block_rwsets refuses a batch under 4 rows: no planes at all
+    assert mvcc_paths == [
+        "serial", "vector" if planes and n >= 4 else "serial"]
 
 
 def test_decode_path_engages_and_is_observable(world):

@@ -7,8 +7,10 @@ tx it cannot prove must fall back (counted) — a corrupted body may
 only ever change SPEED, never a verdict.  The vectorized MVCC
 (ledger/mvcc.validate_and_prepare_batch_vectorized) must return the
 same (flags, batch, tx_writes) triple as the serial path over any mix
-of columnar / generic / missing rwsets.  The end-to-end knob
-differential closes the loop through staging + commit, and the
+of columnar / generic / missing rwsets.  The end-to-end
+differential (planes handed over / none staged) closes the loop
+through staging + commit, the routing tests hold KvLedger.commit_block
+to "a row stage's decoder accepted is never decoded again", and the
 incremental state-fingerprint accumulator is checked against its
 full-scan oracle throughout."""
 import random
@@ -329,7 +331,7 @@ def test_vector_mvcc_matches_generic():
         assert wg == wv
 
 
-# -- end-to-end: staging + commit under the knob ----------------------
+# -- end-to-end: staging + commit, with and without planes ------------
 
 @pytest.fixture(scope="module")
 def world():
@@ -386,11 +388,10 @@ def _signed_stream(world, n_blocks=6, txs_per_block=6, seed=5, n_keys=12):
     return blocks
 
 
-def _run_stream(world, blocks, root):
+def _ledger_and_validator(world, root):
     from fabric_mod_tpu.bccsp.tpu import FakeBatchVerifier
     from fabric_mod_tpu.ledger import KvLedger
-    from fabric_mod_tpu.peer import (Committer, TxValidator,
-                                     ValidationInfoProvider)
+    from fabric_mod_tpu.peer import TxValidator, ValidationInfoProvider
     from fabric_mod_tpu.policy import (ApplicationPolicyEvaluator,
                                        from_string)
     led = KvLedger(str(root), CHANNEL)
@@ -406,6 +407,12 @@ def _run_stream(world, blocks, root):
         CHANNEL, world["mgr"], ApplicationPolicyEvaluator(world["mgr"]),
         FakeBatchVerifier(world["csp"]), vinfo,
         tx_id_exists=led.tx_id_exists, state_metadata=state_vp)
+    return led, validator
+
+
+def _run_stream(world, blocks, root):
+    from fabric_mod_tpu.peer import Committer
+    led, validator = _ledger_and_validator(world, root)
     committer = Committer(validator, led)
     flags = [list(committer.store_block(m.Block.decode(raw)))
              for raw in blocks]
@@ -417,11 +424,13 @@ def _run_stream(world, blocks, root):
 
 
 def test_e2e_knob_differential(world, tmp_path, monkeypatch):
+    """The same stream through stage + commit twice: staged without
+    planes (the constant above the blocks: every envelope decoded at
+    commit, the serial MVCC) and staged as the constant says (blocks AT
+    it: planes, the vectorized MVCC once a block).  No knob chooses
+    since PR 35; the test keeps its name."""
     from fabric_mod_tpu.ledger import kvledger
     from fabric_mod_tpu.peer.txvalidator import _stage_metrics
-    # blocks AT the row count from which stage runs the columnar
-    # decoders: under it a block hands commit no planes and the knob
-    # has nothing to vectorize (the serial path, by its contract)
     rows = batchdecode.COLUMNAR_MIN_ROWS
     blocks = _signed_stream(world, txs_per_block=rows,
                             n_keys=2 * rows)
@@ -433,11 +442,11 @@ def test_e2e_knob_differential(world, tmp_path, monkeypatch):
         return vectorized(*args, **kw)
     monkeypatch.setattr(kvledger, "validate_and_prepare_batch_vectorized",
                         counted)
-    monkeypatch.delenv("FABRIC_MOD_TPU_VECTOR_MVCC", raising=False)
-    gf, gfp = _run_stream(world, blocks, tmp_path / "generic")
+    with monkeypatch.context() as mp:
+        mp.setattr(batchdecode, "COLUMNAR_MIN_ROWS", rows + 1)
+        gf, gfp = _run_stream(world, blocks, tmp_path / "generic")
     assert not vector_calls
     fb0 = _stage_metrics()[3].value
-    monkeypatch.setenv("FABRIC_MOD_TPU_VECTOR_MVCC", "1")
     vf, vfp = _run_stream(world, blocks, tmp_path / "vector")
     fb1 = _stage_metrics()[3].value
     assert len(vector_calls) == len(blocks), "the vectorized MVCC ran"
@@ -446,6 +455,201 @@ def test_e2e_knob_differential(world, tmp_path, monkeypatch):
     assert fb1 == fb0, "well-formed stream must decode without fallback"
     assert any(f != V.VALID for bf in gf for f in bf), \
         "stream should exercise invalid verdicts"
+
+
+# -- commit's routing: what stage handed over decides (PR 35) ---------
+
+# the knob PR 35 deleted, spelled in parts so that a grep for it over
+# the tree finds nothing
+GONE_KNOB = "_".join(("FABRIC_MOD_TPU", "VECTOR", "MVCC"))
+
+REFUSED_TAIL = b"\x08\x00\x08\x00"   # TxReadWriteSet.data_model, twice:
+# the scanner refuses a duplicated known field, the generic decoder
+# takes the last one, so the row is a counted fallback with a sound
+# rwset
+
+
+def _plain_block(world, n, refused=(), num=0, pvt=()):
+    """One block of n endorser transactions (a read of a key nobody
+    wrote and a write each; row i of `refused` carries REFUSED_TAIL,
+    row i of `pvt` a private write)."""
+    s = world["signers"]
+    envs = []
+    for i in range(n):
+        b = RWSetBuilder()
+        b.add_read("mycc", "r%d" % (i % 7), None)
+        b.add_write("mycc", "w%d" % i, b"v%d" % i)
+        if i in pvt:
+            b.add_pvt_write("mycc", "collA", "pk%d" % i, b"secret")
+        results = b.build().encode()
+        if i in refused:
+            results += REFUSED_TAIL
+        envs.append(protoutil.create_signed_tx(
+            CHANNEL, "mycc", results, s["Org1"], [s["Org1"], s["Org2"]]))
+    return protoutil.new_block(num, b"", envs)
+
+
+def _commit_traced(led, validator, block, hand_over=True):
+    """stage + finish + commit_block under tracing: the final flags,
+    both fingerprints, the block's rwset_extract / mvcc_validate
+    attributes and what the source counter gained."""
+    from fabric_mod_tpu.ledger.kvledger import C_MVCC_RWSET_SOURCE
+    from fabric_mod_tpu.observability import tracing
+    counters = {source: C_MVCC_RWSET_SOURCE.with_labels(source)
+                for source in ("planes", "envelope")}
+    before = {source: c.value for source, c in counters.items()}
+    staged = validator.stage(block)
+    flags = validator.finish(staged)
+    tracing.recorder().reset()
+    try:
+        with tracing.active():
+            final = led.commit_block(
+                block, flags, rwsets=staged.rwsets if hand_over else None)
+        spans = {s["name"]: s["attrs"]
+                 for s in tracing.recorder().recent_spans()}
+    finally:
+        tracing.recorder().reset()
+    return dict(
+        flags=list(final), fp=led.state_fingerprint(),
+        fp_full=led.state_fingerprint_full(),
+        extract=spans["rwset_extract"], path=spans["mvcc_validate"]["path"],
+        counted={source: c.value - before[source]
+                 for source, c in counters.items()},
+        staged=staged)
+
+
+@pytest.mark.parametrize("rows,path", [
+    (batchdecode.COLUMNAR_MIN_ROWS, "vector"),
+    (batchdecode.COLUMNAR_MIN_ROWS - 1, "serial")])
+def test_commit_path_follows_the_block_rows(world, tmp_path, monkeypatch,
+                                            rows, path):
+    """A clean environment, nothing patched: a block at the constant
+    is committed from stage's planes under the vectorized MVCC, one
+    row fewer from decoded envelopes under the serial one."""
+    monkeypatch.delenv(GONE_KNOB, raising=False)
+    led, validator = _ledger_and_validator(world, tmp_path / "led")
+    got = _commit_traced(led, validator, _plain_block(world, rows))
+    led.close()
+    assert got["path"] == path
+    assert (got["staged"].rwsets is not None) == (path == "vector")
+    assert got["flags"] == [V.VALID] * rows
+    source = "planes" if path == "vector" else "envelope"
+    assert got["counted"] == {"planes": 0, "envelope": 0, source: rows}
+
+
+@pytest.mark.parametrize("refused", [(), (3, 250, 499)],
+                         ids=["all-accepted", "three-refused"])
+def test_rwset_source_counter_and_span_attributes(world, tmp_path,
+                                                  refused):
+    """500 rows: the counter and the rwset_extract span say 500 / 0
+    for a block the scanner accepted whole and the split where it
+    refused rows; flags and both fingerprints equal the commit that
+    materializes every rwset."""
+    from fabric_mod_tpu.peer.txvalidator import _stage_metrics
+    block = _plain_block(world, 500, refused=refused)
+    seen = {}
+    for arm in ("planes", "materialized"):
+        led, validator = _ledger_and_validator(world, tmp_path / arm)
+        fb0 = _stage_metrics()[3].value
+        seen[arm] = _commit_traced(
+            led, validator, m.Block.decode(block.encode()),
+            hand_over=arm == "planes")
+        assert _stage_metrics()[3].value - fb0 == len(refused)
+        led.close()
+    got, ref = seen["planes"], seen["materialized"]
+    n_planes = 500 - len(refused)
+    assert got["extract"]["planes"] == n_planes
+    assert got["extract"]["decoded"] == len(refused)
+    assert got["counted"] == {"planes": n_planes,
+                              "envelope": len(refused)}
+    assert got["path"] == "vector"
+    assert [b is None for b in got["staged"].rwsets.bodies] == \
+        [i in refused for i in range(500)]
+    assert ref["extract"]["planes"] == 0 and ref["extract"]["decoded"] == 500
+    assert ref["counted"] == {"planes": 0, "envelope": 500}
+    assert ref["path"] == "serial"
+    assert got["flags"] == ref["flags"] == [V.VALID] * 500
+    assert got["fp"] == got["fp_full"] == ref["fp"] == ref["fp_full"]
+
+
+def test_pvt_row_stays_materialized_beside_sentinels(world, tmp_path,
+                                                     monkeypatch):
+    """With a transient store wired, a private-data row keeps its
+    materialized rwset (the pvt path walks its collection hashes)
+    inside a block whose other rows go to MVCC as sentinels."""
+    from fabric_mod_tpu.ledger import kvledger
+    from fabric_mod_tpu.ledger.pvtdata import PvtDataStore, TransientStore
+    rows = batchdecode.COLUMNAR_MIN_ROWS
+    block = _plain_block(world, rows, pvt=(5,))
+    routed = {}
+    vectorized = kvledger.validate_and_prepare_batch_vectorized
+    for arm, transient in (("wired", TransientStore()), ("none", None)):
+        led, validator = _ledger_and_validator(world, tmp_path / arm)
+        if transient is not None:
+            led.attach_pvt(transient, PvtDataStore())
+
+        def spy(txs, *args, _arm=arm, **kw):
+            routed[_arm] = [rwset is COLUMNAR for _txid, rwset, _f in txs]
+            return vectorized(txs, *args, **kw)
+        monkeypatch.setattr(
+            kvledger, "validate_and_prepare_batch_vectorized", spy)
+        got = _commit_traced(led, validator,
+                             m.Block.decode(block.encode()))
+        assert got["staged"].rwsets.bodies[5].has_pvt
+        assert got["flags"] == [V.VALID] * rows
+        assert got["path"] == "vector"
+        n_pvt = 1 if transient is not None else 0
+        assert got["extract"] == {"block": 0, "planes": rows - n_pvt,
+                                  "decoded": n_pvt}
+        # the materialized rwset reached _commit_pvt: its collection
+        # hash has no plaintext in the store and is reported missing
+        assert led.missing_pvt() == ([(0, 5, "mycc", "collA")]
+                                     if n_pvt else [])
+        led.close()
+    assert routed["wired"] == [i != 5 for i in range(rows)]
+    assert routed["none"] == [True] * rows
+
+
+def test_accepted_block_is_never_decoded_again(world, tmp_path,
+                                               monkeypatch):
+    """Commit of a block whose every row the stage decoder accepted
+    does not call tx_rwset_from_envelope at all."""
+    from fabric_mod_tpu.ledger import kvledger
+    led, validator = _ledger_and_validator(world, tmp_path / "led")
+    block = _plain_block(world, batchdecode.COLUMNAR_MIN_ROWS)
+    staged = validator.stage(block)
+    flags = validator.finish(staged)
+    assert staged.rwsets.fallbacks == 0
+
+    def refuse(env):
+        raise AssertionError("an accepted row was decoded again")
+    monkeypatch.setattr(kvledger, "tx_rwset_from_envelope", refuse)
+    final = led.commit_block(block, flags, rwsets=staged.rwsets)
+    assert list(final) == [V.VALID] * len(block.data.data)
+    assert led.state.get_state("mycc", "w0") == (b"v0", (0, 0))
+    led.close()
+
+
+def test_the_knob_is_gone(world, tmp_path, monkeypatch):
+    """GONE_KNOB is no declared knob, nothing of the ledger asks for
+    it, and setting it moves nothing: a block under the constant still
+    commits from its envelopes, serially, and one at it from the
+    planes."""
+    from fabric_mod_tpu.ledger import mvcc
+    from fabric_mod_tpu.utils import knobs
+    assert not knobs.is_declared(GONE_KNOB)
+    with pytest.raises(KeyError):
+        knobs.get_bool(GONE_KNOB)
+    assert not hasattr(mvcc, "vector_mvcc_enabled")
+    rows = batchdecode.COLUMNAR_MIN_ROWS
+    for value, n, path in (("1", rows - 1, "serial"), ("0", rows, "vector")):
+        monkeypatch.setenv(GONE_KNOB, value)
+        led, validator = _ledger_and_validator(
+            world, tmp_path / f"led{value}")
+        got = _commit_traced(led, validator, _plain_block(world, n))
+        led.close()
+        assert got["path"] == path
+        assert got["flags"] == [V.VALID] * n
 
 
 def test_incremental_fingerprint_tracks_mutations(world, tmp_path):
