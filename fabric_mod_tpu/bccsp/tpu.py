@@ -276,6 +276,12 @@ _FALLBACK_OPTS = MetricOpts(
     "fabric", "bccsp", "sw_fallback_batches_total",
     help="Verify batches answered by the sw fallback instead of the "
          "device (device error, or circuit open).")
+_DISPATCH_CHUNKS_OPTS = MetricOpts(
+    "fabric", "bccsp", "dispatch_chunks_total",
+    help="Device calls that batches wider than the widest bucket were "
+         "cut into, by the bucket each call ran in; added once per "
+         "chunked batch (a batch that fits one call adds nothing).",
+    label_names=("bucket",))
 
 
 def is_device_error(e: BaseException) -> bool:
@@ -347,6 +353,7 @@ class TpuVerifier:
         prov = default_provider()
         self._m_device_errors = prov.counter(_DEVICE_ERRORS_OPTS)
         self._m_fallback = prov.counter(_FALLBACK_OPTS)
+        self._m_chunks = prov.counter(_DISPATCH_CHUNKS_OPTS)
 
     def close(self) -> None:
         """Tear down the breaker's background prober (if the circuit
@@ -417,8 +424,18 @@ class TpuVerifier:
         n = len(items)
         if n > BUCKETS[-1]:
             # chunk through the fixed buckets — never mint new shapes
-            parts = [self._dispatch(items[i:i + BUCKETS[-1]])
-                     for i in range(0, n, BUCKETS[-1])]
+            chunks = [items[i:i + BUCKETS[-1]]
+                      for i in range(0, n, BUCKETS[-1])]
+            sizes = [_bucket(len(c), self._mesh_size) for c in chunks]
+            parts = []
+            for k, (chunk, size) in enumerate(zip(chunks, sizes)):
+                # one part's marshal and enqueue: what the host does
+                # while the parts before it run (or the chip waits)
+                with tracing.span("dispatch_chunk", part=k, of=len(chunks),
+                                  items=len(chunk), bucket=size):
+                    parts.append(self._dispatch(chunk))
+            for size, calls in collections.Counter(sizes).items():
+                self._m_chunks.with_labels(str(size)).add(calls)
 
             def finish_parts() -> np.ndarray:
                 return np.concatenate([p() for p in parts])

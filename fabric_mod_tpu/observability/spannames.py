@@ -25,6 +25,7 @@ DECLARED_SPANS: Set[str] = {
     "der_marshal",
     "device_dispatch",
     "device_enqueue",
+    "dispatch_chunk",
     "fanout.materialize",
     "fingerprint",
     "gossip.drain",

@@ -303,3 +303,68 @@ class SmallbankContract:
             self._write(stub, f"c_{i}", v)
             self._write(stub, f"s_{i}", v)
         return b"ok"
+
+
+class HotAccountsContract:
+    """The custom workload of the Fabric++ paper (Sharma, Schuhknecht,
+    Agrawal, Dittrich, SIGMOD 2019, arXiv:1810.13177): N accounts with
+    one balance each, and one kind of transaction, which reads the
+    balances of RW accounts and writes the balances of RW accounts.
+    Account `id` keeps its balance under key `a_<id>`, a whole number
+    as decimal bytes.
+
+        move(r_1..r_8, w_1..w_8, v)
+                reads a_<r_i> through `get_state` in argument order,
+                t = the sum read, then writes
+                a_<w_j> = (t + v + j) mod 1,000,000,007 for j = 1..8
+                in argument order.  A written account that was not
+                read is a blind write: it records no version
+        create_accounts(lo, hi, v)
+                the loader: writes a_<i> = v for lo <= i < hi and
+                reads nothing
+
+    The paper's (recalled, not read here): how many balances a
+    transaction reads and how many it writes (RW = 8 of each, the
+    point its legends carry), and that the two sets are drawn apart,
+    each from a small hot set with a probability of its own, so that
+    most writes are blind and most reads are of accounts the
+    transaction does not write.  This repo's: the value written.  The
+    paper's chaincode is not recalled, and Fabric's validation never
+    looks at a value; what is written here depends on every balance
+    read, on the amount and on the place of the write, so that a
+    reference which recomputes it notices a read served from the wrong
+    state and two writes exchanged.  An account that was never created
+    is a `ChaincodeError` where it is read, as in `SmallbankContract`
+    (a blind write looks at nothing, so it cannot tell), and so is an
+    account named twice among the reads or twice among the writes: the
+    source draws each set without repeats.
+    """
+
+    RW = 8
+    MODULUS = 1_000_000_007
+    # operation -> how many whole numbers it takes
+    ARITY = {"move": 2 * RW + 1, "create_accounts": 3}
+
+    # the same entry: the arity check against `self.ARITY`, whole
+    # numbers, then `_op_<name>`
+    invoke = SmallbankContract.invoke
+
+    def _op_move(self, stub, *nums: int) -> bytes:
+        reads, writes, v = nums[:self.RW], nums[self.RW:-1], nums[-1]
+        for accounts in (reads, writes):
+            if len(set(accounts)) != self.RW:
+                raise ChaincodeError(f"move: an account twice in {accounts}")
+        total = 0
+        for a in reads:
+            raw = stub.get_state(f"a_{a}")
+            if raw is None:
+                raise ChaincodeError(f"no account behind 'a_{a}'")
+            total += int(raw)
+        for j, a in enumerate(writes, 1):
+            stub.put_state(f"a_{a}", b"%d" % ((total + v + j) % self.MODULUS))
+        return b"ok"
+
+    def _op_create_accounts(self, stub, lo: int, hi: int, v: int) -> bytes:
+        for i in range(lo, hi):
+            stub.put_state(f"a_{i}", b"%d" % v)
+        return b"ok"

@@ -98,13 +98,15 @@ def build_default_registry(channel, ledger):
     internal/peer/node/start.go).  Shared by the e2e network and the
     real peer process so their wiring can never drift."""
     from fabric_mod_tpu.peer.chaincode import (
-        ChaincodeRegistry, KvContract, SmallbankContract)
+        ChaincodeRegistry, HotAccountsContract, KvContract,
+        SmallbankContract)
     from fabric_mod_tpu.peer.lifecycle import (
         LIFECYCLE_NS, LifecycleContract)
 
     registry = ChaincodeRegistry()
     registry.register("mycc", KvContract())
     registry.register("smallbank", SmallbankContract())
+    registry.register("accounts", HotAccountsContract())
     registry.register(LIFECYCLE_NS, LifecycleContract(
         channel_orgs=lambda: list(
             channel.bundle().application.org_mspids)))
