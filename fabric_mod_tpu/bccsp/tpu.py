@@ -33,6 +33,12 @@ fed):
   device entirely (the role of the reference's msp cache layer,
   msp/cache).  Identical items within one call dedup to one device
   lane for the same reason.
+* **Key tables** — signers repeat (a channel's endorsing peers, its
+  orderer, the applications' enrolled identities), so the verifier
+  keeps the fixed-base table of each public key seen lately on the
+  device (`KeyTables`) and a lane whose key has one is verified by the
+  table program (ops/p256.py: 129 additions and no doubling, against
+  the ladder's 256 doublings and a table built in every call).
 * **In-flight dispatch window** — `BatchingVerifyService` dispatches
   buckets via `verify_many_async` into a bounded in-flight queue
   (default depth 2, FABRIC_MOD_TPU_INFLIGHT) and a resolver thread
@@ -77,9 +83,9 @@ BUCKETS = (8, 64, 512, 2048)
 
 # Low-S bound over the curve order defined alongside the device kernel,
 # so the rule can't desynchronize from the math layer.
-from fabric_mod_tpu.ops.p256 import N as _P256_N  # noqa: E402
+from fabric_mod_tpu.ops import p256 as _p256  # noqa: E402
 
-_LOW_S_MAX = _P256_N // 2
+_LOW_S_MAX = _p256.N // 2
 # s is acceptable iff s < _LOW_S_MAX + 1, as a big-endian byte bound
 # for the batched lexicographic compare.
 _LOW_S_BOUND = (_LOW_S_MAX + 1).to_bytes(32, "big")
@@ -265,6 +271,157 @@ def _cache_from_env() -> Optional[VerdictCache]:
 
 
 # ---------------------------------------------------------------------------
+# Key tables
+# ---------------------------------------------------------------------------
+
+# Public keys whose fixed-base tables live on the device at once: 64
+# slots x 64 positions x 16 entries x 3 planes x 30 limbs x 4 B = 23.6
+# MB.  The array has ONE shape for the life of a verifier, so a new
+# key never mints a program.
+KEY_SLOTS = 64
+# Tables built for the new keys of one batch, most lanes first; the
+# lanes of the keys beyond it take the ladder.  A build is 11 ms of
+# host under the tables' lock and the ladder call it saves every later
+# batch 142.63 ms of device at the widest bucket (the chip, PERF.md
+# section 5): thirteen builds cost one such call.  Sixteen (0.18 s a
+# batch at most) is the least that lets a batch of sixteen keys never
+# met, with slots free, run as ONE call of one program.
+NEW_TABLES_PER_BATCH = 16
+# Keys met while they could get no table, remembered so that their
+# next batch can tell a signer that repeats from one that passes by.
+SEEN_KEYS = 16 * KEY_SLOTS
+
+_TABLE_LANES_OPTS = MetricOpts(
+    "fabric", "bccsp", "key_table_lanes_total",
+    help="Device verify lanes by the program that verified them: "
+         "`table` (the key's fixed-base table was on the device) or "
+         "`ladder`; added once per dispatch, pad lanes not counted.",
+    label_names=("path",))
+_TABLES_BUILT_OPTS = MetricOpts(
+    "fabric", "bccsp", "key_tables_built_total",
+    help="Fixed-base tables built for public keys seen for the first "
+         "time (or again after their slot was taken).")
+
+
+def _key_of(item: VerifyItem) -> Optional[bytes]:
+    """The item's public key as the tables' lookup key, or None where
+    it is not 64 bytes (such a lane is False whatever runs it)."""
+    xy = item.public_xy
+    if type(xy) is not bytes:
+        if not isinstance(xy, (bytes, bytearray, memoryview)):
+            return None
+        xy = bytes(xy)
+    return xy if len(xy) == 64 else None
+
+
+class KeyTables:
+    """The fixed-base tables (ops/p256.key_table) of the public keys
+    seen lately, in one device array of `KEY_SLOTS` slots.
+
+    Which new key gets a table, from the keys the batches carry and
+    nothing else: while a slot is free, any, at once (most lanes of
+    the batch first, `NEW_TABLES_PER_BATCH` a batch).  Once every slot
+    is taken a table costs another key its own, so only a key met in
+    an earlier batch takes the least recently used slot: a signer
+    that passes by once (one of thousands of enrolled clients) costs
+    no build and evicts nobody.
+
+    The device array is immutable: when a slot changes, a fresh copy of
+    the host master is put, and a call in flight keeps the array it was
+    dispatched with (the master itself is never handed to a transfer —
+    see `marshal_items`' note).  Thread-safe: several threads dispatch.
+    """
+
+    def __init__(self):
+        self._lock = RegisteredLock("bccsp-key-tables")
+        # key -> slot, least recently used first
+        self._slot_of: "collections.OrderedDict[bytes, int]" = \
+            collections.OrderedDict()
+        self._free = list(range(KEY_SLOTS - 1, -1, -1))
+        # keys that got no table in their batch, oldest first
+        self._seen: "collections.OrderedDict[bytes, None]" = \
+            collections.OrderedDict()
+        # a slot whose key is no curve point holds no table: its lanes
+        # are False
+        self._ok = np.zeros(KEY_SLOTS, bool)
+        self._host = _p256.empty_key_tables(KEY_SLOTS)
+        # None: the host master has tables the device has not
+        self._device = None
+        self._built = default_provider().counter(_TABLES_BUILT_OPTS)
+
+    def assign(self, keys: Sequence[Optional[bytes]]):
+        """Slots for one batch's lanes.  A key with a slot keeps it;
+        new keys get tables by the class's rule.  Returns (slot,
+        slot_ok, tabled, tables): per lane its slot, whether that
+        slot's key is a curve point, whether the lane has a slot at
+        all (a lane without one takes the ladder; a lane with no
+        usable key counts as tabled and False), and the device array
+        to run against.
+
+        All or nothing: the tables are built before anything is
+        changed, and the device array is given up BEFORE the first
+        slot changes hands, so a put that fails (the batch then
+        degrades like any device error) is made again by the next
+        batch, and no lane ever runs against an array that lacks its
+        slot's table."""
+        counts = collections.Counter(k for k in keys if k is not None)
+        width = _p256.TABLE
+        with self._lock:
+            slot_of = self._slot_of
+            new = []
+            for key, _ in counts.most_common():
+                if key in slot_of:
+                    slot_of.move_to_end(key)
+                else:
+                    new.append(key)
+            # the plan: (key, slot, the key that loses it)
+            n_free = len(self._free)
+            spare = (k for k in slot_of if k not in counts)
+            plan = []
+            for key in new:
+                if len(plan) == NEW_TABLES_PER_BATCH:
+                    break
+                if len(plan) < n_free:
+                    plan.append((key, self._free[-1 - len(plan)], None))
+                elif key in self._seen:
+                    loser = next(spare, None)
+                    if loser is None:   # every slot's key is in the batch
+                        break
+                    plan.append((key, slot_of[loser], loser))
+            tables = [_p256.key_table(int.from_bytes(key[:32], "big"),
+                                      int.from_bytes(key[32:], "big"))
+                      for key, _, _ in plan]
+            built = sum(t is not None for t in tables)
+            if built:
+                self._device = None
+            for (key, slot, loser), table in zip(plan, tables):
+                if loser is not None:
+                    del slot_of[loser]
+                slot_of[key] = slot
+                self._seen.pop(key, None)
+                self._ok[slot] = table is not None
+                if table is not None:
+                    self._host[..., slot * width:(slot + 1) * width] = table
+            del self._free[n_free - min(n_free, len(plan)):]
+            for key in new:
+                if key not in slot_of:
+                    self._seen[key] = None
+                    self._seen.move_to_end(key)
+            while len(self._seen) > SEEN_KEYS:
+                self._seen.popitem(last=False)
+            self._built.add(built)
+            if self._device is None:
+                faults.point("bccsp.device.tables")
+                self._device = _p256.place_key_tables(self._host)
+            device = self._device
+            # None is no key of `slot_of`: such a lane reads -1 too
+            slot = np.array([slot_of.get(k, -1) for k in keys], np.int32)
+            slot_ok = (slot >= 0) & self._ok[np.maximum(slot, 0)]
+        tabled = (slot >= 0) | np.array([k is None for k in keys], bool)
+        return np.maximum(slot, 0), slot_ok, tabled, device
+
+
+# ---------------------------------------------------------------------------
 # The device verifier
 # ---------------------------------------------------------------------------
 
@@ -300,6 +457,15 @@ def is_device_error(e: BaseException) -> bool:
     return "XlaRuntimeError" in name or "JaxRuntimeError" in name
 
 
+def _carry_lanes(resolve, parts):
+    """Give a resolver the lane tallies of the resolvers it wraps:
+    `table_lanes` / `ladder_lanes`, the device lanes by the program
+    that verifies them (0 where a part never reached the device)."""
+    for name in ("table_lanes", "ladder_lanes"):
+        setattr(resolve, name, sum(getattr(p, name, 0) for p in parts))
+    return resolve
+
+
 class TpuVerifier:
     """Marshals VerifyItems to the device batch verifier.
 
@@ -313,6 +479,14 @@ class TpuVerifier:
     size does not divide, so the partition is always even.  The mesh
     size must divide the largest bucket (i.e. be a power of two
     <= 2048) — checked at construction.
+
+    Which program a lane takes (`_device_dispatch`): the table program
+    where its public key has a fixed-base table on the device
+    (`KeyTables`: while a slot is free a new key gets one at once, up
+    to `NEW_TABLES_PER_BATCH` a batch; once the slots are full, a key
+    met in an earlier batch), the ladder otherwise, in a call of its
+    own.  With a mesh every lane takes the ladder: the tables are one
+    device's.  A process that serves warms BOTH programs (`warm`).
 
     `cache_size` bounds the verdict memo-cache (default from
     FABRIC_MOD_TPU_VERDICT_CACHE, 8192; 0 disables); pass a
@@ -354,6 +528,8 @@ class TpuVerifier:
         self._m_device_errors = prov.counter(_DEVICE_ERRORS_OPTS)
         self._m_fallback = prov.counter(_FALLBACK_OPTS)
         self._m_chunks = prov.counter(_DISPATCH_CHUNKS_OPTS)
+        self._m_lanes = prov.counter(_TABLE_LANES_OPTS)
+        self._tables = KeyTables() if mesh is None else None
 
     def close(self) -> None:
         """Tear down the breaker's background prober (if the circuit
@@ -364,6 +540,20 @@ class TpuVerifier:
 
     def verify_many(self, items: Sequence[VerifyItem]) -> np.ndarray:
         return self.verify_many_async(items)()
+
+    def warm(self, items: Sequence[VerifyItem]) -> None:
+        """Load (or compile) both device programs at the bucket that
+        holds `items`: the one the rule picks for them (a few fixture
+        keys: the table program) and the ladder, which the lanes a
+        batch has no tables for reach at any bucket.  For a process
+        that serves before it knows its signers: a program loaded on
+        the serving path holds its batch for minutes."""
+        self.verify_many(items)
+        if self._tables is not None and self.breaker.allow():
+            try:
+                self._device_call(items, None)()
+            except Exception as e:
+                self._degrade(e, items)
 
     def verify_many_async(self, items: Sequence[VerifyItem]):
         """Memo-probe + dedup + marshal + DISPATCH, returning a
@@ -402,8 +592,6 @@ class TpuVerifier:
             out = vals[lanes]
             return lambda: out
         resolve = self._dispatch([uniq_items[j] for j in miss_lanes])
-        # device calls this batch became, for the caller's span
-        chunks = getattr(resolve, "chunks", 1)
         miss_idx = np.asarray(miss_lanes)
 
         def finish() -> np.ndarray:
@@ -412,8 +600,9 @@ class TpuVerifier:
                 cache.put_many([uniq_keys[j] for j in miss_lanes], mask)
             vals[miss_idx] = mask
             return vals[lanes]
-        finish.chunks = chunks
-        return finish
+        # device calls this batch became, for the caller's span
+        finish.chunks = getattr(resolve, "chunks", 1)
+        return _carry_lanes(finish, [resolve])
 
     def _dispatch(self, items: Sequence[VerifyItem]):
         """Marshal + dispatch unique items (no cache/dedup layer).
@@ -440,7 +629,7 @@ class TpuVerifier:
             def finish_parts() -> np.ndarray:
                 return np.concatenate([p() for p in parts])
             finish_parts.chunks = len(parts)
-            return finish_parts
+            return _carry_lanes(finish_parts, parts)
         breaker = self.breaker
         if not breaker.allow():
             self._m_fallback.add(1)
@@ -457,30 +646,72 @@ class TpuVerifier:
                 return self._degrade(e, items)()
             breaker.record_success()
             return mask
-        return finish
+        return _carry_lanes(finish, [resolve])
 
     def _device_dispatch(self, items: Sequence[VerifyItem]):
-        """The raw device path: marshal + one program dispatch; the
-        returned resolver blocks on (and surfaces errors from) the
-        device execution."""
+        """The raw device path, and the rule that picks each lane's
+        program from its public key and nothing else: the table
+        program where the key has a table on the device, the ladder
+        for the lanes left over, in a call of their own (a device call
+        each: its own `der_marshal` and `device_enqueue`); the two
+        masks are merged in lane order.  The returned resolver blocks
+        on (and surfaces errors from) the device execution."""
+        n = len(items)
+        tabled = np.zeros(n, bool)           # a mesh: the ladder
+        if self._tables is not None:
+            slot, slot_ok, tabled, tables = self._tables.assign(
+                [_key_of(it) for it in items])
+        n_tabled = int(tabled.sum())
+        self._m_lanes.with_labels("table").add(n_tabled)
+        self._m_lanes.with_labels("ladder").add(n - n_tabled)
+        if n_tabled == n:
+            done = self._device_call(items, (slot, slot_ok, tables))
+        elif n_tabled == 0:
+            done = self._device_call(items, None)
+        else:
+            at_table, at_ladder = np.flatnonzero(tabled), \
+                np.flatnonzero(~tabled)
+            by_table = self._device_call(
+                [items[i] for i in at_table],
+                (slot[at_table], slot_ok[at_table], tables))
+            by_ladder = self._device_call(
+                [items[i] for i in at_ladder], None)
+
+            def done() -> np.ndarray:
+                mask = np.empty(n, bool)
+                mask[at_table] = by_table()
+                mask[at_ladder] = by_ladder()
+                return mask
+        done.table_lanes, done.ladder_lanes = n_tabled, n - n_tabled
+        return done
+
+    def _device_call(self, items: Sequence[VerifyItem], tabled):
+        """Marshal + ONE program dispatch: the table program over
+        `tabled` = (slot, slot_ok, tables) of `KeyTables.assign`, the
+        ladder where it is None."""
         n = len(items)
         size = _bucket(n, self._mesh_size)
         with tracing.span("der_marshal", items=n, bucket=size):
             d, r, s, qx, qy, pre_ok, msg = marshal_items(items, size)
         faults.point("bccsp.device.dispatch")
-        from fabric_mod_tpu.ops import p256
-        if msg is not None:
-            # fused hash->verify: raw-message lanes hash on device in
-            # the SAME program as the ladder — one dispatch, no host
-            # digest loop (FABRIC_MOD_TPU_FUSED_HASH consumers)
+        # a raw-message lane hashes on device in the SAME program as
+        # the verify, either program: one dispatch, no host digest loop
+        # (FABRIC_MOD_TPU_FUSED_HASH consumers)
+        if tabled is not None:
+            slot, slot_ok, tables = tabled
+            pad = (0, size - n)
+            dispatch = lambda: _p256.batch_verify_tables(
+                d, r, s, np.pad(slot, pad), np.pad(slot_ok, pad), tables,
+                msg=msg, lazy=True)
+        elif msg is not None:
             words, nblocks, has_msg = msg
-            dispatch = lambda: p256.batch_verify_raw(
+            dispatch = lambda: _p256.batch_verify_raw(
                 words, nblocks, has_msg, d, r, s, qx, qy,
                 mesh=self._mesh, lazy=True)
         else:
-            dispatch = lambda: p256.batch_verify(d, r, s, qx, qy,
-                                                 mesh=self._mesh,
-                                                 lazy=True)
+            dispatch = lambda: _p256.batch_verify(d, r, s, qx, qy,
+                                                  mesh=self._mesh,
+                                                  lazy=True)
         # the transfer and the enqueue; the program runs after it
         with tracing.span("device_enqueue", bucket=size):
             resolve = dispatch()

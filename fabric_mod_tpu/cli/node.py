@@ -95,9 +95,11 @@ def run_node(genesis_path: str, crypto_dir: str, orderer_org: str,
         from fabric_mod_tpu.bccsp.tpu import (
             BatchingVerifyService, TpuVerifier)
         verifier = TpuVerifier()
-        # warm EVERY bucket's device program BEFORE serving: cold XLA
-        # compiles run minutes, ingress futures must never wait on
-        # them, and a flush can select any bucket size
+        # warm EVERY bucket's device programs BEFORE serving (the
+        # table program and the ladder: which one a lane takes depends
+        # on the signers the traffic brings): cold XLA compiles run
+        # minutes, ingress futures must never wait on them, and a
+        # flush can select any bucket size
         from fabric_mod_tpu.bccsp.tpu import BUCKETS
         from fabric_mod_tpu.utils.fixtures import make_verify_items
         items, _ = make_verify_items(BUCKETS[-1], n_keys=4,
@@ -105,7 +107,7 @@ def run_node(genesis_path: str, crypto_dir: str, orderer_org: str,
         for bucket in BUCKETS:
             log.info("warming device verify program (bucket %d)...",
                      bucket)
-            verifier.verify_many(items[:bucket])
+            verifier.warm(items[:bucket])
         log.info("device warm")
         # ingress coalescing only pays when the device is real; the
         # whole-call timeout still allows a surprise recompile

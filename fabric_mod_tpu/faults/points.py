@@ -25,6 +25,7 @@ DECLARED_POINTS: Set[str] = {
     "bccsp.device.dispatch",
     "bccsp.device.probe",
     "bccsp.device.resolve",
+    "bccsp.device.tables",
     "commitpipe.commit",
     "commitpipe.stage",
     "deliver.failover.stream",

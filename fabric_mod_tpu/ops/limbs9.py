@@ -128,16 +128,24 @@ def limbs_to_int(a) -> int:
     return sum(int(v) << (B * i) for i, v in enumerate(a.tolist()))
 
 
+# Limb i holds bits [B*i, B*i + B) of the little-endian value: they
+# start in byte _LIMB_BYTE[i] at bit _LIMB_SHIFT[i] (<= 7) and end in
+# the byte after it.
+_LIMB_BYTE = (B * np.arange(K)) // 8
+_LIMB_SHIFT = ((B * np.arange(K)) % 8).astype(np.uint16)
+
+
 def be_bytes_to_limbs(buf: np.ndarray) -> np.ndarray:
-    """(..., 32) uint8 big-endian -> (..., K) int32 limbs (host-side)."""
+    """(..., 32) uint8 big-endian -> (..., K) int32 limbs (host-side).
+
+    Two byte gathers, a shift and a mask a limb: this runs for every
+    scalar plane of every verify batch, on the thread that dispatches."""
     buf = np.asarray(buf, np.uint8)
     assert buf.shape[-1] == 32
-    bits = np.unpackbits(buf[..., ::-1], axis=-1, bitorder="little")
-    pad = np.zeros(bits.shape[:-1] + (RBITS - 256,), np.uint8)
-    bits = np.concatenate([bits, pad], axis=-1)
-    bits = bits.reshape(bits.shape[:-1] + (K, B))
-    weights = (1 << np.arange(B)).astype(np.int32)
-    return (bits.astype(np.int32) * weights).sum(-1).astype(np.int32)
+    le = np.zeros(buf.shape[:-1] + (_LIMB_BYTE[-1] + 2,), np.uint16)
+    le[..., :32] = buf[..., ::-1]
+    pair = le[..., _LIMB_BYTE] | (le[..., _LIMB_BYTE + 1] << 8)
+    return ((pair >> _LIMB_SHIFT) & MASK).astype(np.int32)
 
 
 def to_device(host_limbs: np.ndarray) -> jnp.ndarray:

@@ -19,19 +19,49 @@ they are branch-free — identity, doubling, and inverse cases all fall
 out of the same straight-line code — so a batch never diverges and XLA
 sees one fused SIMD program.
 
-Scalar multiplication u1*G + u2*Q is one interleaved windowed (Shamir)
-ladder: 64 steps of 4 doublings + two table-adds, where the 16-entry
-G table is a host-precomputed constant (selected by one-hot matmul on
-the MXU) and the 16-entry Q table is built on device per lane.  The
-final comparison avoids an inversion: accept iff X == (r + k*n)*Z
-(mod p) for k in {0, 1} (with r + k*n < p), Z != 0.
+Scalar multiplication u1*G + u2*Q has two programs, and which one a
+lane takes is decided by what is known of its public key, never by a
+knob (bccsp/tpu.py keeps the tables and makes the choice):
 
-Two ladder variants share that schedule: the original all-projective
-`shamir_ladder` (complete addition, alg. 4) and the affine-table
-`shamir_ladder_mixed` (complete MIXED addition, alg. 5, with the Q
-table normalized by one Montgomery simultaneous inversion) —
+* **The table program** (`verify_core_tables`): a permissioned
+  ledger's signers repeat (a block of 1,500-3,000 signatures carries
+  five or six distinct keys), so the provider keeps, for each key seen
+  lately, the host-built fixed-base table [j * 16**i * Q] (i = 0..63,
+  j = 0..15; `key_table`) in one device array of a fixed number of
+  slots.  G has the same table as a program constant.  The sum is then
+  64 scan steps of ONE complete addition over 2 x batch lanes (the G
+  half and the Q half accumulate side by side: G's entry by a constant
+  one-hot matmul, the lane's entry of its slot's table by a one-hot
+  over slot x 16) and one last addition of the two halves: 65 complete
+  additions deep, 129 a lane, and NO doubling.  The key's curve check
+  is the table's build, once a key.
+* **The Shamir ladder** (`verify_core`), for a lane whose key has no
+  table (more new keys in one batch than the provider's budget, a key
+  met once while every slot is taken, a mesh-sharded batch): 64 steps of 4 doublings + two table-adds, the
+  16-entry G table a host constant and the 16-entry Q table built on
+  the device in every call.
+
+Montgomery multiplications a lane (each one schoolbook fold, two
+constant matmuls, three carry passes), by reading the code:
+
+    ladder   64 x (4 x 13 + 2 x 14) = 5,120, the Q table 189,
+             s^-1 mod n 512, curve check and conversions ~15:  ~5,836
+    tables   129 x 14 = 1,806, s^-1 mod n 512, conversions ~10: ~2,328
+             (sequentially 65 x 14 + 512 + 10 = 1,432 deep)
+
+Both share the scalar prologue (`_scalar_windows`), `point_add` and
+the final comparison (`_accept`), which avoids an inversion: accept
+iff X == (r + k*n)*Z (mod p) for k in {0, 1} (with r + k*n < p),
+Z != 0.  Complete additions make the table sum the same group element
+as the ladder's for every input, so the verdicts are bit for bit the
+same.
+
+Two ladder variants share the ladder's schedule: the original
+all-projective `shamir_ladder` (complete addition, alg. 4) and the
+affine-table `shamir_ladder_mixed` (complete MIXED addition, alg. 5,
+with the Q table normalized by one Montgomery simultaneous inversion) —
 selectable via FABRIC_MOD_TPU_MIXED_ADD, differentially tested to
-produce identical verdicts.
+produce identical verdicts.  The table program has one form.
 """
 from __future__ import annotations
 
@@ -113,6 +143,115 @@ def _g_table():
         ys.append(limbs.int_to_limbs(acc[1] * R % P))
         zs.append(one_m.copy())
     return np.stack([np.stack(xs), np.stack(ys), np.stack(zs)])
+
+
+# --- Fixed-base tables (host, python ints; once a key) ---------------------
+
+def _jac_double(X, Y, Z):
+    """Jacobian doubling, a = -3 (never the identity here)."""
+    yy = Y * Y % P
+    s = 4 * X * yy % P
+    zz = Z * Z % P
+    m = 3 * (X - zz) * (X + zz) % P
+    X3 = (m * m - 2 * s) % P
+    return X3, (m * (s - X3) - 8 * yy * yy) % P, 2 * Y * Z % P
+
+
+def _jac_add_affine(X1, Y1, Z1, x2, y2):
+    """Jacobian + affine, for operands that are neither equal nor
+    opposite (every pair `key_table` adds is k*B + B with k = 2, 4,
+    .., 14 and B of prime order)."""
+    zz = Z1 * Z1 % P
+    h = (x2 * zz - X1) % P
+    r = (y2 * zz * Z1 - Y1) % P
+    hh = h * h % P
+    hhh = hh * h % P
+    v = X1 * hh % P
+    X3 = (r * r - hhh - 2 * v) % P
+    return X3, (r * (v - X3) - Y1 * hhh) % P, Z1 * h % P
+
+
+def _to_affine(points):
+    """Jacobian points -> affine with ONE modular inversion
+    (Montgomery's trick); no point may be the identity."""
+    prefix, acc = [], 1
+    for _, _, z in points:
+        prefix.append(acc)
+        acc = acc * z % P
+    inv = pow(acc, -1, P)
+    out = [None] * len(points)
+    for i in range(len(points) - 1, -1, -1):
+        x, y, z = points[i]
+        zi = inv * prefix[i] % P
+        inv = inv * z % P
+        zi2 = zi * zi % P
+        out[i] = (x * zi2 % P, y * zi2 * zi % P)
+    return out
+
+
+def key_table(x: int, y: int):
+    """The fixed-base table of the affine point Q = (x, y):
+    (N_WINDOWS, 3, K, TABLE) f32, entry [t, :, :, j] the projective
+    Montgomery-domain point j * 16**(N_WINDOWS - 1 - t) * Q (position
+    axis MSB first, as `_scalar_windows` orders its windows), entry
+    j = 0 the identity (0 : 1 : 0) as `_g_table` encodes it, Z = 1
+    elsewhere, limbs canonical.  With it u * Q is 64 additions and no
+    doubling.
+
+    None where Q is not a point of the curve (a coordinate out of
+    range, off the curve, the (0, 0) encoding): what `on_curve` and
+    the (0, 0) test decide in the ladder is decided here, once a key.
+    Every scalar j * 16**i is under the (prime) group order, so no
+    entry is the identity and no sum meets an exceptional case.
+    ~16 ms of host a key (python ints, one batched inversion a pass).
+    """
+    if not (0 <= x < P and 0 <= y < P
+            and (y * y - (x * x * x - 3 * x + B)) % P == 0):
+        return None
+    bases = [(x, y, 1)]
+    for _ in range(N_WINDOWS - 1):
+        pt = bases[-1]
+        for _ in range(WINDOW):
+            pt = _jac_double(*pt)
+        bases.append(pt)
+    rows = []
+    for bx, by in _to_affine(bases):                 # 16**i * Q
+        row = [None, (bx, by, 1)]
+        for j in range(2, TABLE):
+            row.append(_jac_double(*row[j // 2]) if j % 2 == 0
+                       else _jac_add_affine(*row[j - 1], bx, by))
+        rows.extend(row[1:])
+    R = 1 << limbs.RBITS
+    raw = b"".join((v * R % P).to_bytes(32, "big")
+                   for pt in _to_affine(rows) for v in pt)
+    xy = be_bytes_to_limbs(np.frombuffer(raw, np.uint8).reshape(
+        N_WINDOWS, TABLE - 1, 2, 32))                # (NW, 15, 2, K)
+    one_m = limbs.int_to_limbs(R % P)
+    tab = np.zeros((N_WINDOWS, 3, K, TABLE), np.float32)
+    tab[:, 1, :, 0] = one_m
+    tab[:, :2, :, 1:] = xy.transpose(0, 2, 3, 1)
+    tab[:, 2, :, 1:] = one_m[:, None]
+    return np.ascontiguousarray(tab[::-1])
+
+
+def empty_key_tables(slots: int) -> np.ndarray:
+    """The host array `slots` key tables live in, side by side on the
+    last axis: slot k is [..., k * TABLE:(k + 1) * TABLE]."""
+    return np.zeros((N_WINDOWS, 3, K, slots * TABLE), np.float32)
+
+
+def place_key_tables(host: np.ndarray):
+    """A device array of the host tables as they stand.  The transfer
+    gets a copy of its own: the caller goes on writing slots into
+    `host`, and a buffer a transfer may still be reading is never
+    written (bccsp/tpu.marshal_items' note)."""
+    return jnp.asarray(host.copy())
+
+
+@functools.lru_cache(maxsize=None)
+def _g_fixed_table():
+    """`key_table` of the base point: a constant of the table program."""
+    return key_table(GX, GY)
 
 
 # --- Complete projective point addition (RCB alg. 4/6, a = -3) -------------
@@ -513,9 +652,49 @@ def digest_words_to_limbs(dw: jnp.ndarray) -> jnp.ndarray:
                          precision=limbs.PRECISION)  # (K, ...batch)
 
 
+def _scalar_windows(e, r, s):
+    """The scalar prologue both programs share: u1 = e/s and u2 = r/s
+    (mod n) from (K, batch) canonical limbs, as WINDOW-bit window
+    values, MSB window first: two (N_WINDOWS, batch) int32 arrays."""
+    _fp, fn, _b_m_np, _, _ = _consts()
+    batch = e.shape[1:]
+
+    # Scalars mod n: w = s^-1, u1 = e*w, u2 = r*w.  mont_mul of a *plain*
+    # value by a Montgomery-domain one yields a plain product directly.
+    s_mn = to_mont(s, fn)
+    w_mn = inv_mont(s_mn, fn)
+    u1 = canonical(mont_mul(e, w_mn, fn), fn)       # (K, batch) int32
+    u2 = canonical(mont_mul(r, w_mn, fn), fn)
+
+    wexp = jnp.asarray(1 << np.arange(WINDOW), jnp.int32)
+
+    def windows_msb_first(u):
+        bits = bits_le(u)                            # (256, batch)
+        w = jnp.tensordot(
+            wexp, bits.reshape((N_WINDOWS, WINDOW) + batch), axes=(0, 1))
+        return w[::-1]                               # (N_WINDOWS, batch)
+
+    return windows_msb_first(u1), windows_msb_first(u2)
+
+
+def _accept(acc, r, rn_lt_p, key_ok):
+    """The final comparison both programs share: accept iff the key is
+    good, Z != 0 and X == r'*Z for r' in {r, r+n} (r' < p)."""
+    fp, fn, _b_m_np, _, _ = _consts()
+    X, Z = acc[0], acc[2]
+    not_inf = ~eq_zero(Z, fp)
+    r_m = to_mont(r, fp)
+    ok_r = eq_zero(sub(X, mont_mul(r_m, Z, fp)), fp)
+    rn = add(r, const_like(fn.p, r))
+    rn_m = to_mont(rn, fp)
+    ok_rn = eq_zero(sub(X, mont_mul(rn_m, Z, fp)), fp) & rn_lt_p
+    return key_ok & not_inf & (ok_r | ok_rn)
+
+
 def _verify_core_impl(e, r, s, qx, qy, rn_lt_p,
                       ladder=shamir_ladder) -> jnp.ndarray:
-    """Batched ECDSA-P256 verify on raw limb arrays.
+    """Batched ECDSA-P256 verify on raw limb arrays, for keys never
+    seen before (the ladder).
 
     Args:
       e, r, s: (K, batch) f32 canonical limbs — digest (as 256-bit int),
@@ -526,8 +705,7 @@ def _verify_core_impl(e, r, s, qx, qy, rn_lt_p,
     Returns:
       (batch,) bool — signature valid AND key on curve.
     """
-    fp, fn, _b_m_np, _, _ = _consts()
-    batch = e.shape[1:]
+    fp = _consts()[0]
 
     # Key checks: on curve, not the identity encoding (0, 0).
     qx_m = to_mont(qx, fp)
@@ -535,51 +713,86 @@ def _verify_core_impl(e, r, s, qx, qy, rn_lt_p,
     key_ok = on_curve(qx_m, qy_m)
     key_ok &= ~(eq_zero(qx, fp) & eq_zero(qy, fp))
 
-    # Scalars mod n: w = s^-1, u1 = e*w, u2 = r*w.  mont_mul of a *plain*
-    # value by a Montgomery-domain one yields a plain product directly.
-    s_mn = to_mont(s, fn)
-    w_mn = inv_mont(s_mn, fn)
-    u1 = canonical(mont_mul(e, w_mn, fn), fn)       # (K, batch) int32
-    u2 = canonical(mont_mul(r, w_mn, fn), fn)
-
-    # WINDOW-bit window values, MSB-window first: (N_WINDOWS, batch).
-    wexp = jnp.asarray(1 << np.arange(WINDOW), jnp.int32)
-
-    def windows_msb_first(u):
-        bits = bits_le(u)                            # (256, batch)
-        w = jnp.tensordot(
-            wexp, bits.reshape((N_WINDOWS, WINDOW) + batch), axes=(0, 1))
-        return w[::-1]                               # (N_WINDOWS, batch)
-
-    u1_w = windows_msb_first(u1)
-    u2_w = windows_msb_first(u2)
-
+    u1_w, u2_w = _scalar_windows(e, r, s)
     acc = ladder(u1_w, u2_w, qx_m, qy_m)
-    X, Z = acc[0], acc[2]
+    return _accept(acc, r, rn_lt_p, key_ok)
 
-    # Accept iff Z != 0 and X == r'*Z for r' in {r, r+n} (r' < p).
-    not_inf = ~eq_zero(Z, fp)
-    r_m = to_mont(r, fp)
-    ok_r = eq_zero(sub(X, mont_mul(r_m, Z, fp)), fp)
-    rn = add(r, const_like(fn.p, r))
-    rn_m = to_mont(rn, fp)
-    ok_rn = eq_zero(sub(X, mont_mul(rn_m, Z, fp)), fp) & rn_lt_p
-    return key_ok & not_inf & (ok_r | ok_rn)
+
+def table_sum(u1_w: jnp.ndarray, u2_w: jnp.ndarray, slot: jnp.ndarray,
+              tables: jnp.ndarray):
+    """u1*G + u2*Q from fixed-base tables: no doubling.
+
+    `u1_w`, `u2_w`: (N_WINDOWS, batch) window values, MSB first;
+    `slot`: (batch,) int32, which of `tables`' slots holds the lane's
+    key; `tables`: (N_WINDOWS, 3, K, slots * TABLE), the provider's
+    `key_table`s side by side (`empty_key_tables`).  Each of the 64
+    scan steps selects G's entry of that position (a constant one-hot
+    matmul) and the lane's entry of its slot's table (a one-hot over
+    slot x TABLE; precision pinned, table limbs reach 511) and adds
+    both into their accumulators in ONE complete addition over
+    2 x batch lanes; a last addition joins the halves.  Complete
+    additions absorb identity entries (zero windows), equal and
+    opposite operands, so the result is the ladder's group element for
+    every input.  A lane whose slot holds no table sums garbage; the
+    caller masks it.
+    """
+    fp, _fn, b_m_np, _, _ = _consts()
+    width = tables.shape[-1]
+    b_m = const_like(b_m_np, u1_w)                   # its rank only
+    lanes = u1_w.shape[1]
+
+    def step(acc, xs):
+        w_g, w_q, g_t, q_t = xs
+        oh_g = jax.nn.one_hot(w_g, TABLE, dtype=jnp.float32, axis=0)
+        oh_q = jax.nn.one_hot(w_q, width, dtype=jnp.float32, axis=0)
+        picked = jnp.concatenate(
+            [jnp.tensordot(g_t, oh_g, axes=(-1, 0),
+                           precision=limbs.PRECISION),
+             jnp.tensordot(q_t, oh_q, axes=(-1, 0),
+                           precision=limbs.PRECISION)],
+            axis=-1)                                 # (3, K, 2 * batch)
+        return point_add(acc, tuple(picked), fp, b_m), None
+
+    acc, _ = jax.lax.scan(
+        step, infinity((2 * lanes,)),
+        (u1_w, slot[None] * TABLE + u2_w,
+         limbs.const_jnp(_g_fixed_table()), tables))
+    return point_add(tuple(c[:, :lanes] for c in acc),
+                     tuple(c[:, lanes:] for c in acc), fp, b_m)
+
+
+def _verify_core_tables_impl(e, r, s, rn_lt_p, slot, slot_ok,
+                             tables) -> jnp.ndarray:
+    """Batched ECDSA-P256 verify for lanes whose public key has a
+    fixed-base table (`key_table`) in `tables`.
+
+    Args:
+      e, r, s, rn_lt_p: as `_verify_core_impl`.
+      slot: (batch,) int32 — the slot of `tables` holding the lane's key.
+      slot_ok: (batch,) bool — False where the key is no curve point
+        (its slot holds no table): the lane is False.
+      tables: (N_WINDOWS, 3, K, slots * TABLE) f32.
+    Returns:
+      (batch,) bool — bit for bit `_verify_core_impl`'s verdicts.
+    """
+    u1_w, u2_w = _scalar_windows(e, r, s)
+    acc = table_sum(u1_w, u2_w, slot, tables)
+    return _accept(acc, r, rn_lt_p, slot_ok)
 
 
 verify_core = jax.jit(_verify_core_impl)
 verify_core_mixed = jax.jit(
     functools.partial(_verify_core_impl, ladder=shamir_ladder_mixed))
+verify_core_tables = jax.jit(_verify_core_tables_impl)
 
 
-def _verify_core_fused_impl(words, nblocks, has_msg, e, r, s, qx, qy,
-                            rn_lt_p, ladder=shamir_ladder) -> jnp.ndarray:
-    """The fused hash->verify core: e = SHA-256(m) computed ON DEVICE
-    in the same program as the ECDSA verify — one dispatch, no host
-    digest loop (the host half of the old path hashed per message in
-    msp/identities.digest_for).
+def _device_digest(words, nblocks, has_msg, e) -> jnp.ndarray:
+    """The fused hash->verify prologue: e = SHA-256(m) computed ON
+    DEVICE in the same program as the ECDSA verify — one dispatch, no
+    host digest loop (the host half of the old path hashed per message
+    in msp/identities.digest_for).
 
-    Args (beyond _verify_core_impl's):
+    Args:
       words: (batch, max_blocks, 16) uint32 — FIPS 180-4 pre-padded
         message words (bccsp/der.pack_messages).
       nblocks: (batch,) int32 — real block count per lane; 0 for
@@ -594,13 +807,30 @@ def _verify_core_fused_impl(words, nblocks, has_msg, e, r, s, qx, qy,
     from fabric_mod_tpu.ops import sha256
     dw = sha256.sha256_blocks(words, nblocks)        # (batch, 8) u32
     e_dev = digest_words_to_limbs(dw)                # (K, batch) f32
-    e = jnp.where(has_msg[None], e_dev, e)
+    return jnp.where(has_msg[None], e_dev, e)
+
+
+def _verify_core_fused_impl(words, nblocks, has_msg, e, r, s, qx, qy,
+                            rn_lt_p, ladder=shamir_ladder) -> jnp.ndarray:
+    """`_verify_core_impl` behind `_device_digest`."""
+    e = _device_digest(words, nblocks, has_msg, e)
     return _verify_core_impl(e, r, s, qx, qy, rn_lt_p, ladder=ladder)
+
+
+def _verify_core_tables_fused_impl(words, nblocks, has_msg, e, r, s,
+                                   rn_lt_p, slot, slot_ok,
+                                   tables) -> jnp.ndarray:
+    """`_verify_core_tables_impl` behind `_device_digest`: the table
+    program composes with the fused hash as the ladder does."""
+    e = _device_digest(words, nblocks, has_msg, e)
+    return _verify_core_tables_impl(e, r, s, rn_lt_p, slot, slot_ok,
+                                    tables)
 
 
 verify_core_fused = jax.jit(_verify_core_fused_impl)
 verify_core_fused_mixed = jax.jit(
     functools.partial(_verify_core_fused_impl, ladder=shamir_ladder_mixed))
+verify_core_tables_fused = jax.jit(_verify_core_tables_fused_impl)
 
 
 # --- Host wrapper ----------------------------------------------------------
@@ -626,6 +856,21 @@ def _host_limbs(b: np.ndarray) -> np.ndarray:
     return np.moveaxis(be_bytes_to_limbs(b), -1, 0).astype(np.float32)
 
 
+def marshal_scalars(digests: np.ndarray, r_bytes: np.ndarray,
+                    s_bytes: np.ndarray):
+    """The key-less half of the host prologue: ((e, r, s limbs,
+    rn_lt_p), range_ok) — the scalars' range checks and byte->limb
+    marshalling, which both programs take."""
+    digests = np.asarray(digests, np.uint8)
+    r_bytes = np.asarray(r_bytes, np.uint8)
+    s_bytes = np.asarray(s_bytes, np.uint8)
+    range_ok = (r_bytes.any(axis=-1) & s_bytes.any(axis=-1)
+                & _lt_bytes(r_bytes, _N_BYTES) & _lt_bytes(s_bytes, _N_BYTES))
+    rn_lt_p = _lt_bytes(r_bytes, _P_MINUS_N_BYTES)
+    return (_host_limbs(digests), _host_limbs(r_bytes),
+            _host_limbs(s_bytes), rn_lt_p), range_ok
+
+
 def marshal_inputs(digests: np.ndarray, r_bytes: np.ndarray,
                    s_bytes: np.ndarray, qx_bytes: np.ndarray,
                    qy_bytes: np.ndarray):
@@ -637,22 +882,14 @@ def marshal_inputs(digests: np.ndarray, r_bytes: np.ndarray,
     `range_ok` the host-side scalar-range verdict to AND into the
     device mask.
     """
-    digests = np.asarray(digests, np.uint8)
-    r_bytes = np.asarray(r_bytes, np.uint8)
-    s_bytes = np.asarray(s_bytes, np.uint8)
     qx_bytes = np.asarray(qx_bytes, np.uint8)
     qy_bytes = np.asarray(qy_bytes, np.uint8)
-
-    nonzero_r = r_bytes.any(axis=-1)
-    nonzero_s = s_bytes.any(axis=-1)
-    range_ok = (nonzero_r & nonzero_s
-                & _lt_bytes(r_bytes, _N_BYTES) & _lt_bytes(s_bytes, _N_BYTES)
-                & _lt_bytes(qx_bytes, _P_BYTES)
+    (e, r, s, rn_lt_p), range_ok = marshal_scalars(
+        digests, r_bytes, s_bytes)
+    range_ok = (range_ok & _lt_bytes(qx_bytes, _P_BYTES)
                 & _lt_bytes(qy_bytes, _P_BYTES))
-    rn_lt_p = _lt_bytes(r_bytes, _P_MINUS_N_BYTES)
-    core_args = (_host_limbs(digests), _host_limbs(r_bytes),
-                 _host_limbs(s_bytes), _host_limbs(qx_bytes),
-                 _host_limbs(qy_bytes), rn_lt_p)
+    core_args = (e, r, s, _host_limbs(qx_bytes), _host_limbs(qy_bytes),
+                 rn_lt_p)
     return core_args, range_ok
 
 
@@ -662,6 +899,15 @@ def _put(x: np.ndarray, sharding):
     if sharding is None:
         return jnp.asarray(x)
     return jax.device_put(x, sharding)
+
+
+def _verdicts(ok, range_ok: np.ndarray, lazy: bool):
+    """A dispatched program's mask ANDed with the host's range
+    verdict: the array, or with `lazy` a resolver that fetches it when
+    called (the program has been dispatched, not awaited)."""
+    if lazy:
+        return lambda: np.asarray(ok) & range_ok
+    return np.asarray(ok) & range_ok
 
 
 def place_core_args(core_args, mesh=None):
@@ -698,9 +944,7 @@ def batch_verify(digests: np.ndarray, r_bytes: np.ndarray,
         digests, r_bytes, s_bytes, qx_bytes, qy_bytes)
     core = _select_core(digests.shape[0], mesh)
     ok = core(*place_core_args(core_args, mesh))
-    if lazy:
-        return lambda: np.asarray(ok) & range_ok
-    return np.asarray(ok) & range_ok
+    return _verdicts(ok, range_ok, lazy)
 
 
 def batch_verify_raw(words: np.ndarray, nblocks: np.ndarray,
@@ -736,9 +980,33 @@ def batch_verify_raw(words: np.ndarray, nblocks: np.ndarray,
               _put(np.asarray(nblocks, np.int32), flag_s),
               _put(np.asarray(has_msg, bool), flag_s),
               *place_core_args(core_args, mesh))
-    if lazy:
-        return lambda: np.asarray(ok) & range_ok
-    return np.asarray(ok) & range_ok
+    return _verdicts(ok, range_ok, lazy)
+
+
+def batch_verify_tables(digests: np.ndarray, r_bytes: np.ndarray,
+                        s_bytes: np.ndarray, slot: np.ndarray,
+                        slot_ok: np.ndarray, tables, msg=None,
+                        lazy: bool = False):
+    """`batch_verify` for lanes whose public keys have fixed-base
+    tables: (batch, 32) uint8 digests and scalars, `slot` (batch,)
+    which slot of `tables` (a device array over `empty_key_tables`'
+    layout) holds the lane's `key_table`, `slot_ok` (batch,) False
+    where the key is no curve point.  `msg`, if given, is
+    `batch_verify_raw`'s (words, nblocks, has_msg): those lanes' digests
+    are computed on the device in the same program.  One device (the
+    provider keeps the ladder for a mesh)."""
+    core_args, range_ok = marshal_scalars(digests, r_bytes, s_bytes)
+    args = core_args + (np.asarray(slot, np.int32),
+                        np.asarray(slot_ok, bool))
+    if msg is None:
+        core = verify_core_tables
+    else:
+        core = verify_core_tables_fused
+        words, nblocks, has_msg = msg
+        args = (np.asarray(words, np.uint32), np.asarray(nblocks, np.int32),
+                np.asarray(has_msg, bool)) + args
+    ok = core(*map(jnp.asarray, args), tables)
+    return _verdicts(ok, range_ok, lazy)
 
 
 def _select_core(batch: int, mesh, fused: bool = False):

@@ -658,6 +658,11 @@ class TxValidator:
             chunks = getattr(mask_fn, "chunks", None)
             if chunks is not None:
                 dispatch_span.set(chunks=chunks)
+            # and its device lanes by the program that verifies them
+            table_lanes = getattr(mask_fn, "table_lanes", None)
+            if table_lanes is not None:
+                dispatch_span.set(table_lanes=table_lanes,
+                                  ladder_lanes=mask_fn.ladder_lanes)
             if gate is not None:
                 # block-signature items that rode this batch
                 dispatch_span.set(

@@ -96,6 +96,29 @@ def test_xla_verify_core_bucket_2048_fits_one_v5e(one_chip,
     assert out.shape == (BUCKET,) and out.dtype == jnp.bool_
 
 
+@pytest.mark.parametrize("bucket", [8, 64, BUCKET])
+def test_xla_verify_core_tables_fits_one_v5e(bucket, one_chip,
+                                             no_persistent_cache):
+    """The program a lane takes when its public key has a table on the
+    device (every lane of every benchmark cell), at the buckets the
+    cells warm, over the provider's `KEY_SLOTS` slots.  ~10-18 s each
+    here: no Q-table build, a scan body of one addition."""
+    from fabric_mod_tpu.bccsp.tpu import KEY_SLOTS
+    from fabric_mod_tpu.ops import p256
+    limb, _, _, _, _, flag = _verify_core_shapes(one_chip, bucket)
+    slot = jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=one_chip)
+    tables = jax.ShapeDtypeStruct(p256.empty_key_tables(KEY_SLOTS).shape,
+                                  jnp.float32, sharding=one_chip)
+    t0 = time.perf_counter()
+    compiled = p256.verify_core_tables.lower(
+        limb, limb, limb, flag, slot, flag, tables).compile()
+    mem = _report(f"xla verify_core_tables (30, {bucket})",
+                  time.perf_counter() - t0, compiled)
+    assert mem["total"] < HBM_BYTES, mem
+    (out,) = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.shape == (bucket,) and out.dtype == jnp.bool_
+
+
 def test_sha256_batch_hash_2048x4_blocks(one_chip, no_persistent_cache):
     from fabric_mod_tpu.ops import sha256
     words = jax.ShapeDtypeStruct((BUCKET, 4, 16), jnp.uint32,

@@ -6,6 +6,7 @@ docstring (and the PRECISION setting of the constant matmuls): any
 inexact product/sum shows up as a wrong limb, never as a tolerance.
 """
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 from fabric_mod_tpu.ops import limbs9 as L
@@ -140,3 +141,24 @@ def test_mul_small(rng):
     got = np.asarray(L.canonical(out, FP))
     for i in range(8):
         assert L.limbs_to_int(got[:, i]) == (13 * a[i]) % P256_P
+
+
+def _be_bytes_to_limbs_by_bits(buf):
+    """The bit-by-bit reference for `be_bytes_to_limbs`: unpack every
+    bit, weigh B of them a limb."""
+    bits = np.unpackbits(np.asarray(buf, np.uint8)[..., ::-1], axis=-1,
+                         bitorder="little")
+    pad = np.zeros(bits.shape[:-1] + (L.RBITS - 256,), np.uint8)
+    bits = np.concatenate([bits, pad], axis=-1).reshape(
+        bits.shape[:-1] + (L.K, L.B))
+    return (bits.astype(np.int32) << np.arange(L.B)).sum(-1)
+
+
+@pytest.mark.parametrize("shape", [(32,), (7, 32), (2048, 32), (3, 2, 32)])
+def test_be_bytes_to_limbs_matches_the_bitwise_reference(shape):
+    gen = np.random.default_rng(len(shape) * 1000 + shape[0])
+    for buf in (gen.integers(0, 256, shape, dtype=np.uint8),
+                np.full(shape, 255, np.uint8), np.zeros(shape, np.uint8)):
+        got = L.be_bytes_to_limbs(buf)
+        assert got.dtype == np.int32 and got.shape == shape[:-1] + (L.K,)
+        np.testing.assert_array_equal(got, _be_bytes_to_limbs_by_bits(buf))
