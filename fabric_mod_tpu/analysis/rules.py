@@ -174,12 +174,28 @@ class SpanNameRule(Rule):
            "declared-but-unused names are flagged on whole-package "
            "runs")
 
-    EXEMPT = {"observability/tracing.py", "observability/spannames.py"}
+    EXEMPT = {"observability/spannames.py"}
+    # records spans itself (the collector's pauses): `Span(NAME, ...)`,
+    # NAME a module-level string constant, is a use of that name
+    TRACER = "observability/tracing.py"
 
     def check(self, mod, ctx):
         if mod.pkgpath in self.EXEMPT:
             return
-        from fabric_mod_tpu.observability import spannames
+        if mod.pkgpath == self.TRACER:
+            consts = {t.id: _str_const(node.value)
+                      for node in mod.tree.body
+                      if isinstance(node, ast.Assign)
+                      for t in node.targets if isinstance(t, ast.Name)}
+            for node in ast.walk(mod.tree):
+                if (isinstance(node, ast.Call)
+                        and isinstance(node.func, ast.Name)
+                        and node.func.id == "Span" and node.args
+                        and isinstance(node.args[0], ast.Name)
+                        and consts.get(node.args[0].id) is not None):
+                    yield from self._use(mod, node,
+                                         consts[node.args[0].id], ctx)
+            return
         al = _aliases(mod.tree)
         tracing_names = (al.get("fabric_mod_tpu.observability.tracing",
                                 set())
@@ -198,12 +214,16 @@ class SpanNameRule(Rule):
                     "of every timeline/metric view — pass a declared "
                     "literal")
                 continue
-            ctx.span_names_used.add(name)
-            if not spannames.is_declared(name):
-                yield self._f(
-                    mod, node,
-                    f"span name {name!r} not declared in "
-                    f"observability/spannames.py")
+            yield from self._use(mod, node, name, ctx)
+
+    def _use(self, mod, node, name, ctx):
+        from fabric_mod_tpu.observability import spannames
+        ctx.span_names_used.add(name)
+        if not spannames.is_declared(name):
+            yield self._f(
+                mod, node,
+                f"span name {name!r} not declared in "
+                f"observability/spannames.py")
 
 
 class ThreadRule(Rule):

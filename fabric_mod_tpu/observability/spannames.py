@@ -28,6 +28,7 @@ DECLARED_SPANS: Set[str] = {
     "dispatch_chunk",
     "fanout.materialize",
     "fingerprint",
+    "gc_pause",
     "gossip.drain",
     "ledger_write",
     "mcs_verify",

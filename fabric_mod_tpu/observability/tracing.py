@@ -9,7 +9,7 @@ metrics half of this layer (core/operations + common/diag,
 reproduced in observability/metrics.py + opsserver.py); this module
 is the missing tracing half.)
 
-Three instruments, one arming gate (``FMT_TRACE``, the FMT_RACECHECK
+Four instruments, one arming gate (``FMT_TRACE``, the FMT_RACECHECK
 / FMT_FAULTS cost model — unset, every seam is one module-flag read
 and NO span objects are allocated):
 
@@ -44,6 +44,20 @@ and NO span objects are allocated):
   milliseconds went, in a bounded **flight recorder** ring served at
   ``/flight``.
 
+* **The collector's pauses** — while armed, and only then, one
+  ``gc.callbacks`` entry times every collection of every generation
+  as a span ``gc_pause`` (attributes ``generation``, ``collected``,
+  ``uncollectable``) on the thread that ran it, nested under the span
+  open there: the pause leaves that span's self time, and joins the
+  block timeline installed on that thread.  A collection can start
+  inside any allocation, the recorder's own critical section
+  included, so the callback takes no lock: it reads the clocks,
+  appends one tuple to the recorder's inbox, and ``add_span``,
+  ``totals()`` and ``recent_spans()`` drain the inbox into the ring,
+  the totals and the histogram.  Its cost is two callbacks a
+  collection, armed only; unarmed, ``gc.callbacks`` holds nothing of
+  this module's.
+
 * **Auto-dumps** — SoakError, a circuit-breaker open, and fault-seam
   fires snapshot the recorder (rate-limited) so a failure report
   carries the timeline of what the system was DOING, not just which
@@ -61,6 +75,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import functools
+import gc
 import json
 import os
 import threading
@@ -84,18 +99,18 @@ def armed() -> bool:
 def enable(on: bool) -> None:
     global _enabled
     _enabled = bool(on)
+    _hook_collector(_enabled)
 
 
 @contextlib.contextmanager
 def active(on: bool = True):
     """Scoped arming — tests and the bench's traced arms."""
-    global _enabled
     prev = _enabled
-    _enabled = bool(on)
+    enable(on)
     try:
         yield
     finally:
-        _enabled = prev
+        enable(prev)
 
 
 # -- clock (injectable: tests drive a ManualClock through spans) ------------
@@ -247,9 +262,11 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
-        _stack().append(self)
+        # timed before it is pushed: a collection that finds it on the
+        # stack finds its `ts` set (`_on_gc`)
         self._cpu0 = time.thread_time()
         self.ts = _clock()
+        _stack().append(self)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -403,18 +420,48 @@ class Recorder:
         # name -> [secs, n, self secs, cpu secs]
         self._totals: Dict[str, List[float]] = {}
         self._last_dump = 0.0
+        # the collector's pauses not yet drained, appended by `_on_gc`
+        # without the lock and taken out only under it; bounded for a
+        # process that is armed and records nothing else (a collection
+        # at every allocation, tests/test_tracing.py, left ~1,500)
+        self._pauses: collections.deque = collections.deque(
+            maxlen=max(SPAN_RING, 1 << 16))
 
     def add_span(self, sp: Span) -> None:
         with self._lock:
-            self._spans.append(sp.to_dict())
-            tot = self._totals.get(sp.name)
-            if tot is None:
-                tot = self._totals[sp.name] = [0.0, 0, 0.0, 0.0]
-            tot[0] += sp.dur
-            tot[1] += 1
-            tot[2] += sp.self_dur
-            tot[3] += sp.cpu
+            pauses = self._drain_locked()
+            self._put_locked(sp)
+        _observe_pauses(pauses)
         _substage_hist().with_labels(sp.name).observe(sp.dur)
+
+    def _put_locked(self, sp: Span) -> None:
+        self._spans.append(sp.to_dict())
+        tot = self._totals.get(sp.name)
+        if tot is None:
+            tot = self._totals[sp.name] = [0.0, 0, 0.0, 0.0]
+        tot[0] += sp.dur
+        tot[1] += 1
+        tot[2] += sp.self_dur
+        tot[3] += sp.cpu
+
+    def _drain_locked(self) -> List[float]:
+        """The pauses `_on_gc` left, into the ring and the totals as
+        spans; their durations, for the histogram outside the lock."""
+        durs = []
+        while self._pauses:
+            (ts, dur, cpu, thread, parent, generation, collected,
+             uncollectable) = self._pauses.popleft()
+            sp = Span(GC_PAUSE,
+                      parent.trace_id if parent else new_trace_id(),
+                      os.urandom(4).hex(),
+                      parent.span_id if parent else None,
+                      {"generation": generation, "collected": collected,
+                       "uncollectable": uncollectable})
+            sp.thread, sp.ts, sp.dur, sp.self_dur, sp.cpu = (
+                thread, ts, dur, dur, cpu)
+            self._put_locked(sp)
+            durs.append(dur)
+        return durs
 
     def add_timeline(self, tl: BlockTimeline) -> None:
         with self._lock:
@@ -429,7 +476,9 @@ class Recorder:
     def recent_spans(self, trace_id: Optional[str] = None,
                      limit: int = 512) -> List[Dict]:
         with self._lock:
+            pauses = self._drain_locked()
             out = list(self._spans)
+        _observe_pauses(pauses)
         if trace_id is not None:
             out = [s for s in out if s["trace_id"] == trace_id]
         return out[-limit:]
@@ -444,10 +493,13 @@ class Recorder:
 
     def totals(self) -> Dict[str, Dict[str, float]]:
         with self._lock:
-            return {name: {"secs": round(t[0], 6), "count": int(t[1]),
-                           "self_secs": round(t[2], 6),
-                           "cpu_secs": round(t[3], 6)}
-                    for name, t in self._totals.items()}
+            pauses = self._drain_locked()
+            out = {name: {"secs": round(t[0], 6), "count": int(t[1]),
+                          "self_secs": round(t[2], 6),
+                          "cpu_secs": round(t[3], 6)}
+                   for name, t in self._totals.items()}
+        _observe_pauses(pauses)
+        return out
 
     def dumps(self) -> List[Dict]:
         with self._lock:
@@ -469,6 +521,7 @@ class Recorder:
             self._events.clear()
             self._dumps.clear()
             self._totals.clear()
+            self._pauses.clear()
             self._last_dump = 0.0
 
     # -- auto-dump ---------------------------------------------------------
@@ -499,6 +552,75 @@ _recorder = Recorder()
 
 def recorder() -> Recorder:
     return _recorder
+
+
+# -- the collector's pauses --------------------------------------------------
+
+GC_PAUSE = "gc_pause"
+# the open collection's `_clock()` (None: none open) and thread CPU time;
+# the interpreter runs one collection at a time
+_gc_began: List[Optional[float]] = [None, 0.0]
+_hook_lock = RegisteredLock("observability.tracing._hook_lock")
+
+
+def _on_gc(phase: str, info: Dict) -> None:
+    """The `gc.callbacks` entry, installed while armed only.  A
+    collection starts inside whatever allocation crossed the threshold,
+    on any thread, with whatever locks that thread holds — the
+    recorder's and a histogram's included — so this takes no lock and
+    never blocks: it reads the clocks, charges the pause to the span
+    open on this thread, and appends one tuple for `Recorder` to
+    drain."""
+    if phase == "start":
+        _gc_began[1] = time.thread_time()
+        _gc_began[0] = _clock()
+        return
+    t0 = _gc_began[0]
+    if t0 is None:              # hooked while a collection ran
+        return
+    _gc_began[0] = None
+    t1 = _clock()
+    cpu = time.thread_time() - _gc_began[1]
+    st = getattr(_tls, "stack", None)
+    parent = st[-1] if st else None
+    if parent is not None:
+        # only the part inside the parent: a closing span has its `dur`
+        end = parent.ts + parent.dur if parent.dur else t1
+        inside = min(t1, end) - max(t0, parent.ts)
+        if inside > 0:
+            parent._child += inside
+    tl = getattr(_tls, "timeline", None)
+    if tl is not None:
+        tl.add(GC_PAUSE, t0, t1 - t0)
+    ident = threading.get_ident()
+    # not `current_thread()`: for a thread `threading` never saw it
+    # registers one under `threading`'s own lock
+    th = threading._active.get(ident)
+    _recorder._pauses.append((
+        t0, t1 - t0, cpu, th.name if th is not None else f"Dummy-{ident}",
+        parent, info["generation"], info["collected"],
+        info["uncollectable"]))
+
+
+def _hook_collector(on: bool) -> None:
+    with _hook_lock:
+        hooked = _on_gc in gc.callbacks
+        if on != hooked:
+            _gc_began[0] = None
+            if on:
+                gc.callbacks.append(_on_gc)
+            else:
+                gc.callbacks.remove(_on_gc)
+
+
+def _observe_pauses(durs: List[float]) -> None:
+    if durs:
+        hist = _substage_hist().with_labels(GC_PAUSE)
+        for dur in durs:
+            hist.observe(dur)
+
+
+_hook_collector(_enabled)
 
 
 def note_event(kind: str, detail: str) -> None:

@@ -208,7 +208,7 @@ def test_batch_over_the_table_budget_splits_and_merges_in_lane_order(
         tracing.recorder().reset()
         resolve = verifier.verify_many_async(items)
         got = resolve()
-        spans = tracing.recorder().recent_spans()
+        spans = tracing.recorder().recent_spans(limit=1 << 20)
     assert list(got) == swcsp.verify_batch(items) \
         == [True, False, False, True, True, True, True]
     assert (resolve.table_lanes, resolve.ladder_lanes) == (5, 2)

@@ -18,7 +18,15 @@ Contract under test, in order of importance:
    pipeline's waits are spans (`spannames.WAIT_SPANS`), the provider's
    spans nest under the seam that called them, and an armed span's
    `ts` lies on the clock of an open `jax.profiler` session.
+5. The collector's pauses: armed, every collection is a span
+   `gc_pause` nested under the span open on its thread; unarmed,
+   `gc.callbacks` holds nothing of the tracer's; a collection inside
+   the recorder's or a histogram's lock neither deadlocks nor is lost.
+   (Any armed test may see a pause: the others filter `gc_pause` out
+   where they count spans or children.)
 """
+import collections
+import gc
 import glob
 import json
 import os
@@ -81,7 +89,8 @@ def test_armed_span_nesting_parents_and_ring():
                 assert c.trace_id == p.trace_id
                 assert c.parent_id == p.span_id
         assert tracing.current_ctx() is None
-    spans = tracing.recorder().recent_spans()
+    spans = [s for s in tracing.recorder().recent_spans()
+             if s["name"] != "gc_pause"]
     assert [s["name"] for s in spans] == ["child", "parent"]
     assert spans[0]["parent_id"] == spans[1]["span_id"]
     # per-name totals accumulated (the bench attribution surface)
@@ -108,7 +117,8 @@ def test_injectable_clock_drives_span_durations():
         with tracing.active():
             with tracing.span("timed"):
                 clk.t += 2.5
-        got = tracing.recorder().recent_spans()[-1]
+        (got,) = [s for s in tracing.recorder().recent_spans()
+                  if s["name"] == "timed"]
         assert got["dur"] == pytest.approx(2.5)
         assert got["ts"] == pytest.approx(100.0)
     finally:
@@ -321,10 +331,14 @@ def test_self_time_ignores_spans_of_other_threads():
             time.sleep(0.02)
             gate_out.set()
             t.join()
-    spans = {s["name"]: s for s in tracing.recorder().recent_spans()}
+    ring = tracing.recorder().recent_spans()
+    spans = {s["name"]: s for s in ring}
     assert spans["mvcc"]["dur"] >= 0.02
+    # less only what collected on this thread inside it
+    paused = sum(s["dur"] for s in ring if s["name"] == "gc_pause"
+                 and s["parent_id"] == spans["unpack"]["span_id"])
     assert spans["unpack"]["self"] == pytest.approx(
-        spans["unpack"]["dur"], abs=1e-6)
+        spans["unpack"]["dur"] - paused, abs=1e-5)
 
 
 def test_cpu_time_of_a_busy_loop_and_of_a_sleep():
@@ -412,7 +426,8 @@ def test_provider_spans_nest_under_the_seam_that_called(monkeypatch):
         return parent["name"] if parent else None
 
     def children(s):
-        return {c["name"] for c in spans if c["parent_id"] == s["span_id"]}
+        return {c["name"] for c in spans if c["parent_id"] == s["span_id"]
+                and c["name"] != "gc_pause"}
 
     checks = [s for s in spans if s["name"] == "mcs_verify"]
     assert checks and all(parent_name(s) == "recv" for s in checks)
@@ -434,8 +449,9 @@ def test_provider_spans_nest_under_the_seam_that_called(monkeypatch):
     assert all(s["attrs"]["bucket"] >= 8 for s in enq)
     # recv's self time is the pull: what is left beside the check
     recv = by_id[checks[0]["parent_id"]]
-    assert recv["self"] == pytest.approx(
-        recv["dur"] - checks[0]["dur"], abs=1e-5)
+    assert recv["self"] == pytest.approx(recv["dur"] - sum(
+        c["dur"] for c in spans if c["parent_id"] == recv["span_id"]),
+        abs=1e-5)
     for s in dispatches:
         nested = sum(c["dur"] for c in spans
                      if c["parent_id"] == s["span_id"])
@@ -676,3 +692,344 @@ def test_procnet_broadcast_trace_stitches_across_processes(tmp_path,
         assert _wait(peer_flight, t=30)
     finally:
         net.teardown()
+
+
+# ---------------------------------------------------------------------------
+# 5. the collector's pauses (`gc_pause`)
+# ---------------------------------------------------------------------------
+
+def _full_pauses(ring):
+    return [s for s in ring
+            if s["name"] == "gc_pause" and s["attrs"]["generation"] == 2]
+
+
+def test_a_collection_inside_a_span_is_a_gc_pause_nested_under_it():
+    hist = tracing._substage_hist().with_labels("gc_pause")
+    observed = hist.count
+    with tracing.active():
+        with tracing.span("unpack") as parent:
+            gc.collect()
+        # nothing drained it yet but the span's own exit: a collection
+        # outside every span waits in the inbox for `totals()`
+        gc.collect()
+        assert tracing.recorder()._pauses
+        totals = tracing.recorder().totals()
+        assert not tracing.recorder()._pauses
+    ring = tracing.recorder().recent_spans(limit=1 << 20)
+    (inside,) = [p for p in _full_pauses(ring)
+                 if p["parent_id"] == parent.span_id]
+    assert inside["trace_id"] == parent.trace_id
+    # (an automatic full collection would be another: rare, harmless)
+    outside = [p for p in _full_pauses(ring) if p["parent_id"] is None][-1]
+    for p in (inside, outside):
+        assert p["thread"] == threading.current_thread().name
+        assert set(p["attrs"]) == {"generation", "collected",
+                                   "uncollectable"}
+        assert p["self"] == p["dur"] and 0 <= p["cpu"]
+    assert parent.ts <= inside["ts"]
+    assert inside["ts"] + inside["dur"] <= parent.ts + parent.dur + 1e-6
+    # the pause is no longer the parent's own time
+    nested = sum(s["dur"] for s in ring
+                 if s["parent_id"] == parent.span_id)
+    assert nested >= inside["dur"]
+    assert parent.self_dur == pytest.approx(parent.dur - nested, abs=1e-5)
+    assert totals["gc_pause"]["count"] == len(
+        [s for s in ring if s["name"] == "gc_pause"])
+    assert totals["gc_pause"]["secs"] >= inside["dur"] + outside["dur"] \
+        - 1e-5
+    assert hist.count - observed == totals["gc_pause"]["count"]
+
+
+def test_a_pause_joins_the_block_timeline_of_its_thread():
+    with tracing.active():
+        tl = tracing.start_timeline("deliver", 5)
+        with tracing.timeline_scope(tl):
+            with tracing.span("mvcc"):
+                gc.collect()
+        tracing.finish_timeline(tl)
+    (got,) = tracing.recorder().timelines()
+    names = [s["name"] for s in got["subs"]]
+    assert "gc_pause" in names and names[-1] == "mvcc"
+
+
+def test_a_pause_charges_only_what_lies_inside_its_parent():
+    """A closing span (its `dur` set, not yet popped) keeps the part of
+    a pause after its end; self time never goes below zero."""
+    now = [10.0]
+    tracing.set_clock(lambda: now[0])
+    try:
+        with tracing.active():
+            with tracing.span("unpack") as sp:
+                now[0] += 1.0
+                sp.dur = 1.0            # as `__exit__` sets it first
+                tracing._on_gc("start", {"generation": 0})
+                now[0] += 0.5
+                tracing._on_gc("stop", {"generation": 0, "collected": 0,
+                                        "uncollectable": 0})
+                assert sp._child == 0.0
+                sp.dur = 0.0
+                tracing._on_gc("start", {"generation": 1})
+                now[0] += 5.0
+                tracing._on_gc("stop", {"generation": 1, "collected": 3,
+                                        "uncollectable": 0})
+                assert sp._child == pytest.approx(5.0)
+    finally:
+        tracing.set_clock(time.time)
+    spans = tracing.recorder().recent_spans()
+    (unpack,) = [s for s in spans if s["name"] == "unpack"]
+    assert unpack["dur"] == pytest.approx(6.5)
+    assert unpack["self"] == pytest.approx(1.5)
+    assert sorted(s["attrs"]["generation"] for s in spans
+                  if s["name"] == "gc_pause" and s["ts"] >= 10.0) == [0, 1]
+
+
+@pytest.mark.parametrize("armed_at_import", [False, True])
+def test_the_hook_is_in_gc_callbacks_exactly_while_armed(armed_at_import):
+    code = ("import gc\n"
+            "before = list(gc.callbacks)\n"
+            "from fabric_mod_tpu.observability import tracing\n"
+            "hooked = [cb for cb in gc.callbacks if cb not in before]\n"
+            f"assert hooked == ([tracing._on_gc] if {armed_at_import} "
+            "else []), hooked\n"
+            "tracing.enable(False)\n"
+            "assert gc.callbacks == before\n")
+    env = dict(os.environ)
+    env.pop("FMT_TRACE", None)
+    if armed_at_import:
+        env["FMT_TRACE"] = "1"
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   cwd=os.path.dirname(os.path.dirname(
+                       os.path.abspath(__file__))))
+    # in this process: once when armed, however often armed; gone when
+    # disarmed, by `enable` and by `active`'s exit alike
+    hooked = lambda: gc.callbacks.count(tracing._on_gc)  # noqa: E731
+    assert hooked() == 0
+    tracing.enable(True)
+    tracing.enable(True)
+    assert hooked() == 1
+    tracing.enable(False)
+    assert hooked() == 0
+    with tracing.active():
+        assert hooked() == 1
+        with tracing.active(False):
+            assert hooked() == 0
+        assert hooked() == 1
+    assert hooked() == 0
+    gc.collect()
+    assert "gc_pause" not in tracing.recorder().totals()
+    assert tracing.recorder().span_count() == 0
+
+
+class _CollectOnce:
+    """A seam that forces one full collection while its lock is held."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.left = 1
+        self.seen = []
+
+    def collect_locked(self):
+        if self.left:
+            self.left -= 1
+            gc.collect()
+
+
+class _CollectingDeque(collections.deque):
+    seam = None
+
+    def append(self, item):
+        self.seam.collect_locked()     # under the recorder's lock
+        super().append(item)
+
+
+class _CollectingHist(_CollectOnce):
+    def with_labels(self, *values):
+        return self
+
+    def observe(self, value):
+        with self._lock:
+            self.collect_locked()
+            self.seen.append(value)
+
+
+@pytest.mark.parametrize("where", ["recorder", "histogram"])
+def test_a_collection_inside_a_critical_section_is_kept(where,
+                                                        monkeypatch):
+    rec = tracing.recorder()
+    if where == "recorder":
+        seam = _CollectOnce()
+        ring = _CollectingDeque(rec._spans, maxlen=rec._spans.maxlen)
+        ring.seam = seam
+        monkeypatch.setattr(rec, "_spans", ring)
+    else:
+        seam = _CollectingHist()
+        monkeypatch.setattr(tracing, "_substage_hist", lambda: seam)
+
+    def work():
+        with tracing.active():
+            with tracing.span("mvcc"):
+                pass
+
+    worker = threading.Thread(target=work, name="gc-seam")
+    worker.start()
+    worker.join(30.0)
+    assert not worker.is_alive(), "a collection under a lock deadlocked"
+    assert seam.left == 0
+    pause = [p for p in _full_pauses(rec.recent_spans(limit=1 << 20))
+             if p["thread"] == "gc-seam"][0]
+    assert rec.totals()["gc_pause"]["count"] >= 1
+    if where == "histogram":
+        assert any(abs(d - pause["dur"]) <= 1e-6 for d in seam.seen)
+
+
+def test_a_collection_at_every_allocation_loses_no_pause():
+    """Threshold 1: collections inside every seam of the recorder, on
+    more threads than cores switching every 10 us; every collection the
+    interpreter reports is a span."""
+    stops = []
+
+    def count(phase, info):
+        if phase == "stop":
+            stops.append(info["generation"])
+
+    def work(n):
+        for i in range(n):
+            with tracing.span("unpack", block=i):
+                with tracing.span("mvcc"):
+                    pass
+
+    was_enabled, threshold = gc.isenabled(), gc.get_threshold()
+    switch = sys.getswitchinterval()
+    with tracing.active():
+        gc.disable()
+        before = tracing.recorder().totals().get(
+            "gc_pause", {"count": 0})["count"]
+        gc.callbacks.append(count)
+        gc.set_threshold(1, 10, 1000)
+        sys.setswitchinterval(1e-5)
+        gc.enable()
+        try:
+            workers = [threading.Thread(target=work, args=(8,))
+                       for _ in range((os.cpu_count() or 1) + 2)]
+            for t in workers:
+                t.start()
+            for t in workers:
+                t.join(60.0)
+            assert not any(t.is_alive() for t in workers)
+        finally:
+            gc.disable()
+            sys.setswitchinterval(switch)
+            gc.set_threshold(*threshold)
+            gc.callbacks.remove(count)
+        after = tracing.recorder().totals()["gc_pause"]["count"]
+        if was_enabled:
+            gc.enable()
+    assert len(stops) > 100
+    assert after - before == len(stops)
+
+
+def test_the_callback_records_no_lock_order_edge():
+    from fabric_mod_tpu import concurrency
+    from fabric_mod_tpu.concurrency import RegisteredLock, lock_registry
+    outer = RegisteredLock("test.gc.outer")
+    with concurrency.armed():
+        with tracing.active():
+            with outer:
+                edges = lock_registry().edge_count()
+                gc.collect()
+                assert lock_registry().edge_count() == edges
+    assert _full_pauses(tracing.recorder().recent_spans())
+
+
+def _layer_metric(name):
+    """(spec, reduce) of one of the two metrics of `gc_pause`, with its
+    entry in `BENCHMARK.json` checked."""
+    from benchmarks import manifest
+    bench = manifest.benchmark_json()
+    (entry,) = [p for p in bench["per_layer"] if p["name"] == name]
+    assert entry["moves"] == "committed_tx_s"
+    assert entry["layer"] == "interpreter"
+    assert "workloads" not in entry              # every cell
+    return manifest.reducer_for(name)
+
+
+def test_gc_pause_ms_per_block_reads_the_recorded_pauses():
+    import dataclasses
+    spec, reduce_fn = _layer_metric("gc_pause_ms_per_block")
+    assert spec["spans"] == ["gc_pause"]
+
+    @dataclasses.dataclass
+    class Window:
+        blocks: int
+        txs: int
+        span_secs: dict
+        span_counts: dict
+
+    with tracing.active():
+        t0 = tracing.recorder().totals()
+        for _ in range(3):
+            gc.collect()
+        t1 = tracing.recorder().totals()
+
+    def delta(key):
+        return {n: t1[n][key] - t0.get(n, {key: 0})[key] for n in t1}
+    window = Window(4, 2000, delta("secs"), delta("count"))
+    assert window.span_counts["gc_pause"] >= 3
+    assert reduce_fn(spec, window) == pytest.approx(
+        1e3 * window.span_secs["gc_pause"] / 4)
+    # a program that records no pause (the parent): no number, no raise
+    assert reduce_fn(spec, Window(4, 2000, {}, {})) is None
+
+
+def test_idle_under_gc_pct_reads_a_recorded_ring_beside_made_up_programs(
+        monkeypatch):
+    """Two programs sent by two `device_enqueue` spans, an `unpack`
+    between them with one full collection in it: of the idle time, the
+    pause is `gc_pause`'s and no longer `unpack`'s."""
+    import types
+    from benchmarks import manifest, timeline
+    from benchmarks.reducers import idle_under
+    spec, reduce_fn = _layer_metric("idle_under_gc_pct")
+    unpack_spec, _ = manifest.reducer_for("idle_under_unpack_pct")
+    with tracing.active():
+        with tracing.span("device_enqueue"):
+            time.sleep(0.001)
+        with tracing.span("unpack"):
+            time.sleep(0.005)
+            gc.collect()
+            time.sleep(0.005)
+        with tracing.span("device_enqueue"):
+            time.sleep(0.001)
+    ring = tracing.recorder().recent_spans(limit=1 << 20)
+    enq = [s for s in ring if s["name"] == "device_enqueue"]
+    (unpack,) = [s for s in ring if s["name"] == "unpack"]
+    (pause,) = _full_pauses(ring)
+    start_ns = enq[0]["ts"] * 1e9 - 1e6
+    on_trace = lambda ts: ts * 1e9 - start_ns  # noqa: E731
+    programs = [("jit__verify_core_tables_impl", on_trace(s["ts"]) + 1e5,
+                 on_trace(s["ts"]) + 5e5) for s in enq]
+    monkeypatch.setattr(timeline, "find_session_xplane",
+                        lambda since: "made-up")
+    monkeypatch.setattr(timeline, "read_session", lambda path: (
+        programs, (start_ns, on_trace(enq[-1]["ts"]) * 2 + start_ns),
+        None))
+    window_s = (programs[-1][2] - programs[0][1]) / 1e9
+    window = types.SimpleNamespace(
+        trace=types.SimpleNamespace(window_s=window_s))
+    idle_under.view_of_run.cache_clear()
+    try:
+        gc_pct = reduce_fn(spec, window)
+        unpack_pct = reduce_fn(unpack_spec, window)
+        # the harness's arithmetic by hand: the stretch is the two
+        # programs and what lies between; the pause is all idle
+        assert gc_pct == pytest.approx(
+            100.0 * pause["dur"] / window_s, rel=0.02, abs=0.05)
+        assert unpack_pct == pytest.approx(
+            100.0 * (unpack["dur"] - pause["dur"]) / window_s,
+            rel=0.02, abs=0.05)
+        # a span the run never recorded: nothing
+        assert reduce_fn(dict(spec, spans=["no_such_span"]), window) \
+            is None
+    finally:
+        idle_under.view_of_run.cache_clear()
+

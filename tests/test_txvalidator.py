@@ -484,7 +484,7 @@ def test_decode_paths_commit_the_same_state(world, tmp_path, monkeypatch,
                           led.state_fingerprint_full())
             led.close()
         mvcc_paths = [s["attrs"]["path"]
-                      for s in tracing.recorder().recent_spans()
+                      for s in tracing.recorder().recent_spans(limit=1 << 20)
                       if s["name"] == "mvcc_validate"]
     finally:
         tracing.recorder().reset()
@@ -512,7 +512,7 @@ def test_decode_path_engages_and_is_observable(world):
             with tracing.active():
                 staged = validator.stage(
                     _block(_plain_envs(world, n), num=n))
-            spans = [s for s in tracing.recorder().recent_spans()
+            spans = [s for s in tracing.recorder().recent_spans(limit=1 << 20)
                      if s["attrs"].get("block") == n]
             unpack = [s for s in spans if s["name"] == "unpack"]
             assert [s["attrs"]["decoder"] for s in unpack] == [path]

@@ -506,7 +506,7 @@ def _commit_traced(led, validator, block, hand_over=True):
             final = led.commit_block(
                 block, flags, rwsets=staged.rwsets if hand_over else None)
         spans = {s["name"]: s["attrs"]
-                 for s in tracing.recorder().recent_spans()}
+                 for s in tracing.recorder().recent_spans(limit=1 << 20)}
     finally:
         tracing.recorder().reset()
     return dict(
