@@ -24,6 +24,7 @@ import numpy as np
 
 from benchmarks import reference
 from benchmarks.manifest import Cell, reducer_for
+from benchmarks.reducers import idle_under
 
 
 class RunFailure(RuntimeError):
@@ -132,7 +133,8 @@ class Window:
     span_counts: Dict[str, int]
     dispatches: List[tuple]            # (items, bucket) of the window
     trace: object = None               # reduce.TraceSummary | None
-    traced_items: int = 0              # real signatures of traced blocks
+    session: object = None             # timeline.Session | None
+    ring: List[dict] = dataclasses.field(default_factory=list)
     device_kind: str = ""
 
 
@@ -291,8 +293,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
             puller.start()
 
             # -- the window
-            trace_summary = None
-            traced_nums: List[int] = []
+            trace_summary = session = None
             try:
                 opened = stamps.wait(
                     lambda: stamps.t0 is not None or not puller.is_alive(),
@@ -302,7 +303,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
                     say(f"window open: set-up {setup_s:.2f}s, "
                         f"{compiles_warm} compile events while warming")
                     if traced:
-                        trace_summary, traced_nums = profile_window(
+                        trace_summary, session = profile_window(
                             stamps, cell, seconds, root, say)
                     n_all = backlog.n_blocks
 
@@ -407,9 +408,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
                 set(concurrency.live_registered()) - live_at_rest)
 
             # -- the result
-            result = assemble(cell, stamps, backlog, block_txs, traced,
-                              trace_summary, traced_nums, spans, device,
-                              compared, t_start, say)
+            result = assemble(cell, stamps, traced, trace_summary, session,
+                              spans, device, compared, t_start, say)
         finally:
             if dev_mgr is not None:
                 dev_mgr.close()
@@ -420,16 +420,17 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
 def profile_window(stamps: Stamps, cell: Cell, seconds: float, root: str,
                    say):
     """Open the profiler at a commit event inside the window, close it
-    `trace_blocks` commit events later, reduce the trace.  In steady
-    state a span of whole blocks holds whole blocks' device work."""
+    `trace_blocks` commit events later, reduce the trace: (the trace's
+    summary, None where the session wrote no trace or recorded no
+    device; the session, from which the reducers read whole calls)."""
     import jax
-    from benchmarks import reduce as reduce_mod
+    from benchmarks import reduce as reduce_mod, timeline
     n_blocks = int(cell.file["trace_blocks"])
     skip = int(cell.file.get("trace_after_blocks", 1))
     first = stamps.warm_last + skip
     if not stamps.wait(lambda: stamps.events[-1][0] >= first,
                        timeout_s=seconds):
-        return None, []
+        return None, None
     out_dir = os.path.join(root, "profile")
     # device planes only: the host's Python tracer slows the very
     # threads that are measured, and the programs' HLO (417 MB of code
@@ -439,6 +440,7 @@ def profile_window(stamps: Stamps, cell: Cell, seconds: float, root: str,
     options.host_tracer_level = 0
     options.enable_hlo_proto = False
     jax.profiler.start_trace(out_dir, profiler_options=options)
+    wall_a = time.time()
     begin_num = stamps.events[-1][0]
     t_a = time.perf_counter()
     try:
@@ -446,23 +448,33 @@ def profile_window(stamps: Stamps, cell: Cell, seconds: float, root: str,
                     or stamps.t1 is not None, timeout_s=seconds)
         end_num = stamps.events[-1][0]
         t_b = time.perf_counter()
+        wall_b = time.time()
     finally:
         jax.profiler.stop_trace()
     t_c = time.perf_counter()
+    try:
+        path = reduce_mod.find_xplane(out_dir)
+    except FileNotFoundError as e:
+        say(f"profiler window: {e}")
+        return None, None
     layout: List[str] = []
-    summary = reduce_mod.summarize(
-        reduce_mod.find_xplane(out_dir), window_s=t_b - t_a, layout=layout)
+    summary = reduce_mod.summarize(path, window_s=t_b - t_a, layout=layout)
+    extent: List[tuple] = []
+    programs, wall, _in_flight = timeline.read_session(path, extent)
     for line in layout:
         say(line)
     say(f"profiler window: blocks {begin_num + 1}..{end_num}, "
         f"{t_b - t_a:.3f}s, stop+write {t_c - t_b:.2f}s, reduce "
         f"{time.perf_counter() - t_c:.2f}s, "
-        f"{summary.n_events if summary else 0} device events")
-    return summary, list(range(begin_num + 1, end_num + 1))
+        f"{summary.n_events if summary else 0} device events"
+        f"{'' if summary else ', no device plane'}")
+    session = None if wall is None else timeline.Session(
+        programs, wall[0], (wall_a, wall_b), extent[0][1] if extent else None)
+    return summary, session
 
 
-def assemble(cell, stamps, backlog, block_txs, traced, trace_summary,
-             traced_nums, spans, device, compared, t_start, say) -> dict:
+def assemble(cell, stamps, traced, trace_summary, session, spans, device,
+             compared, t_start, say) -> dict:
     metrics: Dict[str, dict] = {}
     attempted = failed = 0
     breakdown = None
@@ -498,14 +510,12 @@ def assemble(cell, stamps, backlog, block_txs, traced, trace_summary,
                 dispatches=[(s["attrs"]["items"], s["attrs"]["bucket"])
                             for s in spans if s["name"] == "der_marshal"
                             and w0 < s["ts"] <= w1],
-                trace=trace_summary,
-                traced_items=sum(
-                    1 + real_items_of(backlog.txs[
-                        (n - 1) * block_txs: n * block_txs])
-                    for n in traced_nums),
+                trace=trace_summary, session=session, ring=spans,
                 device_kind=device["kind"])
+            specs = []
             for p in cell.per_layer:
                 spec, reduce_fn = reducer_for(p["name"])
+                specs.append(spec)
                 value = reduce_fn(spec, window)
                 if value is not None:
                     metrics[p["name"]] = {"value": value,
@@ -515,7 +525,10 @@ def assemble(cell, stamps, backlog, block_txs, traced, trace_summary,
                 device["window_s"] = trace_summary.window_s
                 breakdown = {
                     "device_ops": trace_summary.top_ops(10),
-                    "idle_gaps": trace_summary.top_gaps(10)}
+                    "idle_gaps": next(
+                        (idle_under.idle_gaps(spec, window)
+                         for spec in specs
+                         if spec["reducer"] == "idle_under"), [])}
             compared["trace_missing"] = int(trace_summary is None)
     limits = {name: 0 for name in compared}
     correct = all(compared[n] <= limits[n] for n in compared)

@@ -5,9 +5,9 @@ Two steps, so that the arithmetic can be checked without a trace:
 * `read_device_events(path)` reads the planes of the devices with
   `jax.profiler.ProfileData` and returns plain tuples;
 * `summarize_events(...)` is pure arithmetic on those tuples: the
-  union of the intervals in which an operation ran (busy), the time by
-  operation name, the time and count by program (XLA module) name, and
-  the longest gaps.
+  union of the intervals in which an operation ran (busy) and the time
+  by operation name.  What one program's call took is read from whole
+  calls only (`timeline.whole_calls`).
 
 Layout of a TPU trace as `jax.profiler` writes it (looked at by hand,
 PERF.md section 6): one plane per chip named `/device:TPU:<n>`; its
@@ -38,15 +38,19 @@ def find_xplane(profile_dir: str) -> str:
     return found[-1]
 
 
-def read_device_events(path: str, layout: Optional[list] = None
-                       ) -> List[Event]:
+def read_device_events(path: str, layout: Optional[list] = None,
+                       planes: Optional[list] = None) -> List[Event]:
     """`layout`, if given, receives one line of text per plane and
     line of the trace (name, events, most frequent names): what to
-    read when the trace has to be looked at by hand."""
+    read when the trace has to be looked at by hand.  `planes`, if
+    given, receives the name of every device plane, also of one on
+    which nothing ran."""
     from jax.profiler import ProfileData
     out: List[Event] = []
     for plane in ProfileData.from_file(path).planes:
         on_device = bool(DEVICE_PLANE.match(plane.name))
+        if on_device and planes is not None:
+            planes.append(plane.name)
         for line in plane.lines:
             keep = on_device and line.name in (OPS_LINE, MODULES_LINE)
             names: Dict[str, int] = {}
@@ -84,26 +88,10 @@ def union_seconds(intervals: List[Tuple[float, float]]) -> float:
     return total / 1e9
 
 
-def gaps(intervals: List[Tuple[float, float]]) -> List[float]:
-    """Idle gaps between merged intervals, seconds, longest first."""
-    out = []
-    end = None
-    for a, b in sorted(intervals):
-        if end is not None and a > end:
-            out.append((a - end) / 1e9)
-        end = b if end is None else max(end, b)
-    return sorted(out, reverse=True)
-
-
 def op_of(op_event_name: str) -> str:
     """An op event is named by its whole HLO line; `%fusion.2 = ...`
     -> `fusion.2`."""
     return op_event_name.split(" = ", 1)[0].lstrip("%")
-
-
-def program_of(module_event_name: str) -> str:
-    """`jit_verify_core(1234)` -> `jit_verify_core`."""
-    return module_event_name.split("(", 1)[0]
 
 
 @dataclasses.dataclass
@@ -113,39 +101,20 @@ class TraceSummary:
     n_events: int
     n_planes: int
     op_secs: Dict[str, float]           # operation name -> seconds
-    # module event name (with fingerprint) -> [seconds, calls]
-    module_secs: Dict[str, List[float]]
-    gap_secs: List[float]               # longest first, fullest chip
 
     def top_ops(self, n: int) -> List[list]:
         ranked = sorted(self.op_secs.items(), key=lambda kv: -kv[1])
         return [[name, secs] for name, secs in ranked[:n]]
 
-    def top_gaps(self, n: int) -> List[list]:
-        # what the host was doing in a gap needs the program's spans on
-        # the profiler's clock, which the program does not write yet
-        return [["host_unattributed", g] for g in self.gap_secs[:n]]
-
-    def modules_matching(self, patterns: List[str]) -> Dict[str, List[float]]:
-        return {name: v for name, v in self.module_secs.items()
-                if any(re.search(p, program_of(name)) for p in patterns)}
-
 
 def summarize_events(events: List[Event], window_s: float) -> TraceSummary:
     by_plane: Dict[str, List[Tuple[float, float]]] = {}
     op_secs: Dict[str, float] = {}
-    module_secs: Dict[str, List[float]] = {}
-    n_ops = 0
     for plane, line, name, start, dur in events:
         if line == OPS_LINE:
             by_plane.setdefault(plane, []).append((start, start + dur))
             op = op_of(name)
             op_secs[op] = op_secs.get(op, 0.0) + dur / 1e9
-            n_ops += 1
-        elif line == MODULES_LINE:
-            tot = module_secs.setdefault(name, [0.0, 0])
-            tot[0] += dur / 1e9
-            tot[1] += 1
     if not by_plane:
         # a trace with programs but no per-op line: the programs'
         # own intervals are the busy time
@@ -153,18 +122,20 @@ def summarize_events(events: List[Event], window_s: float) -> TraceSummary:
             if line == MODULES_LINE:
                 by_plane.setdefault(plane, []).append((start, start + dur))
     busy = [union_seconds(iv) for iv in by_plane.values()]
-    fullest = max(by_plane.values(), key=union_seconds, default=[])
     return TraceSummary(
         window_s=window_s,
         busy_s=sum(busy) / len(busy) if busy else 0.0,
         n_events=len(events), n_planes=len(by_plane),
-        op_secs=op_secs, module_secs=module_secs,
-        gap_secs=gaps(fullest)[:32])
+        op_secs=op_secs)
 
 
 def summarize(path: str, window_s: float,
               layout: Optional[list] = None) -> Optional[TraceSummary]:
-    events = read_device_events(path, layout)
-    if not events:
+    """None where the trace has no device plane: nothing of the chip
+    was recorded.  A device plane on which no operation ran in the
+    window is a measurement: busy 0 of `window_s`."""
+    planes: List[str] = []
+    events = read_device_events(path, layout, planes)
+    if not planes:
         return None
     return summarize_events(events, window_s)

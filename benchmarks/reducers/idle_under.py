@@ -8,8 +8,9 @@ metrics of this reducer do not add up to the idle share.
 
 The stretch's idle intervals go to standard error once per run,
 longest first, each with the name `timeline.name_idle` gives it, the
-spans most at work in it and the time the wait spans cover: the result
-line's `breakdown.idle_gaps` is `reduce.py`'s.
+spans most at work in it and the time the wait spans cover; the ten
+longest, with their names, are the result line's `breakdown.idle_gaps`
+(`idle_gaps`).
 
 The session's programs are read from the trace `cellrun.py` wrote in
 this run, the spans from the program's own recorder; the device's
@@ -31,8 +32,6 @@ from typing import Dict, List, Optional, Set
 
 from benchmarks import timeline
 
-ENQUEUE = "device_enqueue"
-
 
 @dataclasses.dataclass
 class View:
@@ -40,6 +39,7 @@ class View:
     idle: List[timeline.Interval]
     self_iv: Dict[str, List[timeline.Interval]]
     recorded: Set[str]                 # span names of the whole run
+    gaps: List[list]                   # [[name, seconds]], longest first
 
 
 def reduce(spec, window):
@@ -53,13 +53,24 @@ def reduce(spec, window):
     return 100.0 * under / (view.stretch[1] - view.stretch[0])
 
 
+def idle_gaps(spec, window, n: int = 10) -> List[list]:
+    """[[name, seconds]] of the stretch's `n` longest idle intervals,
+    each named by `timeline.name_idle`; empty where the run gives no
+    view (no trace, no program in it, no `device_enqueue` span)."""
+    if window.trace is None:
+        return []
+    view = view_of_run(window.trace.window_s, tuple(spec["programs"]),
+                       tuple(spec["wait_spans"]))
+    return [] if view is None else view.gaps[:n]
+
+
 @functools.lru_cache(maxsize=1)
 def view_of_run(window_s: float, programs_like: tuple, wait_spans: tuple
                 ) -> Optional[View]:
     """Read once per run: the metrics of this reducer share it."""
     from fabric_mod_tpu.observability import tracing
     ring = tracing.recorder().recent_spans(limit=1 << 30)
-    if not any(sp["name"] == ENQUEUE for sp in ring):
+    if not any(sp["name"] == timeline.ENQUEUE for sp in ring):
         return None
     path = timeline.find_session_xplane(min(sp["ts"] for sp in ring))
     if path is None:
@@ -71,7 +82,7 @@ def view_of_run(window_s: float, programs_like: tuple, wait_spans: tuple
     sent = [p[1:] for p in programs
             if any(re.search(pat, p[0]) for pat in programs_like)]
     pairs = timeline.pair_enqueues(
-        sent, [s[2:] for s in spans if s[1] == ENQUEUE])
+        sent, [s[2:] for s in spans if s[1] == timeline.ENQUEUE])
     shift = timeline.device_shift_ns(pairs)
     lags = sorted(p[0] + shift - e[0] for p, e in pairs)
     busy = [(a + shift, b + shift) for a, b in
@@ -82,10 +93,13 @@ def view_of_run(window_s: float, programs_like: tuple, wait_spans: tuple
         not_before=in_flight[0] + shift if in_flight else 0.0)
     self_iv = timeline.self_intervals(timeline.cut(spans, stretch))
     idle = timeline.idle_intervals(busy, stretch)
+    longest = sorted(idle, key=lambda iv: iv[0] - iv[1])
+    names = [timeline.name_idle(iv, self_iv, wait_spans)
+             for iv in longest[:10]]
     told = []
-    for iv in sorted(idle, key=lambda iv: iv[0] - iv[1])[:8]:
+    for iv, name in zip(longest[:8], names):
         told.append({
-            "name": timeline.name_idle(iv, self_iv, wait_spans),
+            "name": name,
             "s": round((iv[1] - iv[0]) / 1e9, 6),
             "start_s": round(iv[0] / 1e9, 6),
             "at_work_s": [[n, round(ns / 1e9, 6)] for n, ns in
@@ -103,4 +117,6 @@ def view_of_run(window_s: float, programs_like: tuple, wait_spans: tuple
           f"device times shifted by {shift / 1e3:.1f} us, idle "
           f"{sum(b - a for a, b in idle) / 1e9:.6f}s in {len(idle)} "
           f"intervals; the longest: {told}", file=sys.stderr)
-    return View(stretch, idle, self_iv, {sp["name"] for sp in ring})
+    return View(stretch, idle, self_iv, {sp["name"] for sp in ring},
+                [[name, (iv[1] - iv[0]) / 1e9]
+                 for iv, name in zip(longest, names)])

@@ -1,20 +1,25 @@
 """The least time the chip could take for the real signatures of the
-traced blocks (`work.least_seconds`), over the device time of the
-matching programs in the traced window, in percent.  The profiler
-window spans whole blocks, commit event to commit event, so in steady
-state the device work inside it is that of as many blocks.
+traced window's whole calls (`work.least_seconds`), over those calls'
+device time, in percent.  Work and time come from the same calls
+(`timeline.whole_calls`): each program that ran whole inside the
+window, with the items of the `der_marshal` span of the dispatch that
+sent it.  A program cut at either end of the window, and a call whose
+items are not found, count for neither.  None where no such call lies
+in the window.
 
 spec: {"programs": [regular expressions on the jit name]}
 """
-from benchmarks import work
+from benchmarks import timeline, work
 
 
 def reduce(spec, window):
-    if window.trace is None or window.traced_items <= 0:
+    if window.session is None:
         return None
-    found = window.trace.modules_matching(spec["programs"])
-    device_s = sum(v[0] for v in found.values())
+    calls = [(secs, items) for _name, secs, items in timeline.whole_calls(
+        window.session, window.ring, spec["programs"]) if items]
+    device_s = sum(secs for secs, _items in calls)
     if device_s <= 0:
         return None
-    least = work.least_seconds(window.traced_items, window.device_kind)
+    least = work.least_seconds(sum(items for _secs, items in calls),
+                               window.device_kind)
     return 100.0 * least["seconds"] / device_s

@@ -196,18 +196,24 @@ def test_state_that_differs_from_the_rules_is_not_correct(misjudge):
     assert over_limit(result) == {"state_diff"}
 
 
-def test_network_key_the_program_does_not_take_stops_the_run():
+@pytest.mark.parametrize("deployment", [
+    FIVE_ORGS, ("rehearsalnof4.backlog-nof", "rehearsal-nof4", "backlog-nof")],
+    ids=["5org", "nof4"])
+def test_network_key_the_program_does_not_take_stops_the_run(deployment):
+    """A key in the configuration's `network` that the program's
+    `e2e.Network` does not take: stopped before anything is set up,
+    key and configuration named."""
     from benchmarks.cellrun import RunFailure, run_cell
-    cell = the_cell(FIVE_ORGS)
+    cell = the_cell(deployment)
     cell.config = copy.deepcopy(cell.config)
-    cell.config["network"]["endorsement_policy"] = "OutOf(3, ...)"
+    cell.config["network"]["no_such_setting"] = 1
     set_up = []
     with pytest.raises(RunFailure) as failure:
         run_cell(cell, 9, SECONDS, False, OFF_CHIP, lambda msg: None,
                  time.perf_counter(),
                  make_verifier=lambda: set_up.append("the verifier"))
-    assert "'endorsement_policy'" in str(failure.value)
-    assert "'rehearsal-5org'" in str(failure.value)
+    assert "'no_such_setting'" in str(failure.value)
+    assert f"'{deployment[1]}'" in str(failure.value)
     assert not set_up
 
 

@@ -58,10 +58,6 @@ def test_manifest_finds_the_cell_and_what_it_names():
     for other in ("default500.backlog", "smallbank.backlog-zipf"):
         assert NEW_METRIC not in {
             p["name"] for p in Cell(other, bench).per_layer}
-    # the entries were appended: nothing that was there moved
-    assert bench["configs"][-1] is entry
-    assert bench["workloads"][-1] is cell.entry
-    assert bench["per_layer"][-1] is new
 
 
 def test_the_deployment_keeps_its_sources_shapes():

@@ -10,7 +10,7 @@ import re
 
 import pytest
 
-from benchmarks import manifest, reduce, work
+from benchmarks import cellrun, manifest, reduce, timeline, work
 from benchmarks.manifest import HERE, CHECKOUT
 
 MS = 1e6    # nanoseconds
@@ -20,7 +20,7 @@ def ev(line, name, start_ms, dur_ms, plane="/device:TPU:0"):
     return (plane, line, name, start_ms * MS, dur_ms * MS)
 
 
-def test_busy_is_the_union_of_op_intervals_and_gaps_are_what_is_left():
+def test_busy_is_the_union_of_op_intervals():
     events = [
         ev(reduce.OPS_LINE, "fusion.1", 0, 10),
         ev(reduce.OPS_LINE, "fusion.2", 5, 10),      # overlaps: union 15
@@ -34,11 +34,11 @@ def test_busy_is_the_union_of_op_intervals_and_gaps_are_what_is_left():
     s = reduce.summarize_events(events, window_s=0.1)
     assert s.busy_s == pytest.approx(0.030)
     assert 1 - s.busy_s / s.window_s == pytest.approx(0.70)
-    assert s.gap_secs[:2] == [pytest.approx(0.040), pytest.approx(0.025)]
     assert s.top_ops(1) == [["fusion.1", pytest.approx(0.020)]]
-    found = s.modules_matching(["verify"])
-    assert found == {"jit_verify_core(7)": [pytest.approx(0.025), 2],
-                     "jit_verify_core(9)": [pytest.approx(0.005), 1]}
+    # a trace with programs and no per-op line: the programs are busy
+    modules = [e for e in events if e[1] == reduce.MODULES_LINE]
+    assert reduce.summarize_events(modules, 0.1).busy_s == \
+        pytest.approx(0.031)
 
 
 def test_busy_is_averaged_over_the_chips_used():
@@ -54,6 +54,89 @@ def test_no_device_events_reduce_to_nothing(tmp_path):
         reduce.find_xplane(str(tmp_path))
 
 
+def made_up_trace(monkeypatch, device_lines):
+    """`jax.profiler.ProfileData.from_file` of any path gives a session
+    plane and, unless `device_lines` is None, a TPU plane with these
+    lines ({name: [(event name, start_ns, duration_ns)]})."""
+    import types
+
+    import jax.profiler
+
+    def plane(name, lines, stats=()):
+        return types.SimpleNamespace(
+            name=name, stats=list(stats),
+            lines=[types.SimpleNamespace(name=n, events=[
+                types.SimpleNamespace(name=e, start_ns=a, duration_ns=d)
+                for e, a, d in evs]) for n, evs in lines.items()])
+    planes = [plane("Task Environment", {}, [
+        ("profile_start_time", 7e18), ("profile_stop_time", 7e18 + 9e8)])]
+    if device_lines is not None:
+        planes.append(plane("/device:TPU:0", device_lines))
+    monkeypatch.setattr(
+        jax.profiler.ProfileData, "from_file",
+        staticmethod(lambda path: types.SimpleNamespace(planes=planes)))
+
+
+def made_up_stamps(blocks=6):
+    """A window of `blocks` commit events after the last warm-up block
+    (block 2), closed at the last of them."""
+    import types
+    stamps = cellrun.Stamps(2, 60.0, lambda: ({}, 0, 0.0))
+    for n in range(1, 3 + blocks):
+        stamps(types.SimpleNamespace(
+            header=types.SimpleNamespace(number=n),
+            data=types.SimpleNamespace(data=[b""] * 8)))
+    stamps.close_dry()
+    return stamps
+
+
+def assembled(summary, session):
+    from benchmarks.reducers import idle_under
+    from benchmarks.test_correct import OFF_CHIP, the_cell
+    from fabric_mod_tpu.observability import tracing
+    tracing.recorder().reset()          # no other test's spans
+    idle_under.view_of_run.cache_clear()
+    return cellrun.assemble(the_cell(), made_up_stamps(), True, summary,
+                            session, [], dict(OFF_CHIP), {}, 0.0,
+                            lambda msg: None)
+
+
+def test_a_stretch_with_no_program_is_idle_and_correct(monkeypatch):
+    """A device plane on which nothing ran in the window: busy 0 of the
+    window, the run correct, and no metric of the device's programs."""
+    made_up_trace(monkeypatch, {"XLA Modules": [], "XLA Ops": []})
+    summary = reduce.summarize("made-up", window_s=0.412)
+    programs, wall, _ = timeline.read_session("made-up")
+    assert summary is not None and programs == []
+    result = assembled(summary, timeline.Session(
+        programs, wall[0], (7e9 + 0.1, 7e9 + 0.512)))
+    assert result["correct"], result["compared"]
+    assert result["compared"]["trace_missing"]["value"] == 0
+    assert result["device"]["busy_s"] == 0.0
+    assert result["device"]["window_s"] == 0.412
+    assert not {"verify_kernel_ms_per_call", "verify_roofline_pct"} \
+        & set(result["metrics"])
+
+
+def test_a_session_with_no_trace_file_or_no_device_is_missing(
+        monkeypatch, tmp_path):
+    import jax.profiler
+    made_up_trace(monkeypatch, None)            # no device plane
+    assert reduce.summarize("made-up", window_s=0.4) is None
+    # a session that wrote nothing: no trace file at all
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    from benchmarks.test_correct import the_cell
+    said = []
+    assert cellrun.profile_window(made_up_stamps(), the_cell(), 0.1,
+                                  str(tmp_path), said.append) == (None, None)
+    assert any("no .xplane.pb" in line for line in said)
+    result = assembled(None, None)
+    assert not result["correct"]
+    assert result["compared"]["trace_missing"]["value"] == 1
+    assert "busy_s" not in result["device"]
+
+
 def test_recorded_trace_reads_as_recorded():
     """`testdata/probe.xplane.pb` was recorded on one TPU v5 lite chip
     (`benchmarks/testdata/record_probe.py`): CALLS executions of one
@@ -65,11 +148,15 @@ def test_recorded_trace_reads_as_recorded():
     layout = []
     s = reduce.summarize(path, window_s=facts["window_s"], layout=layout)
     assert s is not None and s.n_planes == 1
-    found = s.modules_matching(["bench_probe"])
-    assert sum(v[1] for v in found.values()) == facts["calls"]
-    program_s = sum(v[0] for v in found.values())
-    # the program's intervals and the union of its operations agree
+    extent = []
+    programs, _wall, _ = timeline.read_session(path, extent)
+    assert [p[0].startswith("jit_bench_probe(") for p in programs] == \
+        [True] * facts["calls"]
+    program_s = sum(b - a for _n, a, b in programs) / 1e9
+    # the program's intervals and the union of its operations agree,
+    # and the recording spans all of them
     assert program_s == pytest.approx(s.busy_s, rel=0.01)
+    assert extent[0][0] <= programs[0][1] and extent[0][1] >= programs[-1][2]
     assert 0 < s.busy_s < facts["window_s"]
     assert any("XLA Modules" in line for line in layout)
 

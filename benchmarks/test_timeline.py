@@ -13,9 +13,9 @@ import time
 
 import pytest
 
-from benchmarks import manifest, timeline
+from benchmarks import manifest, timeline, work
 from benchmarks.manifest import HERE
-from benchmarks.reducers import idle_under
+from benchmarks.reducers import idle_under, roofline, trace_program
 from fabric_mod_tpu.observability import spannames, tracing
 
 MS = 1e6    # nanoseconds
@@ -55,8 +55,7 @@ def test_idle_before_the_first_and_after_the_last_program_is_counted():
     assert idle == [(0.0, 100 * MS), (130 * MS, 400 * MS),
                     (410 * MS, 1000 * MS)]
     assert sum(b - a for a, b in idle) == pytest.approx(960 * MS)
-    # `reduce.gaps` saw only the 270 ms in the middle; with no span
-    # at all, nobody owns any of it
+    # with no span at all, nobody owns any of it
     assert {timeline.name_idle(iv, {}, WAITS) for iv in idle} == {
         timeline.HOST_UNATTRIBUTED}
 
@@ -149,10 +148,14 @@ def test_a_program_running_when_the_tracer_started_is_read_from_its_ops(
     monkeypatch.setattr(
         jax.profiler.ProfileData, "from_file",
         staticmethod(lambda path: types.SimpleNamespace(planes=planes)))
-    programs, wall, in_flight = timeline.read_session("made-up")
-    assert programs == [("jit_verify", 300.0, 400.0)]
+    extent = []
+    programs, wall, in_flight = timeline.read_session("made-up", extent)
+    assert programs == [("jit_verify(1)", 300.0, 400.0)]
     assert wall == (7e18, 7e18 + 9e8)
     assert in_flight == (40.0, 255.0)
+    # what the device's tracer recorded: from the rest's first operation
+    # to the program's end
+    assert extent == [(40.0, 400.0)]
     planes[1].lines[1].events[:2] = []
     assert timeline.read_session("made-up")[2] is None
 
@@ -193,6 +196,159 @@ def test_device_shift_pairs_programs_with_enqueues_in_order():
         [((30 * MS, 170 * MS), (2 * MS, 42 * MS))]) == 0.0
     assert timeline.pair_enqueues(programs, enqueues[:1]) == []
     assert timeline.device_shift_ns([]) == 0.0
+
+
+# -- whole calls --------------------------------------------------------------
+
+START_NS = 1.7e18                   # the session's start on the wall clock
+TABLES = "jit__verify_core_tables_impl(4711)"
+LADDER = "jit__verify_core_impl(815)"
+
+
+def wall(ms):
+    """The `time.time()` of `ms` milliseconds into the session."""
+    return (START_NS + ms * MS) / 1e9
+
+
+def dispatch(ring, items, at_ms, parent, thread="stage"):
+    """One device call as the provider records it: the marshal of its
+    items, then the enqueue, which begins 1 ms before the program does
+    on the device."""
+    ring.append({"name": "der_marshal", "thread": thread,
+                 "parent_id": parent, "ts": wall(at_ms - 3), "dur": 0.0015,
+                 "attrs": {"items": items, "bucket": 2048}})
+    ring.append({"name": "device_enqueue", "thread": thread,
+                 "parent_id": parent, "ts": wall(at_ms - 1), "dur": 0.0005,
+                 "attrs": {"bucket": 2048}})
+
+
+@dataclasses.dataclass
+class CallWindow:
+    session: object
+    ring: list
+    device_kind: str = "TPU v5 lite"
+
+
+def window_of(programs, ring, opened=100, closed=500, recorded_end=None):
+    """The profiler held open from `opened` to `closed` ms into the
+    session, the device's tracer recording until `recorded_end` (ms)."""
+    return CallWindow(timeline.Session(
+        programs, START_NS, (wall(opened), wall(closed)),
+        recorded_end and recorded_end * MS), ring)
+
+
+SPEC = {"programs": ["verify"]}
+
+
+@pytest.mark.parametrize("opened,closed,recorded_end", [
+    # as on the chip: the last program is cut where the recording
+    # ends, which on the host's clock lies just inside the window
+    (100, 484.5, 483.5),
+    # where the recording's end is not known, the window alone
+    (100, 483, None),
+], ids=["as_on_the_chip", "window_alone"])
+def test_whole_calls_leave_out_the_programs_cut_at_either_end(
+        opened, closed, recorded_end):
+    """Four calls of the table program a block period apart: one began
+    before the window opened (it may have been running when the tracer
+    started), two ran whole, one was cut when the tracer stopped.  Time
+    and work are those of the two whole calls."""
+    ring, programs = [], []
+    for k, (start, end, items) in enumerate([
+            (90, 139, 1498), (200, 249, 1200), (330, 379, 1300),
+            (480, 483.5, 1100)]):
+        dispatch(ring, items, start, parent=f"block{k}")
+        programs.append((TABLES, start * MS, end * MS))
+    programs.append(("jit_other(5)", 10 * MS, 11 * MS))   # no enqueue
+    window = window_of(programs, ring, opened, closed, recorded_end)
+    calls = timeline.whole_calls(window.session, ring, SPEC["programs"])
+    assert [(n, i) for n, _s, i in calls] == [(TABLES, 1200), (TABLES, 1300)]
+    assert [s for _n, s, _i in calls] == [pytest.approx(0.049)] * 2
+    assert trace_program.reduce(SPEC, window) == pytest.approx(49.0)
+    least = work.least_seconds(1200 + 1300, "TPU v5 lite")["seconds"]
+    assert roofline.reduce(SPEC, window) == pytest.approx(
+        100.0 * least / 0.098)
+    assert 0.012 < roofline.reduce(SPEC, window) < 0.035
+
+
+def test_the_first_thing_recorded_is_whole_where_it_began_in_the_window():
+    """`testnet10.backlog` on the chip: the chip idles when the tracer
+    starts, one whole 5.8 ms program, then the tail of the next one,
+    cut where the recording ends."""
+    ring = []
+    dispatch(ring, 31, 120, parent="block40")
+    dispatch(ring, 31, 131, parent="block41")
+    programs = [(TABLES, 120 * MS, 125.8 * MS), (TABLES, 131 * MS, 131.8 * MS)]
+    window = window_of(programs, ring, opened=100, closed=132,
+                       recorded_end=131.8)
+    assert [(i, round(s, 6)) for _n, s, i in timeline.whole_calls(
+        window.session, ring, SPEC["programs"])] == [(31, 0.0058)]
+    assert trace_program.reduce(SPEC, window) == pytest.approx(5.8)
+
+
+@pytest.mark.parametrize("programs_ms,closed,recorded_end,whole", [
+    # `thakkar4.backlog-nof` on the chip: the chip idled for the last
+    # 77 ms of the window, after three whole programs
+    ([(103.09, 152.13), (245.43, 294.47), (383.68, 432.72)], 509.88,
+     432.72, 3),
+    # `smallbank.backlog-zipf`: a tail from before the window, then two
+    # whole programs and 125 ms of idle
+    ([(98.41, 98.65), (233.91, 282.94), (467.82, 516.86)], 641.98, 516.86,
+     2),
+    # the same last program with the recording ending 2 ms before the
+    # close: it may have been stopped while the program ran
+    ([(233.91, 282.94), (467.82, 516.86)], 518.86, 516.86, 1),
+], ids=["idle_77ms", "idle_125ms", "stopped_at_close"])
+def test_the_last_thing_recorded_is_whole_where_the_chip_idled_to_the_close(
+        programs_ms, closed, recorded_end, whole):
+    ring, programs = [], []
+    for k, (start, end) in enumerate(programs_ms):
+        dispatch(ring, 1498, start, parent=f"block{k}")
+        programs.append((TABLES, start * MS, end * MS))
+    window = window_of(programs, ring, opened=100, closed=closed,
+                       recorded_end=recorded_end)
+    calls = timeline.whole_calls(window.session, ring, SPEC["programs"])
+    assert len(calls) == whole
+    assert [s for _n, s, _i in calls] == [pytest.approx(0.04904, abs=1e-4)] * whole
+
+
+def test_each_call_takes_the_items_of_its_own_marshal():
+    """A block split between the table program and the ladder: two
+    marshals and two enqueues under one parent, and another block's
+    call on another thread between them."""
+    ring = []
+    dispatch(ring, 1000, 200, parent="block7")
+    dispatch(ring, 7, 230, parent="block8", thread="commit")
+    dispatch(ring, 498, 260, parent="block7")
+    programs = [(TABLES, 200 * MS, 249 * MS), (LADDER, 249 * MS, 255 * MS),
+                (LADDER, 260 * MS, 402 * MS)]
+    calls = timeline.whole_calls(window_of(programs, ring).session, ring,
+                                 SPEC["programs"])
+    assert [(n, i) for n, _s, i in calls] == [
+        (TABLES, 1000), (LADDER, 7), (LADDER, 498)]
+    # the program that took most device time is the ladder's
+    assert trace_program.reduce(SPEC, window_of(programs, ring)) == \
+        pytest.approx(74.0)
+
+
+def test_a_tail_alone_gives_no_reading_where_it_gave_170_percent():
+    """5 us of a program cut when the window closed: read as a call,
+    1,498 signatures' least time over it is ~177%."""
+    ring = []
+    dispatch(ring, 1498, 499.993, parent="block9")
+    programs = [(TABLES, 499.993 * MS, 499.998 * MS)]
+    least = work.least_seconds(1498, "TPU v5 lite")["seconds"]
+    assert 100.0 * least / 5e-6 > 170.0
+    # it ends where the recording does, inside the window by the host's
+    # clock; or after the window closed, where no extent is known
+    for window in (window_of(programs, ring, recorded_end=499.998),
+                   window_of(programs, ring, closed=499.996)):
+        assert trace_program.reduce(SPEC, window) is None
+        assert roofline.reduce(SPEC, window) is None
+    # nor is anything read where the programs cannot be paired with
+    # their enqueues, or where no session was recorded
+    assert timeline.whole_calls(window.session, [], SPEC["programs"]) == []
+    assert roofline.reduce(SPEC, CallWindow(None, ring)) is None
 
 
 def test_the_reducer_finds_nothing_rather_than_zero(monkeypatch):
@@ -252,7 +408,7 @@ def test_recorded_trace_and_a_made_up_ring_give_named_idle(monkeypatch,
     programs, wall, in_flight = timeline.read_session(path)
     assert in_flight is None            # the probe's chip was at rest
     assert len(programs) == facts["calls"]
-    assert {p[0] for p in programs} == {"jit_bench_probe"}
+    assert {p[0].split("(")[0] for p in programs} == {"jit_bench_probe"}
     length_s = (wall[1] - wall[0]) / 1e9
     assert facts["window_s"] < length_s < 1.0
 
@@ -299,6 +455,11 @@ def test_recorded_trace_and_a_made_up_ring_give_named_idle(monkeypatch,
     assert shift_us == pytest.approx(100.0, abs=1.0)
     assert "'name': 'unpack'" in said and "'at_work_s': [['unpack', 0.0" \
         in said
+    # the breakdown's idle gaps: the same intervals, named, longest first
+    gaps = idle_under.idle_gaps(spec, window)
+    assert 0 < len(gaps) <= 10 and gaps[0][0] == "unpack"
+    assert [g[1] for g in gaps] == sorted((g[1] for g in gaps), reverse=True)
+    assert "idle_under:" not in capsys.readouterr().err  # not read again
     # a program that records no enqueue span: nothing, not zero
     idle_under.view_of_run.cache_clear()
     monkeypatch.setattr(tracing.recorder(), "recent_spans",

@@ -47,24 +47,34 @@ the spans nested in it on its thread) of spans that are not in
 `wait_spans`, on any thread.  Where no such span covers at least half
 of the interval: `host_waiting` if the wait spans do, else
 `host_unattributed`.
+
+What a device call costs, and how much work it did, is read from whole
+calls only (`whole_calls`): a program that ran from start to end
+inside the window, with the items of the dispatch that sent it.  A
+program cut at either end of the window is busy time and no call.
 """
+import dataclasses
 import glob
+import math
 import os
+import re
 import sys
 import tempfile
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from benchmarks.reduce import (DEVICE_PLANE, MODULES_LINE, OPS_LINE,
-                               program_of, union_seconds)
+                               union_seconds)
 
 HOST_WAITING = "host_waiting"
 HOST_UNATTRIBUTED = "host_unattributed"
 SESSION_PLANE = "Task Environment"
+ENQUEUE = "device_enqueue"        # the span that sends one program
+MARSHAL = "der_marshal"           # the span that packs that program's items
 
 Interval = Tuple[float, float]                  # (start_ns, end_ns)
 # (thread, name, start_ns, end_ns) on the trace's clock
 HostSpan = Tuple[str, str, float, float]
-Program = Tuple[str, float, float]              # (jit name, start, end)
+Program = Tuple[str, float, float]              # (event name, start, end)
 
 
 # -- reading -----------------------------------------------------------------
@@ -88,14 +98,18 @@ def find_session_xplane(since_s: float) -> Optional[str]:
     return found[0] if len(found) == 1 else None
 
 
-def read_session(path: str) -> Tuple[List[Program], Optional[Interval],
-                                     Optional[Interval]]:
-    """(program executions on the fullest chip; the session's start
-    and stop in wall-clock ns; the rest of a program that was running
-    when the device's tracer started).  Such a program has no event on
-    the programs' line, only its remaining operations on line
-    `XLA Ops`: the stretch from the first to the last operation that
-    ended before the first recorded program began."""
+def read_session(path: str, extent: Optional[list] = None
+                 ) -> Tuple[List[Program], Optional[Interval],
+                            Optional[Interval]]:
+    """(program executions on the fullest chip, each named by its
+    event: `<jit name>(<fingerprint>)`; the session's start and stop in
+    wall-clock ns; the rest of a program that was running when the
+    device's tracer started).  Such a program has no event on the
+    programs' line, only its remaining operations on line `XLA Ops`:
+    the stretch from the first to the last operation that ended before
+    the first recorded program began.  `extent`, if given, receives the
+    (first start, last end) of everything recorded on that chip's two
+    lines: where the device's tracer began and stopped recording."""
     from jax.profiler import ProfileData
     by_plane: Dict[str, List[Program]] = {}
     ops_lines = {}
@@ -108,7 +122,7 @@ def read_session(path: str) -> Tuple[List[Program], Optional[Interval],
         for line in plane.lines:
             if line.name == MODULES_LINE:
                 by_plane[plane.name] = [
-                    (program_of(ev.name), float(ev.start_ns),
+                    (ev.name, float(ev.start_ns),
                      float(ev.start_ns) + float(ev.duration_ns))
                     for ev in line.events]
             elif line.name == OPS_LINE:
@@ -117,16 +131,22 @@ def read_session(path: str) -> Tuple[List[Program], Optional[Interval],
         [p[1:] for p in by_plane[name]]))
     programs = sorted(by_plane.get(fullest, []), key=lambda p: p[1])
     in_flight = None
-    if programs and fullest in ops_lines:
+    ops = ops_lines[fullest].events if fullest in ops_lines else []
+    if programs and ops:
         first = programs[0][1]
         lo = hi = None
-        for ev in ops_lines[fullest].events:
+        for ev in ops:
             end = ev.start_ns + ev.duration_ns
             if end <= first:
                 lo = ev.start_ns if lo is None else min(lo, ev.start_ns)
                 hi = end if hi is None else max(hi, end)
         if lo is not None:
             in_flight = (float(lo), float(hi))
+    if extent is not None and programs:
+        extent.append((
+            min([programs[0][1]] + [float(ev.start_ns) for ev in ops]),
+            max([p[2] for p in programs]
+                + [float(ev.start_ns + ev.duration_ns) for ev in ops])))
     try:
         wall = (float(session["profile_start_time"]),
                 float(session["profile_stop_time"]))
@@ -264,20 +284,31 @@ def idle_under_ns(idle: Iterable[Interval],
     return sum(overlap_ns(iv, at_work) for iv in idle)
 
 
+def pairing_offset(programs: Sequence[Interval],
+                   enqueues: Sequence[Interval],
+                   slack_ns: float = 5e6) -> Optional[int]:
+    """The device runs programs in the order they were sent, so the
+    k-th program of the trace (by start) belongs to the (i + k)-th
+    enqueue (by start) for one i: the largest for which no program
+    starts more than `slack_ns` before its enqueue began.  None where
+    there is no such i."""
+    programs, enqueues = sorted(programs), sorted(enqueues)
+    for first in range(len(enqueues) - len(programs), -1, -1):
+        if all(e[0] <= p[0] + slack_ns
+               for p, e in zip(programs, enqueues[first:])):
+            return first
+    return None
+
+
 def pair_enqueues(programs: Sequence[Interval],
                   enqueues: Sequence[Interval],
                   slack_ns: float = 5e6) -> List[Tuple[Interval, Interval]]:
-    """[(program, the enqueue that sent it)].  The device runs programs
-    in the order they were sent, so the k-th program of the trace
-    belongs to the (i + k)-th enqueue for one i: the largest for which
-    no program starts more than `slack_ns` before its enqueue began.
-    Empty where there is no such i."""
-    programs, enqueues = sorted(programs), sorted(enqueues)
-    for first in range(len(enqueues) - len(programs), -1, -1):
-        pairs = list(zip(programs, enqueues[first:]))
-        if all(e[0] <= p[0] + slack_ns for p, e in pairs):
-            return pairs
-    return []
+    """[(program, the enqueue that sent it)], by `pairing_offset`.
+    Empty where there is no such pairing."""
+    first = pairing_offset(programs, enqueues, slack_ns)
+    if first is None:
+        return []
+    return list(zip(sorted(programs), sorted(enqueues)[first:]))
 
 
 def device_shift_ns(pairs: Sequence[Tuple[Interval, Interval]]) -> float:
@@ -294,3 +325,96 @@ def device_shift_ns(pairs: Sequence[Tuple[Interval, Interval]]) -> float:
     device's clock is the late one; if it is early, nothing here
     bounds it."""
     return max([0.0] + [e[0] - p[0] for p, e in pairs])
+
+
+# -- whole calls -------------------------------------------------------------
+
+@dataclasses.dataclass
+class Session:
+    """What `cellrun.profile_window` recorded: the trace's programs
+    and the end of the last thing the device's tracer recorded on that
+    chip (`read_session`'s extent), the session's start on the wall
+    clock in ns, and the window it held the profiler open for on the
+    same clock (`time.time()` once `start_trace` had returned, and
+    before `stop_trace` was called)."""
+    programs: List[Program]
+    start_wall_ns: float
+    window_wall: Tuple[float, float]
+    recorded_end: Optional[float] = None
+
+
+# a program event that ends this close to the last thing the device's
+# tracer recorded touches the end of the recording
+TOUCH_NS = 1e4
+# the recording was stopped with a program running only where it ended
+# this close to the window's close or later: on the chip a cut tail ends
+# 0.05-1.0 ms before the host's close by the trace's clock; a recording
+# that ends earlier ends where the chip went idle, after a whole program
+CUT_NS = 5e6
+
+
+# (program event name, device seconds, real items its dispatch marshalled
+# or None where no `der_marshal` span is found for it)
+Call = Tuple[str, float, Optional[int]]
+
+
+def whole_calls(session: Session, ring: Sequence[dict],
+                like: Sequence[str]) -> List[Call]:
+    """The calls of the programs whose event name matches one of the
+    regular expressions `like` that ran whole inside the window.
+
+    The device's tracer starts some 50 ms before `start_trace` returns
+    and stops with the window.  So a program event that began before
+    the window opened, by the host's clock, may have been running when
+    the tracer started, and one that touches the end of what the
+    tracer recorded (`Session.recorded_end`, within `TOUCH_NS`) may
+    have been cut when it stopped, where the recording went on to
+    within `CUT_NS` of the window's close: neither is a call, nor is
+    one that ended after the window closed.  (On the chip a program
+    launched at the window's last commit event leaves a tail of
+    0.1-3.6 ms that ends inside the window by the host's clock, at the
+    recording's end; where the chip went idle after the last program
+    and stayed so until the close, that program is whole.  The first
+    program of a stretch is often the first thing recorded, and is
+    whole where it began after the window opened.)
+    The rest of a program that `read_session` returns as in flight has
+    no event and is no call either.  Each program is given to the
+    `device_enqueue` span that sent it by `pairing_offset`, its times
+    are shifted by `device_shift_ns`, and its items are those of the
+    `der_marshal` span that the same thread began last, under the same
+    parent, before that enqueue began: the real signatures of that one
+    call.  Empty where the programs cannot be paired with enqueues."""
+    sent = sorted((p for p in session.programs
+                   if any(re.search(pat, p[0]) for pat in like)),
+                  key=lambda p: p[1:])
+    enqueues = sorted((sp for sp in ring if sp["name"] == ENQUEUE),
+                      key=lambda sp: (sp["ts"], sp["dur"]))
+    on_trace = [(sp["ts"] * 1e9 - session.start_wall_ns,
+                 (sp["ts"] + sp["dur"]) * 1e9 - session.start_wall_ns)
+                for sp in enqueues]
+    first = pairing_offset([p[1:] for p in sent], on_trace)
+    if first is None:
+        return []
+    shift = device_shift_ns(list(zip([p[1:] for p in sent],
+                                     on_trace[first:])))
+    marshals: Dict[tuple, List[dict]] = {}
+    for sp in ring:
+        if sp["name"] == MARSHAL:
+            marshals.setdefault((sp["thread"], sp.get("parent_id")),
+                                []).append(sp)
+    lo, hi = (w * 1e9 - session.start_wall_ns for w in session.window_wall)
+    last_seen = math.inf if session.recorded_end is None \
+        else session.recorded_end
+    stopped_running = last_seen + shift > hi - CUT_NS
+    calls: List[Call] = []
+    for (name, start, end), enq in zip(sent, enqueues[first:]):
+        if start + shift < lo or end + shift > hi \
+                or (stopped_running and last_seen - end < TOUCH_NS):
+            continue
+        marshal = max((m for m in marshals.get(
+            (enq["thread"], enq.get("parent_id")), [])
+            if m["ts"] <= enq["ts"]), key=lambda m: m["ts"], default=None)
+        calls.append((name, (end - start) / 1e9,
+                      None if marshal is None
+                      else marshal["attrs"].get("items")))
+    return calls
