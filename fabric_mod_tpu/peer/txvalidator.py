@@ -55,8 +55,16 @@ _STAGED_ITEMS_OPTS = MetricOpts(
 _DEDUP_SAVED_OPTS = MetricOpts(
     "fabric", "validator", "dedup_saved_items",
     help="Verify requests answered by within-block dedup instead of a "
-         "device lane (meta-policies and key-level candidates re-stage "
-         "identical signature sets).")
+         "device lane (key-level candidates and repeated signature "
+         "sets re-stage identical items; an implicit meta policy's "
+         "leaves share one staging and no longer count here: see "
+         "fabric_policy_meta_shared_resolutions_total).")
+_META_SHARED_OPTS = MetricOpts(
+    "fabric", "policy", "meta_shared_resolutions_total",
+    help="Identity resolutions (deserialize, validate, stage) an "
+         "implicit meta policy's sub-policies took from one shared "
+         "pass instead of resolving again: (leaves - 1) x distinct "
+         "identities per evaluation; added once per block.")
 _RAW_ITEMS_OPTS = MetricOpts(
     "fabric", "validator", "staged_raw_message_items",
     help="Staged items carrying raw messages instead of host digests "
@@ -88,7 +96,8 @@ def _stage_metrics():
                            buckets=(1, 8, 64, 256, 512, 1024, 2048)),
             prov.counter(_DEDUP_SAVED_OPTS),
             prov.counter(_RAW_ITEMS_OPTS),
-            prov.counter(_BODY_FALLBACK_OPTS))
+            prov.counter(_BODY_FALLBACK_OPTS),
+            prov.counter(_META_SHARED_OPTS))
 
 
 @functools.lru_cache(maxsize=None)
@@ -635,9 +644,11 @@ class TxValidator:
         # (bccsp/tpu.VerdictCache); within-block repeats never reach
         # it thanks to the collector's dedup, and both effects are
         # exported so coalescing stays observable.
-        staged_hist, dedup_ctr, raw_ctr, _fb_ctr = _stage_metrics()
+        staged_hist, dedup_ctr, raw_ctr, _fb_ctr, shared_ctr = \
+            _stage_metrics()
         staged_hist.observe(len(collector.items))
         dedup_ctr.add(collector.requests - len(collector.items))
+        shared_ctr.add(collector.shared_resolutions)
         _decode_path_metrics()[decoder].add(1)
         # Raw-message items (identities emit them under FABRIC_MOD_
         # TPU_FUSED_HASH) flow through the same collector/dedup into

@@ -41,15 +41,19 @@ class PolicyError(Exception):
 class BatchCollector:
     """Accumulates VerifyItems across many policy evaluations so they
     can be verified in one device dispatch.  Identical work items
-    (same digest, signature, key) dedup to one batch slot — meta
-    policies hand the same signature set to every sub-policy, and
-    re-verifying it per sub-policy would multiply the device batch."""
+    (same digest, signature, key and message) dedup to one batch slot:
+    key-level candidates and repeated signature sets stage the same
+    check more than once in a block, and verifying each repeat would
+    multiply the device batch."""
 
     def __init__(self):
         self.items: List[VerifyItem] = []
         self.requests = 0          # add() calls incl. dedup hits — the
         self._index: dict = {}     # spread vs len(items) is staged work
         #                            the dedup saved (validator metrics)
+        # identity resolutions a meta policy's leaves took from a
+        # resolution already made on their manager (prepare)
+        self.shared_resolutions = 0
 
     def add(self, item: VerifyItem) -> int:
         self.requests += 1
@@ -73,7 +77,8 @@ class PendingEval:
 
     `slots` pairs each candidate identity with either the index of its
     VerifyItem in the collector batch or a host-computed verdict (for
-    non-batchable curves).
+    non-batchable curves).  The leaves of one meta policy that share a
+    resolution hold the same `idents` and `slots` lists.
     """
 
     def __init__(self, closure: Callable, idents: List,
@@ -82,15 +87,21 @@ class PendingEval:
         self._idents = idents
         self._slots = slots                 # (batch_idx | None, host_ok)
 
-    def finish(self, mask) -> bool:
-        """Resolve against the batch verdict mask -> policy verdict."""
-        valid = []
-        for ident, (bidx, host_ok) in zip(self._idents, self._slots):
-            ok = bool(mask[bidx]) if bidx is not None else host_ok
-            if ok:
-                valid.append(ident)
-        used = [False] * len(valid)
-        return self._closure(valid, used)
+    def finish(self, mask, valid: Optional[dict] = None) -> bool:
+        """Resolve against the batch verdict mask -> policy verdict.
+
+        `valid` maps a resolution to the identities the mask accepted:
+        a meta policy hands one dict to every leaf beneath it, so a
+        shared resolution is read once (the walk only reads the list)."""
+        if valid is None:
+            valid = {}
+        ok = valid.get(id(self._slots))
+        if ok is None:
+            ok = valid[id(self._slots)] = [
+                ident for ident, (bidx, host_ok)
+                in zip(self._idents, self._slots)
+                if (bool(mask[bidx]) if bidx is not None else host_ok)]
+        return self._closure(ok, [False] * len(ok))
 
 
 def _compile(rule: m.SignaturePolicy,
@@ -132,6 +143,33 @@ def _compile(rule: m.SignaturePolicy,
     return leaf
 
 
+def _resolve(signed_datas: Sequence[SignedData], msp_mgr,
+             collector: BatchCollector) -> tuple:
+    """-> (idents, slots, distinct identities in the set)."""
+    idents: List = []
+    slots: List[tuple] = []
+    seen = set()
+    for sd in signed_datas:
+        if sd.identity in seen:
+            continue                          # duplicate identity: skip
+        seen.add(sd.identity)
+        try:
+            ident = msp_mgr.deserialize_identity(sd.identity)
+        except Exception:
+            continue                          # unknown MSP / bad cert
+        try:
+            msp_mgr.validate(ident)
+        except Exception:
+            continue                          # expired/revoked/untrusted
+        item = ident.verify_item(sd.data, sd.signature)
+        if item is not None:
+            slots.append((collector.add(item), False))
+        else:                                 # non-P256: host verify now
+            slots.append((None, ident.verify(sd.data, sd.signature)))
+        idents.append(ident)
+    return idents, slots, len(seen)
+
+
 class CompiledPolicy:
     """A compiled SignaturePolicyEnvelope bound to an MSP manager.
 
@@ -147,33 +185,27 @@ class CompiledPolicy:
 
     # -- phase 1: dedup + validate + stage verifies ----------------------
     def prepare(self, signed_datas: Sequence[SignedData],
-                collector: BatchCollector):
+                collector: BatchCollector,
+                resolved: Optional[dict] = None) -> PendingEval:
         """Dedup identities, drop undeserializable/invalid ones, stage
         each survivor's signature check into `collector` (reference:
         common/policies/policy.go:365-403, which dedups then verifies
-        every signature before the policy walk)."""
-        idents: List = []
-        slots: List[tuple] = []
-        seen = set()
-        for sd in signed_datas:
-            if sd.identity in seen:
-                continue                      # duplicate identity: skip
-            seen.add(sd.identity)
-            try:
-                ident = self._msp_mgr.deserialize_identity(sd.identity)
-            except Exception:
-                continue                      # unknown MSP / bad cert
-            try:
-                self._msp_mgr.validate(ident)
-            except Exception:
-                continue                      # expired/revoked/untrusted
-            item = ident.verify_item(sd.data, sd.signature)
-            if item is not None:
-                slots.append((collector.add(item), False))
-            else:                             # non-P256: host verify now
-                slots.append((None, ident.verify(sd.data, sd.signature)))
-            idents.append(ident)
-        return PendingEval(self._closure, idents, slots)
+        every signature before the policy walk).
+
+        `resolved` maps a manager to its resolution of this same
+        signature set: a meta policy hands one dict to every leaf
+        beneath it, so each manager resolves the set once.  The
+        reference resolves it per sub-policy with the same
+        deserializer, so the answer is the same."""
+        if resolved is None:
+            resolved = {}
+        got = resolved.get(id(self._msp_mgr))
+        if got is None:
+            got = resolved[id(self._msp_mgr)] = _resolve(
+                signed_datas, self._msp_mgr, collector)
+        else:
+            collector.shared_resolutions += got[2]
+        return PendingEval(self._closure, got[0], got[1])
 
     def satisfied_by_principals(self, idents: Sequence) -> bool:
         """Principal-only evaluation — no signatures involved (the

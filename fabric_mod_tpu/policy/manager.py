@@ -32,8 +32,11 @@ class _MetaPending:
         self._pendings = pendings
         self._threshold = threshold
 
-    def finish(self, mask) -> bool:
-        got = sum(1 for p in self._pendings if p.finish(mask))
+    def finish(self, mask, valid: Optional[dict] = None) -> bool:
+        # every sub-policy counted, no early exit at the threshold
+        if valid is None:
+            valid = {}
+        got = sum(1 for p in self._pendings if p.finish(mask, valid))
         return got >= self._threshold
 
 
@@ -58,9 +61,17 @@ class ImplicitMetaPolicyObj:
             self.threshold = 1
 
     def prepare(self, signed_datas: Sequence[SignedData],
-                collector: BatchCollector):
+                collector: BatchCollector,
+                resolved: Optional[dict] = None):
+        """Every sub-policy's check of one signature set.  One dict of
+        resolutions serves the whole tree, so the set is deserialized,
+        validated and staged once per manager; each node keeps its
+        threshold and each leaf its closure."""
+        if resolved is None:
+            resolved = {}
         return _MetaPending(
-            [s.prepare(signed_datas, collector) for s in self._subs],
+            [s.prepare(signed_datas, collector, resolved)
+             for s in self._subs],
             self.threshold)
 
     def evaluate_signed_data(self, signed_datas: Sequence[SignedData],

@@ -464,6 +464,183 @@ def test_collector_dedups_identical_items(world):
     assert pend.finish(mask) is True
 
 
+# --- one resolution per implicit meta evaluation -----------------------------
+
+def _per_sub(pol, sds, col):
+    """Each leaf resolves the signature set itself, as the reference
+    does for every sub-policy."""
+    from fabric_mod_tpu.policy.manager import _MetaPending
+    if isinstance(pol, CompiledPolicy):
+        return pol.prepare(sds, col)
+    return _MetaPending([_per_sub(s, sds, col) for s in pol._subs],
+                        pol.threshold)
+
+
+def _meta_tree(world, shape):
+    subs = list(_org_writers(world).values())
+    if shape != "nested":
+        return ImplicitMetaPolicyObj(subs, getattr(m.ImplicitMetaRule, shape))
+    # /Channel/Writers over Application/Writers over each org's
+    # Writers, beside an orderer-side meta over one two-org leaf
+    app = ImplicitMetaPolicyObj(subs, m.ImplicitMetaRule.MAJORITY)
+    ordr = ImplicitMetaPolicyObj(
+        [_compiled(world, "AND('Org1.peer', 'Org2.peer')")],
+        m.ImplicitMetaRule.ANY)
+    return ImplicitMetaPolicyObj(
+        [app, ordr, ImplicitMetaPolicyObj([], m.ImplicitMetaRule.ANY)],
+        m.ImplicitMetaRule.MAJORITY)
+
+
+def _signature_sets(world):
+    """Signature sets that mix every way an endorsement can drop out
+    of, or repeat within, a set."""
+    from cryptography.hazmat.primitives.asymmetric import ec
+    o = world["orgs"]
+    data = b"prp||endorser"
+    p1, p2, p3 = (_sd(o[n]["peer"], data) for n in ("Org1", "Org2", "Org3"))
+    garbage = SignedData(data=data, identity=b"\x0a\x03Org9\x12\x01z",
+                         signature=p1.signature)
+    rogue_ca = calib.CA("ca.rogue", "Org1")
+    cert, key = rogue_ca.issue("peer9.org1", "Org1", ous=["peer"])
+    rogue = _sd(SigningIdentity("Org1", cert, calib.key_pem(key),
+                                world["csp"]), data)
+    cert, key = o["Org2"]["ca"].issue(
+        "p384.org2", "Org2", ous=["peer"],
+        key=ec.generate_private_key(ec.SECP384R1()))
+    p384 = _sd(SigningIdentity("Org2", cert, calib.key_pem(key),
+                               world["csp"]), data)
+
+    def flipped(sd):
+        sig = bytearray(sd.signature)
+        sig[-1] ^= 1
+        return SignedData(data=sd.data, identity=sd.identity,
+                          signature=bytes(sig))
+    return {
+        "single": [p1],
+        "two": [p1, p2],
+        "three": [p3, p2, p1],
+        "none-valid": [flipped(p1), garbage, rogue],
+        "duplicate": [p1, p1, p2],
+        "undeserializable": [garbage, p2, p3],
+        "fails-validate": [rogue, p3],
+        "flipped": [flipped(p1), p2, p3],
+        "host-verdict": [p384, p1],
+        "host-verdict-flipped": [flipped(p384), p3],
+        "mixed": [p2, garbage, p2, rogue, flipped(p3), p384, p1],
+    }
+
+
+@pytest.mark.parametrize("shape", ["ANY", "ALL", "MAJORITY", "nested"])
+def test_shared_resolution_matches_per_sub_prepare(world, shape):
+    """One resolution bound to every leaf gives each set the verdict
+    the per-sub-policy path gives, and stages the same items in the
+    same order, over a block's worth of evaluations in one batch."""
+    meta = _meta_tree(world, shape)
+    sets = _signature_sets(world)
+    shared_col, per_col = BatchCollector(), BatchCollector()
+    shared = [meta.prepare(sds, shared_col) for sds in sets.values()]
+    per = [_per_sub(meta, sds, per_col) for sds in sets.values()]
+    assert shared_col.items == per_col.items
+    assert per_col.shared_resolutions == 0
+    leaves = 4 if shape == "nested" else 3
+    distinct = sum(len({sd.identity for sd in sds})
+                   for sds in sets.values())
+    assert shared_col.shared_resolutions == (leaves - 1) * distinct
+    assert shared_col.requests < per_col.requests
+    mask = world["csp"].verify_batch(shared_col.items)
+    got = [p.finish(mask) for p in shared]
+    assert got == [p.finish(mask) for p in per]
+    assert True in got and False in got
+
+
+@pytest.mark.parametrize("case", ["two", "mixed"])
+def test_meta_tree_on_two_managers_resolves_once_per_manager(
+        world, monkeypatch, case):
+    """Leaves bound to two managers: each manager deserializes the set
+    once, for its own leaves only, and the verdict and staged items
+    are the per-sub-policy path's."""
+    other = MspManager(world["mgr"].msps())
+    meta = ImplicitMetaPolicyObj(
+        [_compiled(world, "OR('Org1.member')"),
+         _compiled(world, "OR('Org3.member')"),
+         CompiledPolicy(from_string("OR('Org2.member')"), other)],
+        m.ImplicitMetaRule.ALL)
+    calls = []
+    deserialize = MspManager.deserialize_identity
+    monkeypatch.setattr(
+        MspManager, "deserialize_identity",
+        lambda self, raw: calls.append(self) or deserialize(self, raw))
+    sds = _signature_sets(world)[case]
+    col, per_col = BatchCollector(), BatchCollector()
+    pend = meta.prepare(sds, col)
+    distinct = len({sd.identity for sd in sds})
+    assert calls.count(world["mgr"]) == calls.count(other) == distinct
+    assert col.shared_resolutions == distinct
+    per = _per_sub(meta, sds, per_col)
+    assert col.items == per_col.items
+    mask = world["csp"].verify_batch(col.items)
+    assert pend.finish(mask) == per.finish(mask)
+
+
+def test_meta_endorsement_resolves_each_endorsement_once(world, monkeypatch):
+    """Under the three-org MAJORITY Endorsement policy each distinct
+    endorsement is deserialized, validated and staged once per
+    prepare, not once per sub-policy."""
+    from fabric_mod_tpu.msp.identities import Identity
+    bundle = _bundle(world)
+    pol = bundle.policy(ENDORSEMENT)
+    staged = []
+    verify_item = Identity.verify_item
+    monkeypatch.setattr(
+        Identity, "verify_item",
+        lambda self, msg, sig: staged.append(self) or
+        verify_item(self, msg, sig))
+    o = world["orgs"]
+    endorsers = [o["Org1"]["peer"], o["Org2"]["peer"]]
+    col = BatchCollector()
+    before = _lookups()
+    pendings = [pol.prepare([_sd(e, b"tx%d" % i) for e in endorsers], col)
+                for i in range(5)]
+    looked = {}
+    for (cache, _), v in _lookups().items():
+        looked[cache] = looked.get(cache, 0) + v - before.get((cache, _), 0)
+    assert looked["deserialize"] == 10 and looked["validate"] == 10
+    assert len(staged) == 10 and len(col.items) == 10
+    assert col.requests == 10 and col.shared_resolutions == 20
+    mask = world["csp"].verify_batch(col.items)
+    assert all(p.finish(mask) for p in pendings)
+
+
+@pytest.mark.parametrize("policy", ["meta-majority", "flat-signature"])
+def test_validator_counts_shared_resolutions_once_a_block(world, policy):
+    """fabric_policy_meta_shared_resolutions_total: 4 a two-endorsement
+    transaction under the MAJORITY Endorsement policy, 0 under a flat
+    SIGNATURE policy that no meta policy wraps."""
+    from fabric_mod_tpu.bccsp.tpu import FakeBatchVerifier
+    from fabric_mod_tpu.peer import TxValidator, ValidationInfoProvider
+    from fabric_mod_tpu.peer.txvalidator import _stage_metrics
+    bundle = _bundle(world)
+    if policy == "meta-majority":
+        validator = _validator_of(bundle, world)
+    else:
+        flat = m.ApplicationPolicy(signature_policy=from_string(
+            "OutOf(2, 'Org1.peer', 'Org2.peer', 'Org3.peer')"))
+        validator = TxValidator(
+            "cachech", bundle.msp_manager,
+            ApplicationPolicyEvaluator(bundle.msp_manager,
+                                       bundle.policy_manager,
+                                       sequence=bundle.sequence),
+            FakeBatchVerifier(world["csp"]),
+            ValidationInfoProvider(flat.encode()))
+    o = world["orgs"]
+    endorsers = [o["Org1"]["peer"], o["Org3"]["peer"]]
+    envs = [_signed_tx(world, endorsers, key=f"s{i}") for i in range(5)]
+    _staged, _dedup, _raw, _fallback, counter = _stage_metrics()
+    before = counter.value
+    assert _flags(validator, envs) == bytes([V.VALID] * 5)
+    assert counter.value - before == (20 if policy == "meta-majority" else 0)
+
+
 def test_channel_policy_reference_not_stale(world):
     """Replacing a named channel policy must take effect on the next
     evaluation (the reference re-resolves per call)."""
@@ -660,8 +837,9 @@ def test_second_block_of_the_same_identities_adds_hits_only(world):
     after = _lookups()
     assert _misses_since(before) == {
         "deserialize": 0, "validate": 0, "principal": 0}
-    # 6 creators + 6 x 2 endorsers x 3 sub-policies
-    assert after[("validate", "hit")] - before[("validate", "hit")] == 42
+    # 6 creators + 6 x 2 endorsers, each resolved once for the three
+    # sub-policies of the MAJORITY Endorsement policy
+    assert after[("validate", "hit")] - before[("validate", "hit")] == 18
 
 
 def _crl_revoking(ca, cert):
