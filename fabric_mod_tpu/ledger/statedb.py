@@ -88,6 +88,11 @@ class VersionedDB:
         got = self._metadata.get((ns, key))
         return dict(got) if got else None
 
+    def has_metadata(self) -> bool:
+        """Whether any key carries metadata: where none does, no key
+        has a key-level endorsement policy to look up."""
+        return bool(self._metadata)
+
     def iter_state(self):
         """Deterministic full scan: (ns, key, value, version) sorted —
         the snapshot generator's input (reference: the stateleveldb

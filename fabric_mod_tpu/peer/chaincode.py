@@ -10,6 +10,7 @@ builders.)
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, List, Optional, Protocol
 
 
@@ -368,3 +369,122 @@ class HotAccountsContract:
         for i in range(lo, hi):
             stub.put_state(f"a_{i}", b"%d" % v)
         return b"ok"
+
+
+class SbeAssetContract:
+    """fabric-samples' `asset-transfer-sbe` chaincode (state-based
+    endorsement; recalled, not read here).  An asset is the JSON
+    document {ID, Value, Owner, OwnerOrg} under its ID as the key, and
+    the key carries a key-level endorsement policy that the peer of the
+    org owning the asset alone satisfies (the sample's
+    `AddOrgs(RoleTypePeer, ownerOrg)`: `OutOf(1, '<OwnerOrg>.peer')`),
+    set through the key's VALIDATION_PARAMETER.
+
+        CreateAsset(id, value, owner)
+                refused where the asset exists (the read records that
+                the key was absent); OwnerOrg is the creator's MSP and
+                the key's policy becomes that org's peer.  The key has
+                no policy yet, so the chaincode-wide policy judges it
+        UpdateAsset(id, value)
+                reads the asset and writes it back with the new value.
+                There is no ownership check: the key's policy is what
+                stops a non-owner
+        TransferAsset(id, newOwner, newOwnerOrg)
+                reads the asset, writes it with the new owner and org
+                and moves the key's policy to the new org's peer
+        ReadAsset(id)
+                returns the document; writes nothing
+
+    `Value` is a whole number, as the sample's.  Departures: the
+    document is written with sorted keys and no spaces (the sample's
+    JSON.stringify keeps its fields' order), and the policy is written
+    in the form this peer's key-level validation reads, an
+    ApplicationPolicy holding the SignaturePolicyEnvelope (Fabric
+    stores the bare envelope).
+    """
+
+    # operation -> how many arguments it takes
+    ARITY = {"CreateAsset": 3, "UpdateAsset": 2, "TransferAsset": 3,
+             "ReadAsset": 1}
+
+    def invoke(self, stub: ChaincodeStub) -> bytes:
+        if not stub.args:
+            raise ChaincodeError("no args")
+        op = stub.args[0].decode()
+        if self.ARITY.get(op) != len(stub.args) - 1:
+            raise ChaincodeError(
+                f"no op {op!r} of {len(stub.args) - 1} arguments")
+        return getattr(self, "_op_" + op)(
+            stub, *(a.decode() for a in stub.args[1:]))
+
+    @staticmethod
+    def document(asset_id: str, value: int, owner: str,
+                 owner_org: str) -> bytes:
+        import json
+        return json.dumps(
+            {"ID": asset_id, "Value": value, "Owner": owner,
+             "OwnerOrg": owner_org},
+            sort_keys=True, separators=(",", ":")).encode()
+
+    @staticmethod
+    @functools.lru_cache(maxsize=None)
+    def owner_policy(org: str) -> bytes:
+        """The VALIDATION_PARAMETER of an asset `org` owns (one per
+        org: the policy's text is parsed once)."""
+        from fabric_mod_tpu.policy import from_string
+        from fabric_mod_tpu.protos import messages as m
+        return m.ApplicationPolicy(signature_policy=from_string(
+            f"OutOf(1, '{org}.peer')")).encode()
+
+    @staticmethod
+    def _value(raw: str) -> int:
+        try:
+            return int(raw)
+        except ValueError as e:
+            raise ChaincodeError(f"value {raw!r}: {e}") from e
+
+    @staticmethod
+    def _asset(stub: ChaincodeStub, asset_id: str) -> dict:
+        import json
+        raw = stub.get_state(asset_id)
+        if raw is None:
+            raise ChaincodeError(f"the asset {asset_id} does not exist")
+        return json.loads(raw)
+
+    def _put(self, stub: ChaincodeStub, asset: dict) -> None:
+        stub.put_state(asset["ID"], self.document(
+            asset["ID"], asset["Value"], asset["Owner"], asset["OwnerOrg"]))
+
+    def _op_CreateAsset(self, stub, asset_id: str, value: str,
+                        owner: str) -> bytes:
+        if stub.get_state(asset_id) is not None:
+            raise ChaincodeError(f"the asset {asset_id} already exists")
+        org = stub.creator_mspid()
+        if not org:
+            raise ChaincodeError("the creator names no MSP")
+        self._put(stub, {"ID": asset_id, "Value": self._value(value),
+                         "Owner": owner, "OwnerOrg": org})
+        stub.set_state_metadata(asset_id, "VALIDATION_PARAMETER",
+                                self.owner_policy(org))
+        return b"ok"
+
+    def _op_UpdateAsset(self, stub, asset_id: str, value: str) -> bytes:
+        asset = self._asset(stub, asset_id)
+        asset["Value"] = self._value(value)
+        self._put(stub, asset)
+        return b"ok"
+
+    def _op_TransferAsset(self, stub, asset_id: str, new_owner: str,
+                          new_owner_org: str) -> bytes:
+        asset = self._asset(stub, asset_id)
+        asset["Owner"], asset["OwnerOrg"] = new_owner, new_owner_org
+        self._put(stub, asset)
+        stub.set_state_metadata(asset_id, "VALIDATION_PARAMETER",
+                                self.owner_policy(new_owner_org))
+        return b"ok"
+
+    def _op_ReadAsset(self, stub, asset_id: str) -> bytes:
+        raw = stub.get_state(asset_id)
+        if raw is None:
+            raise ChaincodeError(f"the asset {asset_id} does not exist")
+        return raw

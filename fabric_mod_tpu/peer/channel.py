@@ -140,7 +140,8 @@ class Channel:
             tx_id_exists=self.ledger.tx_id_exists,
             config_apply=self._validate_and_apply_config,
             state_metadata=state_vp,
-            plugin_registry=self._plugin_registry)
+            plugin_registry=self._plugin_registry,
+            state_has_metadata=lambda: self.ledger.state.has_metadata())
         with self._lock:
             self._bundle = bundle
             self._validator = validator

@@ -390,6 +390,12 @@ class PipelinedCommitter:
         placement-balance view next to the router's channels gauge."""
         return self._consumer
 
+    def _barrier_holds(self) -> bool:
+        """A staged block that needs a barrier is not committed yet
+        (under `self._cv`)."""
+        return (self._barrier_height is not None
+                and self._height < self._barrier_height)
+
     # -- stage loop: host unpack + device dispatch -----------------------
     def _stage_loop(self) -> None:
         try:
@@ -403,12 +409,21 @@ class PipelinedCommitter:
                                   block=block.header.number), self._cv:
                     # depth bound + barrier drain share the wait: stage
                     # only when a slot is free AND no barrier block is
-                    # still committing
+                    # still committing; the barrier's part of the wait
+                    # is a span of its own
                     while self._err is None and (
                             self._inflight >= self.depth
-                            or (self._barrier_height is not None
-                                and self._height < self._barrier_height)):
-                        self._cv.wait(timeout=0.5)
+                            or self._barrier_holds()):
+                        if self._barrier_holds():
+                            with tracing.span(
+                                    "stage_barrier_wait",
+                                    block=block.header.number,
+                                    barrier_block=self._barrier_height - 1):
+                                while self._err is None and \
+                                        self._barrier_holds():
+                                    self._cv.wait(timeout=0.5)
+                        else:
+                            self._cv.wait(timeout=0.5)
                     if self._err is not None:
                         continue           # drain mode (below)
                     self._inflight += 1

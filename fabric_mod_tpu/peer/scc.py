@@ -99,7 +99,7 @@ def build_default_registry(channel, ledger):
     real peer process so their wiring can never drift."""
     from fabric_mod_tpu.peer.chaincode import (
         ChaincodeRegistry, HotAccountsContract, KvContract,
-        SmallbankContract)
+        SbeAssetContract, SmallbankContract)
     from fabric_mod_tpu.peer.lifecycle import (
         LIFECYCLE_NS, LifecycleContract)
 
@@ -107,6 +107,7 @@ def build_default_registry(channel, ledger):
     registry.register("mycc", KvContract())
     registry.register("smallbank", SmallbankContract())
     registry.register("accounts", HotAccountsContract())
+    registry.register("sbe", SbeAssetContract())
     registry.register(LIFECYCLE_NS, LifecycleContract(
         channel_orgs=lambda: list(
             channel.bundle().application.org_mspids)))

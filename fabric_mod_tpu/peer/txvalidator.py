@@ -24,8 +24,10 @@ validator makes the data flow explicit and device-shaped:
   pass 2 (device) verifier.verify_many(collector.items) — a single
                   jitted dispatch for the whole block
   pass 3 (host)   resolve creator verdicts, finish each endorsement-
-                  policy decision against the mask, mark duplicate
-                  tx ids, write the txflags bitmap
+                  policy decision against the mask (key-level ones in
+                  block order: a write to a key whose policy an
+                  earlier valid tx of the block changed fails), mark
+                  duplicate tx ids, write the txflags bitmap
 
 MVCC and commit stay in the ledger (kvledger.commit_block).
 """
@@ -76,6 +78,18 @@ _POLICY_EVALS_OPTS = MetricOpts(
          "(chaincode-wide and key-level), by result; added once per "
          "block.",
     label_names=("result",))
+_KEY_POLICY_EVALS_OPTS = MetricOpts(
+    "fabric", "validator", "key_policy_evals_total",
+    help="Written keys by the policy that judged them in pass 3: the "
+         "key's committed VALIDATION_PARAMETER, a change an earlier "
+         "valid transaction of the block made (which fails the write), "
+         "or the chaincode-wide policy; added once per block.",
+    label_names=("source",))
+_VP_UPDATED_OPTS = MetricOpts(
+    "fabric", "validator", "vp_updated_invalid_total",
+    help="Transactions marked ENDORSEMENT_POLICY_FAILURE because they "
+         "write a key whose policy an earlier valid transaction of the "
+         "same block changed; added once per block.")
 _BODY_FALLBACK_OPTS = MetricOpts(
     "fabric", "validator", "body_decode_fallbacks",
     help="Endorser-tx bodies the columnar batch decoder could not "
@@ -109,9 +123,14 @@ def _decode_path_metrics():
 
 @functools.lru_cache(maxsize=None)
 def _policy_eval_metrics():
-    evals = default_provider().counter(_POLICY_EVALS_OPTS)
+    prov = default_provider()
+    evals = prov.counter(_POLICY_EVALS_OPTS)
+    by_source = prov.counter(_KEY_POLICY_EVALS_OPTS)
     return (evals.with_labels("satisfied"),
-            evals.with_labels("unsatisfied"))
+            evals.with_labels("unsatisfied"),
+            {source: by_source.with_labels(source)
+             for source in ("committed", "inblock", "chaincode")},
+            prov.counter(_VP_UPDATED_OPTS))
 
 
 class ValidationInfoProvider:
@@ -138,13 +157,17 @@ VALIDATION_PARAMETER = "VALIDATION_PARAMETER"
 
 
 class _KeyEval:
-    """One written key's endorsement-policy resolution candidates.
+    """One written key's endorsement-policy resolution.
 
-    (reference: statebased/validator_keylevel.go:243-271 — a key with a
-    VALIDATION_PARAMETER metadata override validates against it; which
-    override is in force can depend on EARLIER txs in the same block,
-    so every candidate's signature checks are staged in pass 1 and the
-    choice is resolved sequentially in pass 3.)
+    (reference: statebased/validator_keylevel.go `checkSBAndCCEP` — a
+    key with a committed VALIDATION_PARAMETER validates against it, one
+    without against the chaincode-wide policy; and where an EARLIER
+    VALID tx of the same block changed the key's parameter,
+    vpmanagerimpl.go answers ValidationParameterUpdatedError, which the
+    key-level validator turns into a policy failure whoever endorsed.
+    `inblock` lists those earlier txs; pass 3 reads their flags in
+    block order.  Their new policy never judges the write, so it is
+    not staged.)
     """
 
     __slots__ = ("ns", "key", "committed", "inblock")
@@ -153,15 +176,33 @@ class _KeyEval:
         self.ns = ns
         self.key = key
         self.committed = committed        # PendingEval | None
-        self.inblock = inblock            # [(tx_idx, PendingEval)]
+        self.inblock = inblock            # (tx_idx, ...)
 
 
 class _ActionEval:
-    __slots__ = ("cc_pending", "key_evals")
+    __slots__ = ("cc_pending", "key_evals", "key_level", "groups", "sds")
 
-    def __init__(self, cc_pending, key_evals):
+    def __init__(self, cc_pending, groups, sds):
         self.cc_pending = cc_pending      # chaincode-wide policy
-        self.key_evals = key_evals        # [_KeyEval]
+        self.key_evals = []               # [_KeyEval], staged per block
+        # whether a written key met a key-level policy when staged: a
+        # committed one or an earlier tx's change in this block
+        self.key_level = False
+        # what the block's key-policy pass stages from (then dropped):
+        # the written view [(ns, [key], [(key, [(name, value)])])] and
+        # the endorsements' signed data
+        self.groups = groups
+        self.sds = sds
+
+
+class _Tally:
+    """Pass 3's counts for one block, added to the counters once."""
+
+    __slots__ = ("satisfied", "unsatisfied", "sources", "vp_updated")
+
+    def __init__(self):
+        self.satisfied = self.unsatisfied = self.vp_updated = 0
+        self.sources = {"committed": 0, "inblock": 0, "chaincode": 0}
 
 
 class _TxWork:
@@ -254,7 +295,8 @@ class TxValidator:
                  config_apply: Optional[Callable[[m.Envelope], None]] = None,
                  state_metadata: Optional[Callable[[str, str],
                                                    Optional[bytes]]] = None,
-                 plugin_registry=None):
+                 plugin_registry=None,
+                 state_has_metadata: Optional[Callable[[], bool]] = None):
         self.channel_id = channel_id
         self._msp_mgr = msp_mgr
         self._policy_eval = policy_eval
@@ -277,11 +319,14 @@ class TxValidator:
         # (reference: the key-level validator's policy fetcher over the
         # state DB) — returns ApplicationPolicy bytes or None.
         self._state_metadata = state_metadata
+        # whether any key of the state carries metadata (None: unknown);
+        # where none does, the per-key look-ups are skipped
+        self._state_has_metadata = state_has_metadata
 
     # -- pass 1: host unpack + staging -----------------------------------
     def _stage_tx(self, env: m.Envelope, work: _TxWork,
-                  collector: BatchCollector, inblock_vp,
-                  spine=None, body=None) -> None:
+                  collector: BatchCollector, spine=None,
+                  body=None) -> None:
         """Syntactic validation + creator/endorsement staging for one
         tx.  Sets work.flag on terminal failure, else leaves VALID
         pending the device verdicts.  `spine` (protos/batchdecode) is
@@ -295,6 +340,9 @@ class TxValidator:
         prp / endorsement / written-key values the generic decode
         chain below would produce, already validated transitively —
         rows it could not prove take the generic chain (counted).
+        The key-level policies of each action's written keys are
+        staged afterwards, for the whole block in order
+        (`_stage_key_policies`).
         (reference: msgvalidation.go:248 ValidateTransaction)"""
         if not env.payload:
             work.flag = V.NIL_ENVELOPE
@@ -356,7 +404,7 @@ class TxValidator:
             # this tx's staged body view (single action — the scanner
             # rejects multi-action txs into the fallback), so staging
             # reads fields instead of re-decoding six proto layers
-            self._stage_body(body, work, collector, inblock_vp)
+            self._stage_body(body, work, collector)
             return
 
         # endorsement policy per action (reference: VSCC v20
@@ -396,14 +444,13 @@ class TxValidator:
                                   signature=e.signature)
                        for e in endorsements]
                 cc_pending = evaluator.prepare(policy_bytes, sds, collector)
-                key_evals = self._stage_key_policies(
-                    rwset, sds, collector, inblock_vp, work)
-                work.actions.append(_ActionEval(cc_pending, key_evals))
+                work.actions.append(_ActionEval(
+                    cc_pending, self._written_groups(rwset), sds))
         except Exception:
             work.flag = V.INVALID_ENDORSER_TRANSACTION
             return
 
-    def _stage_body(self, body, work, collector, inblock_vp) -> None:
+    def _stage_body(self, body, work, collector) -> None:
         """Stage one scanner-accepted endorser-tx body — the columnar
         twin of _stage_tx's generic action loop, consuming the values
         batchdecode already proved instead of re-decoding them.  Every
@@ -429,9 +476,7 @@ class TxValidator:
                               signature=signature)
                    for endorser, signature in body.endorsements]
             cc_pending = evaluator.prepare(policy_bytes, sds, collector)
-            key_evals = self._stage_key_policies_columnar(
-                body, sds, collector, inblock_vp, work)
-            work.actions.append(_ActionEval(cc_pending, key_evals))
+            work.actions.append(_ActionEval(cc_pending, body.groups, sds))
         except Exception:
             work.flag = V.INVALID_ENDORSER_TRANSACTION
             return
@@ -461,80 +506,94 @@ class TxValidator:
                 pass
         return self._vinfo.validation_info(ns)
 
-    def _stage_key_policies(self, rwset, sds, collector, inblock_vp, work):
-        """Stage every candidate key-level endorsement policy of this
-        action's written keys (reference: validator_keylevel.go — the
-        committed VALIDATION_PARAMETER plus any same-block overrides
-        whose applicability pass 3 resolves in order).  `rwset` is the
-        action's already-decoded TxReadWriteSet (None = malformed ->
-        no key evals, the historical behavior)."""
-        key_evals = []
+    @staticmethod
+    def _written_groups(rwset) -> list:
+        """The written view of a decoded TxReadWriteSet, as the columnar
+        decoder's `TxBody.groups` gives it: per namespace occurrence,
+        (ns, [written key], [(metadata key, [(name, value)])]).  `rwset`
+        None (malformed results) -> no groups, so no key evals."""
         if rwset is None:
-            return key_evals
+            return []
         from fabric_mod_tpu.ledger.rwsetutil import parse_tx_rwset
-        for ns, kv in parse_tx_rwset(rwset):
-            if kv.writes or kv.metadata_writes:
-                work.written_ns.add(ns)
-            written = dict.fromkeys(
-                [w.key for w in kv.writes]
-                + [mw.key for mw in kv.metadata_writes])
-            for key in written:
-                committed_pending = None
-                if self._state_metadata is not None:
-                    vp = self._state_metadata(ns, key)
-                    if vp:
-                        committed_pending = self._policy_eval.prepare(
-                            vp, sds, collector)
-                cands = inblock_vp.get((ns, key), ())
-                inblock = [
-                    (idx, self._policy_eval.prepare(vp, sds, collector))
-                    for idx, vp in cands]
-                # EVERY written key gets an eval entry: keys without an
-                # effective VP resolve to None in pass 3 and force the
-                # cc-wide policy — otherwise a tx satisfying one key's
-                # narrow VP could smuggle writes to other keys past the
-                # chaincode policy (fail-closed, like the reference's
-                # per-key fallback to the default policy)
-                key_evals.append(
-                    _KeyEval(ns, key, committed_pending, inblock))
-            # register this tx's own VALIDATION_PARAMETER writes for
-            # later txs in the block (applied only if this tx is VALID)
-            for mw in kv.metadata_writes:
-                for e in mw.entries:
-                    if e.name == VALIDATION_PARAMETER:
-                        work.vp_writes.append((ns, mw.key, e.value))
-        return key_evals
+        return [(ns, [w.key for w in kv.writes],
+                 [(mw.key, [(e.name, e.value) for e in mw.entries])
+                  for mw in kv.metadata_writes])
+                for ns, kv in parse_tx_rwset(rwset)]
 
-    def _stage_key_policies_columnar(self, body, sds, collector,
-                                     inblock_vp, work):
-        """_stage_key_policies over a columnar TxBody: `body.groups`
-        is the per-ns-occurrence written view the generic path derives
-        from parse_tx_rwset — same occurrence order, same per-
-        occurrence key dedup, same eval/vp-write sequence."""
-        key_evals = []
-        for ns, wkeys, metas in body.groups:
+    def _stage_key_policies(self, number: int, works,
+                            collector: BatchCollector) -> None:
+        """Pass 1's key-level part, over the whole block in tx order
+        once every tx's creator and chaincode-wide policy is staged
+        (reference: validator_keylevel.go).  Every written key of every
+        staged action gets an eval: its committed VALIDATION_PARAMETER,
+        staged against the action's endorsements, or None, which the
+        chaincode-wide policy covers in pass 3 (fail-closed: a tx that
+        satisfies one key's narrow policy cannot smuggle writes to
+        other keys past the chaincode policy).  The EARLIER txs of the
+        block that write the same key's parameter are recorded: pass 3
+        fails the write if one of them is VALID, so their new policy is
+        never staged.  A committed parameter that cannot be staged
+        marks its tx INVALID_ENDORSER_TRANSACTION.  Where the state
+        holds no key metadata and no tx of the block writes any, no key
+        can meet a key-level policy: the look-ups are skipped and no
+        span is opened."""
+        lookup = self._state_metadata
+        if lookup is not None and self._state_has_metadata is not None \
+                and not self._state_has_metadata():
+            lookup = None
+        if lookup is None and not any(
+                metas for work in works for action in work.actions
+                for _ns, _keys, metas in action.groups):
+            self._stage_block_keys(works, collector, None)
+            return
+        with tracing.span("key_policy_stage", block=number) as sp:
+            sp.set(**self._stage_block_keys(works, collector, lookup))
+
+    def _stage_block_keys(self, works, collector: BatchCollector,
+                          lookup) -> dict:
+        """The key evals of a block's actions, committed parameters
+        read through `lookup` (None: none to read); the counts the
+        span records."""
+        # (ns, key) -> [tx_idx]: the earlier txs of this block that
+        # write the key's VALIDATION_PARAMETER
+        inblock_vp: Dict[tuple, list] = {}
+        counts = {"keys": 0, "committed": 0, "inblock": 0}
+        for idx, work in enumerate(works):
+            try:
+                for action in work.actions:
+                    self._stage_action_keys(action, work, lookup,
+                                            inblock_vp, collector, counts)
+            except Exception:
+                work.flag = V.INVALID_ENDORSER_TRANSACTION
+            for ns, key, _vp in work.vp_writes:
+                inblock_vp.setdefault((ns, key), []).append(idx)
+        return counts
+
+    def _stage_action_keys(self, action: _ActionEval, work: _TxWork,
+                           lookup, inblock_vp, collector: BatchCollector,
+                           counts) -> None:
+        for ns, wkeys, metas in action.groups:
             if wkeys or metas:
                 work.written_ns.add(ns)
-            written = dict.fromkeys(
-                list(wkeys) + [mkey for mkey, _entries in metas])
-            for key in written:
-                committed_pending = None
-                if self._state_metadata is not None:
-                    vp = self._state_metadata(ns, key)
+            for key in dict.fromkeys([*wkeys, *(k for k, _ in metas)]):
+                pending = None
+                if lookup is not None:
+                    vp = lookup(ns, key)
                     if vp:
-                        committed_pending = self._policy_eval.prepare(
-                            vp, sds, collector)
-                cands = inblock_vp.get((ns, key), ())
-                inblock = [
-                    (idx, self._policy_eval.prepare(vp, sds, collector))
-                    for idx, vp in cands]
-                key_evals.append(
-                    _KeyEval(ns, key, committed_pending, inblock))
+                        counts["committed"] += 1
+                        pending = self._policy_eval.prepare(
+                            vp, action.sds, collector)
+                writers = tuple(inblock_vp.get((ns, key), ()))
+                counts["keys"] += 1
+                counts["inblock"] += len(writers)
+                if pending is not None or writers:
+                    action.key_level = True
+                action.key_evals.append(_KeyEval(ns, key, pending, writers))
             for mkey, entries in metas:
                 for name, value in entries:
                     if name == VALIDATION_PARAMETER:
                         work.vp_writes.append((ns, mkey, value))
-        return key_evals
+        action.groups = action.sds = None
 
     def _decode_columnar(self, block: m.Block):
         """The two batch pre-passes of a block at or above
@@ -598,10 +657,6 @@ class TxValidator:
         `finish` refuses the block unless the policy is satisfied."""
         works: List[_TxWork] = []
         collector = BatchCollector()
-        # (ns, key) -> [(tx_idx, ApplicationPolicy bytes)]: the
-        # VALIDATION_PARAMETER writes of EARLIER txs in this block —
-        # the intra-block dependency structure of validator_keylevel.go
-        inblock_vp: Dict[tuple, list] = {}
         datas = block.data.data
         decoder = ("columnar"
                    if len(datas) >= batchdecode.COLUMNAR_MIN_ROWS
@@ -628,9 +683,8 @@ class TxValidator:
                         work.flag = V.BAD_PAYLOAD
                         continue
                 body = rwsets.bodies[idx] if rwsets is not None else None
-                self._stage_tx(env, work, collector, inblock_vp, spine, body)
-                for ns, key, vp in work.vp_writes:
-                    inblock_vp.setdefault((ns, key), []).append((idx, vp))
+                self._stage_tx(env, work, collector, spine, body)
+            self._stage_key_policies(block.header.number, works, collector)
         gate = None
         n_tx_items = len(collector.items)
         if block_gate is not None:
@@ -682,9 +736,9 @@ class TxValidator:
 
     def finish(self, staged: "StagedBlock") -> List[int]:
         """Pass 3: await the device verdicts, then sequential flag
-        resolution — duplicate marking and key-level override
-        application happen in block order so later txs see exactly the
-        effects of earlier VALID ones."""
+        resolution — duplicate marking and the key-level rule run in
+        block order, so a write meets exactly the parameter changes of
+        earlier VALID txs."""
         block, works = staged.block, staged.works
         mask = staged.resolve_mask()
         if staged.gate is not None and not staged.gate.finish(mask):
@@ -694,29 +748,29 @@ class TxValidator:
             raise signatures_unsatisfied(block.header.number)
         flags: List[int] = []
         seen_txids = set()
-        applied_vp: Dict[tuple, int] = {}   # (ns, key) -> writer tx_idx
-        # evaluations satisfied and unsatisfied: tallied per block,
+        # evaluations and key-level judgements: tallied per block,
         # never per (identity, principal) pair
-        tally = [0, 0]
+        tally = _Tally()
         with tracing.span("policy_finish",
                           block=block.header.number) as finish_span:
-            for idx, work in enumerate(works):
-                flag = self._finish_tx(work, mask, applied_vp, tally)
+            for work in works:
+                flag = self._finish_tx(work, mask, flags, tally)
                 if flag == V.VALID and work.txid:
                     if work.txid in seen_txids or \
                             self._tx_id_exists(work.txid):
                         flag = V.DUPLICATE_TXID
                     else:
                         seen_txids.add(work.txid)
-                if flag == V.VALID:
-                    for ns, key, _vp in work.vp_writes:
-                        applied_vp[(ns, key)] = idx
                 flags.append(flag)
             protoutil.set_block_txflags(block, bytes(flags))
-            finish_span.set(evals=tally[0] + tally[1])
-        satisfied, unsatisfied = _policy_eval_metrics()
-        satisfied.add(tally[0])
-        unsatisfied.add(tally[1])
+            finish_span.set(evals=tally.satisfied + tally.unsatisfied)
+        satisfied, unsatisfied, by_source, vp_updated = \
+            _policy_eval_metrics()
+        satisfied.add(tally.satisfied)
+        unsatisfied.add(tally.unsatisfied)
+        for source, n in tally.sources.items():
+            by_source[source].add(n)
+        vp_updated.add(tally.vp_updated)
         return flags
 
     def validate(self, block: m.Block) -> List[int]:
@@ -726,12 +780,17 @@ class TxValidator:
         return self.finish(self.stage(block))
 
     @staticmethod
-    def _finish_eval(pending, mask, tally) -> bool:
+    def _finish_eval(pending, mask, tally: _Tally) -> bool:
         ok = pending.finish(mask)
-        tally[0 if ok else 1] += 1
+        if ok:
+            tally.satisfied += 1
+        else:
+            tally.unsatisfied += 1
         return ok
 
-    def _finish_tx(self, work: _TxWork, mask, applied_vp, tally) -> int:
+    def _finish_tx(self, work: _TxWork, mask, flags: List[int],
+                   tally: _Tally) -> int:
+        """One tx's flag; `flags` holds those of the txs before it."""
         if work.flag != V.NOT_VALIDATED:
             return work.flag
         bidx, host_ok = work.creator_slot
@@ -750,26 +809,48 @@ class TxValidator:
                 return V.INVALID_CONFIG_TRANSACTION
             return V.VALID
         for action in work.actions:
-            uncovered = not action.key_evals
-            for ke in action.key_evals:
-                writer = applied_vp.get((ke.ns, ke.key))
-                pending = None
-                if writer is not None:
-                    for tx_idx, cand in ke.inblock:
-                        if tx_idx == writer:
-                            pending = cand
-                            break
-                if pending is None:
-                    pending = ke.committed
-                if pending is None:
-                    uncovered = True        # falls to the cc-wide policy
-                    continue
-                if not self._finish_eval(pending, mask, tally):
+            # no written key, or one without a key-level policy: the
+            # chaincode-wide policy judges the action
+            uncovered = True
+            if action.key_level:
+                with tracing.span("key_policy_resolve",
+                                  keys=len(action.key_evals)) as sp:
+                    failed, uncovered = self._finish_keys(
+                        action, mask, flags, tally)
+                    sp.set(vp_updated_invalid=int(failed == "inblock"))
+                if failed is not None:
                     return V.ENDORSEMENT_POLICY_FAILURE
+            else:
+                tally.sources["chaincode"] += len(action.key_evals)
             if uncovered and not self._finish_eval(
                     action.cc_pending, mask, tally):
                 return V.ENDORSEMENT_POLICY_FAILURE
         return V.VALID
+
+    def _finish_keys(self, action: _ActionEval, mask, flags: List[int],
+                     tally: _Tally):
+        """The key-level judgement of one action's written keys, in
+        their order: (failed, uncovered).  `failed` is "inblock" where
+        an earlier VALID tx of the block changed a key's parameter (the
+        reference's ValidationParameterUpdatedError: a policy failure
+        whoever endorsed), "committed" where a committed parameter is
+        not satisfied, None where neither; `uncovered`, whether a key
+        without a parameter leaves the chaincode-wide policy to judge
+        the action."""
+        uncovered = False
+        for ke in action.key_evals:
+            if any(flags[i] == V.VALID for i in ke.inblock):
+                tally.sources["inblock"] += 1
+                tally.vp_updated += 1
+                return "inblock", uncovered
+            if ke.committed is None:
+                tally.sources["chaincode"] += 1
+                uncovered = True
+                continue
+            tally.sources["committed"] += 1
+            if not self._finish_eval(ke.committed, mask, tally):
+                return "committed", uncovered
+        return None, uncovered
 
 
 class Committer:

@@ -231,8 +231,9 @@ def _mixed_verdicts(world):
 
 def _inblock_override(world):
     """A VALIDATION_PARAMETER pin and writes of the pinned key in the
-    SAME block (the candidates `finish` resolves in block order), then
-    a write under the committed pin."""
+    SAME block (`finish` fails them in block order, whoever endorsed:
+    an earlier valid tx changed the key's policy), then a write under
+    the committed pin."""
     pin = _policy("'Org3.peer'")
     blocks, prev = [], b""
     for envs in (
@@ -241,7 +242,7 @@ def _inblock_override(world):
             [_tx(world, _vp_write("pinned", pin)),
              _tx(world, _write("mycc", "pinned", b"v1")),       # EPF
              _tx(world, _write("mycc", "pinned", b"v2"),
-                 endorsers=("Org3",)),                           # VALID
+                 endorsers=("Org3",)),                           # EPF
              _tx(world, _write("mycc", "o1"))],
             [_tx(world, _write("mycc", "pinned", b"v3")),       # EPF
              _tx(world, _write("mycc", "o2"))]):

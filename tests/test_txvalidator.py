@@ -434,7 +434,7 @@ def test_decode_paths_stage_the_same(world, monkeypatch, n, only):
             works=[(w.flag, w.txid, w.vp_writes, w.is_config,
                     w.creator_slot, w.written_ns, len(w.actions),
                     [[(ke.ns, ke.key, ke.committed is not None,
-                       [i for i, _ in ke.inblock])
+                       list(ke.inblock))
                       for ke in a.key_evals] for a in w.actions])
                    for w in staged.works])
     assert seen["generic"] == seen["columnar"]
